@@ -179,12 +179,17 @@ def test_node_commits_through_subprocess_app():
                                validator_address=pv.get_address())
                     pv.sign_tx_vote("abci-chain", v)
                     node.tx_vote_pool.check_tx(v)
-            deadline = time.monotonic() + 60
+            deadline = time.monotonic() + 60 * conftest.WAIT_FACTOR
             for tx in txs:
                 h = hashlib.sha256(tx).hexdigest().upper()
                 while not node.tx_store.has_tx(h):
                     assert time.monotonic() < deadline, "commit timeout"
                     time.sleep(0.01)
+            # a certificate is a decision-time fact; the DeliverTx to the
+            # other process runs a beat later on the committer thread
+            while not node.txflow.commits_drained():
+                assert time.monotonic() < deadline, "apply timeout"
+                time.sleep(0.01)
             # the app state lives in the subprocess: query round trip
             res = node.proxy_app.query.query_sync("/store", b"sub-3")
             assert res.value == b"v3"

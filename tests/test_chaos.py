@@ -12,8 +12,10 @@ paper-level properties:
 """
 
 import hashlib
+import itertools
 import time
 
+import conftest
 import numpy as np
 import pytest
 
@@ -39,7 +41,7 @@ CHAIN_ID = "txflow-localnet"  # LocalNet default
 
 
 def wait_until(pred, timeout=20.0, poll=0.01):
-    deadline = time.monotonic() + timeout
+    deadline = time.monotonic() + timeout * conftest.WAIT_FACTOR
     while time.monotonic() < deadline:
         if pred():
             return True
@@ -450,7 +452,7 @@ def test_localnet_commits_through_device_outage_and_recovery():
         down = [b"outage-%d=v" % i for i in range(3)]
         for tx in down:
             net.broadcast_tx(tx)
-        assert net.wait_all_committed(down, timeout=60), (
+        assert net.wait_all_committed(down, timeout=60 * conftest.WAIT_FACTOR), (
             "fallback must keep commits flowing while the device is down"
         )
         assert not resilient.device_healthy and resilient.demotions == 1
@@ -461,8 +463,19 @@ def test_localnet_commits_through_device_outage_and_recovery():
         up = [b"recovered-%d=v" % i for i in range(3)]
         for tx in up:
             net.broadcast_tx(tx)
-        assert net.wait_all_committed(up, timeout=60)
-        assert wait_until(lambda: resilient.device_healthy, timeout=20), (
+        assert net.wait_all_committed(up, timeout=60 * conftest.WAIT_FACTOR)
+        # only a verify call can probe: the three txs above may all have
+        # committed on the fallback INSIDE the probe interval that the
+        # last failed probe re-armed, after which an idle net never asks
+        # the device again — keep traffic flowing until a probe is due
+        more = itertools.count()
+
+        def healed():
+            if not resilient.device_healthy:
+                net.broadcast_tx(b"probe-%d=v" % next(more))
+            return resilient.device_healthy
+
+        assert wait_until(healed, timeout=20, poll=0.05), (
             "a probe within probe_interval must re-promote the device"
         )
         assert resilient.repromotions == 1

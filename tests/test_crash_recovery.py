@@ -45,7 +45,7 @@ class CountingKVStore(KVStoreApplication):
 
 
 def wait_until(pred, timeout=20.0, poll=0.01):
-    deadline = time.monotonic() + timeout
+    deadline = time.monotonic() + timeout * conftest.WAIT_FACTOR
     while time.monotonic() < deadline:
         if pred():
             return True
@@ -192,10 +192,19 @@ def test_consensus_crash_then_restart_resumes_chain(tmp_path, point):
     node2, pv = build_node(tmp_path, enable_consensus=True, app=app2)
     node2.start()
     try:
-        st = node2.consensus.state
-        # handshake reconciled the three height domains
-        assert st.last_block_height == node2.block_store.height()
-        assert node2.block_store.height() >= crash_store_h - 1
+        # handshake reconciled the three height domains. The restarted
+        # node is already producing blocks, and a block is saved a beat
+        # before the state that follows it — but both happen inside one
+        # hold of the consensus lock, so under it the two reads are one
+        # moment of the chain: the state sits exactly on the store's top
+        # block, and names that very block
+        with node2.consensus._mtx:
+            st = node2.consensus.state
+            store_h = node2.block_store.height()
+            top = node2.block_store.load_block(store_h)
+        assert st.last_block_height == store_h
+        assert top.hash() == st.last_block_id
+        assert store_h >= crash_store_h - 1
         # every fast-committed tx delivered exactly once into the new app
         for tx in txs:
             assert app2.delivered[tx] == 1, f"{tx} delivered {app2.delivered[tx]}x"

@@ -484,7 +484,7 @@ def test_book_reconnector_heals_evicted_tcp_peer():
 
         # evict (what the scoreboard does at score_floor) ...
         a.switch.stop_peer(peer, reason="test eviction")
-        deadline = time.monotonic() + 10
+        deadline = time.monotonic() + 10 * conftest.WAIT_FACTOR
         while (
             a.switch.get_peer(b_id) is not None
             or b.switch.get_peer(a.switch.node_id) is not None

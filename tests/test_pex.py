@@ -18,7 +18,7 @@ CHAIN_ID = "test-pex"
 
 
 def wait_until(pred, timeout=30.0, poll=0.02):
-    deadline = time.monotonic() + timeout
+    deadline = time.monotonic() + timeout * conftest.WAIT_FACTOR
     while time.monotonic() < deadline:
         if pred():
             return True
@@ -79,8 +79,11 @@ def test_pex_discovers_full_mesh_from_one_seed():
         assert wait_until(
             lambda: all(n.switch.n_peers() == 3 for n in nodes), timeout=30
         ), f"peer counts: {[n.switch.n_peers() for n in nodes]}"
-        # books learned everyone's listen address
-        assert all(b.size() >= 3 for b in books)
+        # books learned everyone's listen address (the adverts travel on
+        # the links just counted: connected is a beat before advertised)
+        assert wait_until(lambda: all(b.size() >= 3 for b in books)), [
+            b.size() for b in books
+        ]
 
         # the discovered mesh actually carries traffic
         tx = b"pex=v"

@@ -65,6 +65,7 @@ import random
 import sys
 import tempfile
 import time
+import urllib.error
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -683,7 +684,20 @@ def wan_matrix_main(smoke: bool) -> dict:
                 else:
                     lats.append(lat)
             for b in range(n_bulk):
-                hashes.append(H.broadcast(net, b % n, f"{name}-bulk-{b}=v"))
+                # a client that obeys the front door: the soak admission
+                # posture grants bulk 1 tx/s per node with a burst of 2, so
+                # on a fast box a node's third bulk tx is shed with 429 +
+                # Retry-After — wait it out instead of calling it a stall
+                tx = f"{name}-bulk-{b}=v"
+                give_up = time.monotonic() + commit_wait
+                while True:
+                    try:
+                        hashes.append(H.broadcast(net, b % n, tx))
+                        break
+                    except urllib.error.HTTPError as e:
+                        if e.code != 429 or time.monotonic() > give_up:
+                            raise
+                        time.sleep(float(e.headers.get("Retry-After") or 1))
 
             # zero admitted-tx loss: every accepted hash commits on
             # EVERY node (weather may drop frames; the reliable lane +

@@ -275,24 +275,19 @@ class TxFlow:
         if verifier is not None:
             self.verifier = verifier
         elif self.config.use_device:
+            from ..verifier import ResilientVoteVerifier
+
+            # mesh-sharded verify (EngineConfig.mesh_devices): shard the
+            # vote axis across the first N devices of the default
+            # backend. Asked for N and given fewer is an error (make_mesh
+            # raises): a node configured for a slice must not come up on
+            # one device in silence.
+            mesh = None
+            if int(self.config.mesh_devices or 0) > 1:
+                from ..parallel.mesh import make_mesh
+
+                mesh = make_mesh(int(self.config.mesh_devices))
             try:
-                from ..verifier import ResilientVoteVerifier
-
-                # mesh-sharded verify (EngineConfig.mesh_devices): shard
-                # the vote axis across the first N devices of the default
-                # backend; anything short of a usable multi-device mesh
-                # (fewer devices than asked, no backend) degrades to the
-                # single-device path — decisions are identical either way
-                mesh = None
-                if int(self.config.mesh_devices or 0) > 1:
-                    try:
-                        from ..parallel.mesh import make_mesh
-
-                        mesh = make_mesh(int(self.config.mesh_devices))
-                        if mesh.size <= 1:
-                            mesh = None
-                    except Exception:
-                        mesh = None
                 # resilient by default: a device fault mid-run degrades to
                 # the scalar golden model (retry/backoff/re-probe policy,
                 # verifier.ResilientVoteVerifier) instead of erroring the
@@ -455,6 +450,7 @@ class TxFlow:
         self._warmer = None
         self._depth_ctrl = None
         self._cold_fallback_votes = 0
+        self._prewarm_failures = 0
         # last epoch rotation applied by update_state (None = never):
         # drills assert restaged (no rebuild => no recompile window) and
         # reconcile dropped/committed counts across nodes
@@ -467,24 +463,6 @@ class TxFlow:
             if self._running:
                 return
             self._running = True
-        if self.config.compilation_cache_dir:
-            # persistent XLA compilation cache: every shape this engine
-            # (or its BackgroundWarmer) compiles is banked on disk, so
-            # the next process loads instead of compiling. Must land
-            # before the first dispatch; harmless without jax.
-            import os as _os
-
-            _os.environ.setdefault(
-                "JAX_COMPILATION_CACHE_DIR", self.config.compilation_cache_dir
-            )
-            try:
-                import jax as _jax
-
-                _jax.config.update(
-                    "jax_compilation_cache_dir", self.config.compilation_cache_dir
-                )
-            except Exception:
-                pass
         if self.config.prewarm_shapes and self._shape_registry is None:
             # compile every shape the pipeline can hit BEFORE serving: a
             # cold compile inside the pipelined loop stalls the in-flight
@@ -495,7 +473,11 @@ class TxFlow:
             try:
                 self._shape_registry.prewarm(full=True)
             except Exception:
-                pass  # warmup failures degrade via ResilientVoteVerifier
+                # serving goes on (the first batch of a cold shape
+                # compiles in the loop, or degrades via
+                # ResilientVoteVerifier) but the failure is counted
+                # where pipeline_stats() readers can see it
+                self._prewarm_failures += 1
         if self.config.background_warmup and self._warm_gate is None:
             self._setup_background_warmup()
         if self.config.coalesce and self._coalescer is None:
@@ -1607,6 +1589,7 @@ class TxFlow:
             "full_batches": co.full_batches if co is not None else 0,
             "linger_flushes": co.linger_flushes if co is not None else 0,
             "cold_fallback_votes": self._cold_fallback_votes,
+            "prewarm_failures": self._prewarm_failures,
             # wide-rung ladder (wide_buckets): gate line, live verdict,
             # and how many drains actually rode the wide rungs
             "wide_from": co.wide_from if co is not None else None,

@@ -28,16 +28,19 @@ _lib = None
 _tried = False
 
 
-def _build() -> bool:
-    """Compile prep.c -> _prep.so if missing or stale. True on success."""
+def _build(force: bool = False) -> bool:
+    """Compile prep.c -> _prep.so if missing or stale (or ``force``: the
+    library that lies there is not trusted). True on success."""
     # codec.c is optional: a tree without it still builds prep.c alone
     # (sign_bytes_batch then reports unavailable via the hasattr check)
     srcs = [s for s in (_SRC, _SRC_CODEC) if os.path.exists(s)]
     if not srcs:
         return False
     try:
-        if os.path.exists(_SO) and os.path.getmtime(_SO) >= max(
-            os.path.getmtime(s) for s in srcs
+        if (
+            not force
+            and os.path.exists(_SO)
+            and os.path.getmtime(_SO) >= max(os.path.getmtime(s) for s in srcs)
         ):
             return True
     except OSError:
@@ -62,13 +65,14 @@ def _build() -> bool:
     return False
 
 
-def _load():
+def _load(rebuild: bool = False):
     global _lib, _tried
     with _lock:
-        if _tried:
+        if _tried and not rebuild:
             return _lib
         _tried = True
-        if not _build():
+        _lib = None
+        if not _build(force=rebuild):
             return None
         try:
             lib = ctypes.CDLL(_SO)
@@ -115,6 +119,33 @@ def _load():
 
 def available() -> bool:
     return _load() is not None
+
+
+def rebuild() -> None:
+    """Build ``_prep.so`` from ``prep.c`` + ``codec.c`` NOW and load it.
+
+    ``_build`` trusts a library that lies next to the sources by mtime,
+    which a copy of the tree need not keep — and git ignores the library,
+    so one that is there was built by some other run from some other
+    sources. A caller that must know what serves (chip_smoke.py) calls
+    this first: the sources git tracks are compiled over whatever lies
+    there (atomically — a concurrent loader sees the old file or the new
+    one), and a failure RAISES instead of dropping to the numpy path. The
+    node itself keeps the lazy build and the numpy fallback."""
+    lib = _load(rebuild=True)
+    if lib is None:
+        raise RuntimeError(
+            f"native prep build failed: no C compiler produced {_SO} "
+            f"from {_SRC} and {_SRC_CODEC}"
+        )
+    if not hasattr(lib, "txflow_sign_bytes_batch"):
+        raise RuntimeError(f"{_SO} was built without {_SRC_CODEC}")
+
+
+def serving() -> str:
+    """Which host-prep path this process runs: ``"native"`` once the C
+    library is loaded, else ``"numpy"``."""
+    return "native" if available() else "numpy"
 
 
 def _u8p(a: np.ndarray):

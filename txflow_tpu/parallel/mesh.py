@@ -18,40 +18,29 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 from ..ops import ed25519_batch, tally
 
 VOTE_AXIS = "votes"
 
 
-def _axis_size(axis_name: str) -> int:
-    """Static (Python-int) mesh-axis size inside a shard_map'd function.
-
-    ``jax.lax.axis_size`` only exists on newer jax; 0.4.x exposes the
-    bound frame through ``jax.core.axis_frame`` (which returns the size
-    directly on 0.4.37, a frame object with ``.size`` on other builds)."""
-    size = getattr(jax.lax, "axis_size", None)
-    if size is not None:
-        return int(size(axis_name))
-    from jax import core
-
-    frame = core.axis_frame(axis_name)
-    return int(frame if isinstance(frame, int) else frame.size)
-
-
 def make_mesh(n_devices: int | None = None, axis_name: str = VOTE_AXIS) -> Mesh:
-    """1-D mesh over the first n_devices (default: all) local devices."""
+    """1-D mesh over the first n_devices (default: all) local devices.
+
+    Asked for more devices than ``jax.devices()`` has is an error, never a
+    smaller mesh: a caller that asked for four chips and silently ran on
+    one would report a sharded run that did not happen."""
     devs = jax.devices()
     if n_devices is not None:
+        if len(devs) < n_devices:
+            raise ValueError(
+                f"make_mesh: asked for {n_devices} devices, jax.devices() "
+                f"has {len(devs)} ({devs[0].platform})"
+            )
         devs = devs[:n_devices]
-    import numpy as np
-
     return Mesh(np.array(devs), (axis_name,))
 
 
@@ -138,7 +127,7 @@ def ring_tally(stake_partial, axis_name: str = VOTE_AXIS):
     real ICI (XLA schedules each hop independently) and as the pattern
     template for future ring-style kernels.
     """
-    n = _axis_size(axis_name)
+    n = int(jax.lax.axis_size(axis_name))
     perm = [(i, (i + 1) % n) for i in range(n)]
 
     def hop(_, carry):

@@ -147,11 +147,6 @@ class EngineConfig:
     # the device the moment their shape lands. The streaming alternative
     # to prewarm_shapes' stop-the-world warmup.
     background_warmup: bool = False
-    # persistent XLA compilation cache directory (JAX_COMPILATION_CACHE_DIR):
-    # every compiled shape is banked on disk, so reruns — and background
-    # warmup walks — load instead of compile. Empty = leave the process
-    # environment alone.
-    compilation_cache_dir: str = ""
     # overlap commit side-effects (TxStore persist, ABCI execute, pool
     # purge) with the next device verify call via a per-engine committer
     # thread (SURVEY §7 hard-part 5); False = reference-faithful inline
