@@ -1,0 +1,8 @@
+"""Host prep: ``pipeline_stats()["prep_s"]`` spent in the window over the
+votes routed in it."""
+
+
+def read(ctx):
+    if ctx["votes"] <= 0:
+        return None
+    return 1e6 * ctx["pipeline"]["prep_s"] / ctx["votes"]
