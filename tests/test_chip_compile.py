@@ -103,6 +103,28 @@ def _compile_single(topo, b: int, b_slots: int):
     return jax.jit(tally.compact_step_packed()).lower(*args).compile()
 
 
+def test_packed_step_is_named_for_the_profiler():
+    """The step's program is found by name: ``jit_txflow_verify_tally``,
+    with the four scopes in its operations' debug info. Lowered only
+    (nothing compiles or runs), for the host's own backend, at the
+    smallest rung's shapes; first in the file, before ``chip_compiler``
+    steers traces to the chip's formulation."""
+    import jax
+
+    from txflow_tpu.ops import tally
+
+    b = chip_smoke.BUCKETS[0]
+    args = [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in _step_arg_shapes(b, b)]
+    lowered = tally.compact_step_packed_jit().lower(*args)
+    text = lowered.as_text(debug_info=True)
+    assert "module @jit_txflow_verify_tally" in text
+    for scope in ("decompress", "double_scalar_mul", "encode_compare", "tally"):
+        assert f"/{scope}/" in text, scope
+    # the other two jitted forms carry names too (no program is jit_f)
+    assert tally.compact_step().__name__ == "txflow_verify_tally_unpacked"
+    assert tally.verify_and_tally(None).__name__ == "txflow_verify_tally_generic"
+
+
 def _fits_v5e(compiled) -> None:
     mem = compiled.memory_analysis()
     total = (

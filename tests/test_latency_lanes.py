@@ -485,14 +485,16 @@ def test_critical_path_lane_and_spec_attribution():
     assert "linger[prio=" in line
     assert "spec_saved=" in line
 
-    # a pre-lane digest (merged "linger" family only) still attributes
-    cp_legacy = critical_path(
+    # a single-lane run (bulk family only, no spec) attributes the same
+    # way; the merged "linger" family went with PR 28 (nothing records it)
+    cp_bulk = critical_path(
         {"prep_s": 1.0, "route_s": 0.0, "dispatch_wait_s": 0.0},
-        {"latency_ms": {"linger": {"sum_ms": 1500.0}}},
+        {"latency_ms": {"linger_bulk": {"sum_ms": 1500.0},
+                        "linger": {"sum_ms": 9000.0}}},
     )
-    assert cp_legacy["linger_s"] == pytest.approx(1.5)
-    assert "linger_prio_s" not in cp_legacy
-    assert "spec_saved_s" not in cp_legacy
+    assert cp_bulk["linger_s"] == pytest.approx(1.5)
+    assert cp_bulk["linger_bulk_s"] == pytest.approx(1.5)
+    assert "spec_saved_s" not in cp_bulk
 
 
 # ---- unit: latency-bank supersede contract ----------------------------

@@ -507,6 +507,13 @@ def test_pool_trace_span_parity():
         names = [s["name"] for s in p.tracer.spans()]
         assert names == ["vote_ingest", "vote_ingest"]
         assert p.tracer.open_count() == 0
+        # each accepted vote of a tx not seen before also starts that
+        # tx's vote_wait, at the ingest instant (a dup starts nothing)
+        firsts = {s["tx"]: s["start"] for s in p.tracer.spans()}
+        assert p.tracer._first_votes == firsts and len(firsts) == 2
+        # and the pool stamps its first new vote once, for pickup_wait
+        assert 0.0 < p.take_first_new() <= min(firsts.values())
+        assert p.take_first_new() == 0.0
     assert [s["tx"] for s in a.tracer.spans()] == [
         s["tx"] for s in b.tracer.spans()
     ]

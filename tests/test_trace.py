@@ -107,7 +107,9 @@ def test_anchor_latch_and_fifo_bound():
     tr.anchor(_hash(42), 1.0)
     tr.anchor(_hash(42), 50.0)
     tr.latch(_hash(42), t=60.0)
-    assert tr.spans()[-1]["start"] == 42.0  # the first anchor won
+    # spans() is ordered by start (PR 28), so find the span by its tx
+    (s42,) = [s for s in tr.spans() if s["tx"] == _hash(42)]
+    assert s42["start"] == 42.0  # the first anchor won
 
 
 def test_null_tracer_and_config_switch():
@@ -123,6 +125,7 @@ def test_null_tracer_and_config_switch():
     n.finish(0)
     n.abandon(0)
     n.anchor(_hash(1))
+    assert n.anchored(_hash(1)) is None
     n.latch(_hash(1))
     assert n.open_count() == 0 and n.spans() == []
     assert n.digest()["enabled"] is False
@@ -246,12 +249,14 @@ def test_critical_path_attribution():
     stats = {"prep_s": 2.0, "lock_wait_s": 0.5, "route_s": 1.0,
              "dispatch_wait_s": 6.0}
     digest = {"latency_ms": {
-        "linger": {"sum_ms": 1500.0, "p50": 1.0},
+        "linger_bulk": {"sum_ms": 1500.0, "p50": 1.0},
         "e2e": {"p50": 30.0},
         "vote_ingest": {"p50": 0.0},
         "host_prep": {"p50": 2.0},
-        "device_verify": {"p50": 5.0},
-        "quorum_latch": {"p50": 1.0},
+        "dispatch": {"p50": 0.5},
+        "device_busy": {"p50": 4.5},
+        "route": {"p50": 1.0},
+        "quorum_latch": {"p50": 0.7},  # a child of route: not summed
         "commit_apply": {"p50": 2.0},
     }}
     cp = critical_path(stats, digest)
@@ -349,20 +354,26 @@ def test_localnet_trace_parity_and_export(tmp_path):
     dumps_ser, _, _ = _run_traced_net(1, b"ts")
 
     def families(dumps):
-        # linger excluded: deadline flushes are timing-dependent.
+        # linger_*, pool_wait and gc_pause excluded: deadline flushes,
+        # an engine that found the pool empty, and the collector are
+        # timing-dependent.
         # sync_fetch/sync_verify/sync_apply excluded: a node that
         # briefly lags its peers catches up via the sync channel —
         # whether that happens is scheduler timing and topology, not
         # engine mode, and it emits all three families together
         return {
             s["name"] for d in dumps for s in d["spans"]
-        } - {"linger", "sync_fetch", "sync_verify", "sync_apply"}
+        } - {"linger_bulk", "linger_prio", "pool_wait", "gc_pause",
+             "sync_fetch", "sync_verify", "sync_apply"}
 
     fam_pipe, fam_ser = families(dumps_pipe), families(dumps_ser)
     assert fam_pipe == fam_ser
     assert {
-        "admission", "mempool_ingest", "vote_ingest", "host_prep",
-        "device_verify", "quorum_latch", "commit_apply", "e2e",
+        "admission", "mempool_ingest", "sign_wait", "sign_walk",
+        "vote_ingest", "vote_wait", "pickup_wait", "host_prep", "lock_wait",
+        "dispatch",
+        "device_busy", "collect_wait", "route", "route_tally",
+        "route_purge", "quorum_latch", "commit_apply", "publish", "e2e",
     } <= fam_pipe
 
     # merged view: one tx's spans cover admission -> commit_apply in
@@ -429,3 +440,51 @@ def test_trace_overhead_gate():
         f"tracing cost {per_vote * 1e6:.2f}us/vote is {ratio:.1%} of a "
         f"scalar verify ({per_verify * 1e3:.2f}ms) — over the 3% budget"
     )
+
+
+def test_stage_record_overhead_gate():
+    """The step record is always on: ten stage records a step, each two
+    clock reads, a counter, a Prometheus counter, a ring store with its
+    histogram and an inactive profiler annotation, must cost under 50 us
+    (0.01% of a 350 ms flood cycle, under 0.5% of an 11.5 ms served
+    commit). The best of several rounds: the gate is on the code's cost,
+    not on what else runs on the box."""
+    from txflow_tpu.engine.txflow import _Stage, _annotation_cls
+    from txflow_tpu.node import LocalNet
+    from txflow_tpu.trace.tracer import SPAN_DISPATCH, SPAN_PREP, SPAN_ROUTE
+
+    assert _annotation_cls() is not None  # JAX is here: annotations are entered
+    net = LocalNet(1, use_device_verifier=False)  # never started: just an engine
+    eng = net.nodes[0].txflow
+    assert eng.tracer.active and eng.tracer.metrics is not None
+    # the gate is on what a node pays: a plain lock, where the tests'
+    # lock audit (conftest) hands the tracer an instrumented one
+    import threading
+
+    eng.tracer._lk = threading.Lock()
+    from txflow_tpu.utils.clock import monotonic
+
+    names = (SPAN_PREP, SPAN_DISPATCH, SPAN_ROUTE)
+    best = clock = float("inf")
+    for _ in range(30):
+        t0 = time.perf_counter()
+        for i in range(100):  # ten steps of ten stages
+            with _Stage(eng, names[i % 3], step=i, votes=4096):
+                pass
+        best = min(best, (time.perf_counter() - t0) / 10)
+        t0 = time.perf_counter()
+        for _i in range(1000):
+            monotonic()
+        clock = min(clock, (time.perf_counter() - t0) / 1000)
+    # 50 us where a clock read through the seam costs its usual 0.1 us;
+    # the shared box sometimes runs a whole process half again slower,
+    # and then the bound moves with the clock read measured beside it
+    bound = 50e-6 if clock < 0.12e-6 else 520 * clock
+    assert best < bound, (
+        f"ten stage records cost {best * 1e6:.1f} us "
+        f"(bound {bound * 1e6:.1f}, clock read {clock * 1e6:.3f} us)"
+    )
+    stats = eng.pipeline_stats()
+    assert stats["prep_s"] > 0 and stats["route_s"] > 0  # every sink was fed
+    assert len(eng.tracer.spans()) == 3000
+    print(f"ten stage records: {best * 1e6:.1f} us, clock read {clock * 1e6:.3f} us")

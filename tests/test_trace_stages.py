@@ -1,0 +1,588 @@
+"""Stage spans (PR 28): every engine step leaves one span per stage under
+its step id, the stages tile the engine thread, ``pipeline_stats()`` reads
+the same clock reads the spans do (one record site), ``device_busy`` spans
+of one verifier never overlap, a served tx's waits are spans in order, the
+step record survives a flood of per-tx spans, and the collector's pauses
+are recorded through one ``gc.callbacks`` entry a process.
+
+Scalar verifier, small ``LocalNet``, CPU.
+"""
+
+import conftest  # noqa: F401
+
+import base64
+import gc
+import hashlib
+import json
+import socket
+import struct
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from txflow_tpu.node import LocalNet
+from txflow_tpu.parallel.staging import StagingRing
+from txflow_tpu.trace.export import to_chrome_trace
+from txflow_tpu.trace.tracer import (
+    SPAN_COMMIT,
+    SPAN_ORDER,
+    SPAN_PREP,
+    STAGE_SPANS,
+    Tracer,
+    _GC_HOOK,
+)
+from txflow_tpu.types.priv_validator import MockPV
+from txflow_tpu.types.validator import Validator, ValidatorSet
+from txflow_tpu.utils.config import TraceConfig, test_config as make_test_config
+from txflow_tpu.verifier import ScalarVoteVerifier, VerifyTicket
+
+STEP_STAGES = ("host_prep", "dispatch", "device_busy", "collect_wait", "route")
+ENGINE_THREAD = ("pool_wait", "linger_bulk", "linger_prio", "host_prep", "dispatch",
+                 "collect_wait", "route")
+
+
+def _hash(tx: bytes) -> str:
+    return hashlib.sha256(tx).hexdigest().upper()
+
+
+def _one_validator_net(verifier_factory=None, *, rpc=False, buckets=(8, 64), **trace):
+    """One validator whose own vote is the quorum: the whole served path
+    in one process with few threads. The scalar verifier gets a bucket
+    ladder so the engine runs its coalescer (as the benchmark's does)."""
+    cfg = make_test_config()
+    cfg.trace.sample_rate = 1
+    for key, value in trace.items():
+        setattr(cfg.trace, key, value)
+    pv = MockPV(hashlib.sha256(b"trace-stages-validator").digest())
+    val_set = ValidatorSet([Validator.from_pub_key(pv.get_pub_key(), 10)])
+    verifier = (verifier_factory or ScalarVoteVerifier)(val_set)
+    if buckets:
+        verifier.buckets = tuple(buckets)
+    return LocalNet(1, priv_vals=[pv], config=cfg, use_device_verifier=False,
+                    verifier=verifier, rpc=rpc)
+
+
+def _run(net, txs, gap_s=0.0):
+    for tx in txs:
+        net.broadcast_tx(tx)
+        if gap_s:
+            time.sleep(gap_s)
+    assert net.wait_all_committed(txs, timeout=60.0)
+    node = net.nodes[0]
+    deadline = time.monotonic() + 10.0
+    while not node.txflow.commits_drained() or node.tracer.open_count():
+        assert time.monotonic() < deadline, node.tracer.open_count()
+        time.sleep(0.01)
+
+
+def _by_name(spans, name):
+    return [s for s in spans if s["name"] == name]
+
+
+def _total(spans, name):
+    return sum(s["end"] - s["start"] for s in _by_name(spans, name))
+
+
+def _served_run():
+    net = _one_validator_net()
+    net.start()
+    try:
+        _run(net, [b"burst-%d=v" % i for i in range(24)])
+        _run(net, [b"spaced-%d=v" % i for i in range(16)], gap_s=0.02)
+    finally:
+        net.stop()
+    node = net.nodes[0]
+    return node.tracer.spans(), node.txflow.pipeline_stats(), node.tracer.digest()
+
+
+@pytest.fixture(scope="module")
+def served_run():
+    """One run shared by the tests that only read it: 40 txs, some in
+    bursts (several a step) and some spaced (one a step, linger flushes)."""
+    return _served_run()
+
+
+def test_every_step_has_one_span_per_stage(served_run):
+    spans, stats, _ = served_run
+    steps = {s["step"] for s in spans if s["name"] in STEP_STAGES and s["step"]}
+    assert len(steps) == stats["steps"] > 0
+    for family in STEP_STAGES + ("lock_wait", "route_tally", "route_purge"):
+        ids = [s["step"] for s in _by_name(spans, family) if s["step"]]
+        assert sorted(ids) == sorted(steps), family  # exactly one a step
+    # the committer thread applies the commits: the engine thread has no
+    # inline commit loop to time (test_inline_commits_are_route_commit)
+    assert not _by_name(spans, "route_commit")
+    for s in spans:
+        if s["name"] in STAGE_SPANS and s["name"] != "gc_pause":
+            assert s["tx"] == ""
+    # a per-tx span the engine records names the step that decided it
+    for family in ("quorum_latch", "commit_apply"):
+        decided = _by_name(spans, family)
+        assert len(decided) == 40
+        assert all(s["step"] in steps and s["tx"] for s in decided), family
+    # and the step's children lie inside its route span
+    route = {s["step"]: s for s in _by_name(spans, "route")}
+    for family in ("route_tally", "route_commit", "route_purge", "quorum_latch"):
+        for s in _by_name(spans, family):
+            parent = route[s["step"]]
+            assert parent["start"] <= s["start"] and s["end"] <= parent["end"], family
+
+
+def test_engine_thread_stages_tile(served_run):
+    """What the engine thread does, it does one thing at a time: pool_wait,
+    host_prep, dispatch, collect_wait and route never overlap. A lane's
+    linger hold is the one span that is no stretch of the thread's own
+    time: it runs from the first held vote to the flush, and while it runs
+    the thread may collect and route the step before. Laid together they
+    cover the thread's wall time: what lies between two stages is the
+    loop's own bookkeeping, a tenth of a millisecond a step, unless the
+    machine took the thread off its core there. That can happen on a
+    loaded machine, so the share is asked of the best of four runs; the
+    order and the overlaps are asked of every run."""
+    shares = []
+    for attempt in range(4):
+        spans = (served_run if attempt == 0 else _served_run())[0]
+        shares.append(_check_tiling(spans))
+        if shares[-1] >= 0.98:
+            break
+    assert max(shares) >= 0.98, shares
+
+
+def _check_tiling(spans) -> float:
+    mine = sorted((s for s in spans if s["name"] in ENGINE_THREAD),
+                  key=lambda s: s["start"])
+    assert {s["name"] for s in mine} >= {"pool_wait", "linger_bulk", "host_prep",
+                                         "dispatch", "collect_wait", "route"}
+    work = [s for s in mine if not s["name"].startswith("linger")]
+    for a, b in zip(work, work[1:]):
+        assert b["start"] >= a["end"] - 1e-9, (a, b)  # one thread: no overlap
+    for a, b in zip(mine, mine[1:]):
+        if a["name"].startswith("linger") and b["start"] < a["end"] - 1e-9:
+            assert b["name"] in ("collect_wait", "route"), (a, b)
+    covered, cursor = 0.0, mine[0]["start"]
+    for s in mine:  # the union: a hold may lie over the step before
+        if s["end"] > cursor:
+            covered += s["end"] - max(s["start"], cursor)
+            cursor = s["end"]
+    return covered / (mine[-1]["end"] - mine[0]["start"])
+
+
+def test_pickup_wait_runs_from_the_pool_to_the_batch_taken_up(served_run):
+    """One a step at most, under the step's id: from the first vote the
+    pool accepted since the drain before to the hold's start (the spaced
+    txs: every step a linger flush) or to host_prep (a full bucket)."""
+    spans, _, _ = served_run
+    pickups = _by_name(spans, "pickup_wait")
+    prep = {s["step"]: s for s in _by_name(spans, "host_prep")}
+    hold = {s["step"]: s for s in _by_name(spans, "linger_bulk")}
+    ingest = sorted(s["start"] for s in _by_name(spans, "vote_ingest"))
+    assert len(pickups) >= 8 and len({s["step"] for s in pickups}) == len(pickups)
+    for s in pickups:
+        assert s["tx"] == "" and s["step"] in prep
+        taken_up = hold[s["step"]]["start"] if s["step"] in hold else prep[s["step"]]["start"]
+        assert s["end"] == taken_up and s["start"] < s["end"]
+        # it starts at a vote's insert (vote_ingest marks the instant
+        # just after, on the inserting thread), and after the drain of
+        # the step before
+        assert any(0.0 <= t - s["start"] < 0.05 for t in ingest), s
+        before = [p for k, p in prep.items() if k < s["step"]]
+        assert all(s["start"] >= p["start"] for p in before)
+    # the spaced txs: a thread hop from the signer to an idle engine
+    spaced = [s for s in pickups if s["step"] in hold]
+    assert len(spaced) >= 6  # a loaded machine merges spaced txs into fewer steps
+    assert sorted(s["end"] - s["start"] for s in spaced)[len(spaced) // 2] < 0.01
+
+
+def test_vote_wait_is_each_txs_own_wait_for_its_step(served_run):
+    """Per tx: from its first vote in the pool (the vote_ingest instant)
+    to the host_prep of the step that drained it, under that step's id.
+    The burst's steps carry several txs: each has its own, and the one
+    whose vote came last waited least."""
+    spans, _, _ = served_run
+    waits = _by_name(spans, "vote_wait")
+    assert len(waits) == 40 and len({s["tx"] for s in waits}) == 40
+    prep = {s["step"]: s for s in _by_name(spans, "host_prep")}
+    ingest = {s["tx"]: s["start"] for s in reversed(_by_name(spans, "vote_ingest"))}
+    decided = {s["tx"]: s["step"] for s in _by_name(spans, "commit_apply")}
+    by_step: dict[int, list] = {}
+    for s in waits:
+        assert s["start"] == ingest[s["tx"]] and s["end"] == prep[s["step"]]["start"]
+        assert s["step"] == decided[s["tx"]]  # one validator: the step that drains decides
+        by_step.setdefault(s["step"], []).append(s)
+    shared = [v for v in by_step.values() if len(v) > 1]
+    assert shared  # the burst
+    for group in shared:
+        assert len({s["end"] for s in group}) == 1
+        assert len({s["start"] for s in group}) == len(group)
+
+
+def test_pool_stamps_the_first_new_item_once():
+    from txflow_tpu.pool.base import IngestLogPool
+
+    pool = IngestLogPool()
+    assert pool.take_first_new() == 0.0
+    with pool._mtx:
+        t0 = time.monotonic()
+        pool._log_append(b"a")
+        pool._log_append_quiet(b"b")
+        pool._log_notify()
+    first = pool.take_first_new()
+    assert t0 <= first <= time.monotonic()
+    assert pool.take_first_new() == 0.0  # taken: the next insert starts over
+    with pool._mtx:
+        pool._log_append_quiet(b"c")
+        pool._log_notify()
+    assert pool.take_first_new() > first
+
+
+def test_inline_commits_are_route_commit():
+    """pipeline_commits off: the engine thread applies the commits itself,
+    and that loop is the step's route_commit, between route_tally and
+    route_purge, with each tx's commit_apply inside it."""
+    cfg = make_test_config()
+    cfg.trace.sample_rate = 1
+    cfg.engine.pipeline_commits = False
+    pv = MockPV(hashlib.sha256(b"trace-stages-validator").digest())
+    val_set = ValidatorSet([Validator.from_pub_key(pv.get_pub_key(), 10)])
+    verifier = ScalarVoteVerifier(val_set)
+    verifier.buckets = (8, 64)
+    net = LocalNet(1, priv_vals=[pv], config=cfg, use_device_verifier=False, verifier=verifier)
+    net.start()
+    try:
+        _run(net, [b"inline-%d=v" % i for i in range(12)], gap_s=0.005)
+    finally:
+        net.stop()
+    spans = net.nodes[0].tracer.spans()
+    commit = {s["step"]: s for s in _by_name(spans, "route_commit")}
+    tally = {s["step"]: s for s in _by_name(spans, "route_tally")}
+    purge = {s["step"]: s for s in _by_name(spans, "route_purge")}
+    applied = _by_name(spans, "commit_apply")
+    assert len(applied) == 12 and {s["step"] for s in applied} == set(commit)
+    for step, s in commit.items():
+        assert tally[step]["end"] == s["start"] and s["end"] == purge[step]["start"]
+    for s in applied:
+        assert commit[s["step"]]["start"] <= s["end"] <= commit[s["step"]]["end"]
+
+
+def test_pipeline_stats_are_the_sums_of_their_families(served_run):
+    """One record site a stage: the counters and the spans come from the
+    same two clock reads (pipeline_stats rounds to 1e-4 s)."""
+    spans, stats, digest = served_run
+    assert digest["dropped"] == 0 and digest["stage_dropped"] == 0
+    assert stats["prep_s"] == pytest.approx(
+        _total(spans, "host_prep") + _total(spans, "dispatch"), abs=1e-4)
+    assert stats["route_s"] == pytest.approx(_total(spans, "route"), abs=1e-4)
+    assert stats["dispatch_wait_s"] == pytest.approx(_total(spans, "collect_wait"), abs=1e-4)
+    assert stats["device_busy_s"] == pytest.approx(_total(spans, "device_busy"), abs=1e-4)
+    assert stats["lock_wait_s"] == pytest.approx(_total(spans, "lock_wait"), abs=1e-4)
+    assert stats["active_s"] == pytest.approx(
+        stats["prep_s"] + stats["route_s"] + stats["dispatch_wait_s"], abs=3e-4)
+    assert stats["idle_gap_s"] == pytest.approx(
+        max(stats["active_s"] - stats["device_busy_s"], 0.0), abs=2e-4)
+
+
+def test_export_gives_the_stage_families_tracks_and_the_step(served_run):
+    spans, _, _ = served_run
+    doc = to_chrome_trace([{"node": "n0", "base_wall_ns": 0, "base_mono": 0.0, "spans": spans}])
+    events = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+    assert len(events) == len(spans)
+    for e in events:
+        assert e["tid"] == SPAN_ORDER.index(e["name"]) + 1  # no hashed fallback track
+        assert "step" in e["args"] and "tx" in e["args"]
+    order = [SPAN_ORDER.index(n) for n in (
+        "rpc_ingest", "sign_wait", "sign_walk", "pool_wait", "pickup_wait", "linger_bulk",
+        "host_prep",
+        "dispatch", "device_busy", "collect_wait", "route", "publish")]
+    assert order == sorted(order)  # tracks in engine order
+    stepped = [e for e in events if e["name"] == "route"]
+    assert stepped and all(e["args"]["step"] > 0 for e in stepped)
+
+
+# -- device_busy: disjoint, ending at the ring thread's stamp --
+
+
+class _SlowArray:
+    """Stands for a device array: fetching it takes a while."""
+
+    def __init__(self, seconds: float):
+        self._seconds = seconds
+
+    def __array__(self, dtype=None, copy=None):
+        time.sleep(self._seconds)
+        return np.zeros(1, np.int32)
+
+
+class _RingTicket(VerifyTicket):
+    def __init__(self, result, ring, slot, log):
+        self._result, self._ring, self._slot, self._log = result, ring, slot, log
+
+    def result(self):
+        self._ring.result(self._slot)
+        self.ready_t = self._slot.ready_t
+        self._log.append(self.ready_t)
+        return self._result
+
+
+class _RingVerifier(ScalarVoteVerifier):
+    """Scalar verdicts, but the result comes back the way the device
+    verifier's does: through a staging ring whose thread stamps ready_t."""
+
+    def __init__(self, val_set):
+        super().__init__(val_set)
+        self.ring = StagingRing(2, name="test-staging")
+        self.ready_log: list[float] = []
+
+    def submit(self, msgs, sigs, val_idx, tx_slot, n_slots, **kw):
+        res = self.verify_and_tally(msgs, sigs, val_idx, tx_slot, n_slots, **kw)
+        return _RingTicket(res, self.ring, self.ring.submit(_SlowArray(0.003)), self.ready_log)
+
+
+class _PlainTicket(VerifyTicket):
+    """A ticket that carries no stamp, as a device ticket without a
+    staging ring (``staging_ring`` < 2): the collect time stands in."""
+
+    def __init__(self, result):
+        self._result = result
+
+    def result(self):
+        time.sleep(0.002)  # the blocking readback
+        return self._result
+
+
+class _PlainVerifier(ScalarVoteVerifier):
+    def submit(self, msgs, sigs, val_idx, tx_slot, n_slots, **kw):
+        return _PlainTicket(self.verify_and_tally(msgs, sigs, val_idx, tx_slot, n_slots, **kw))
+
+
+@pytest.mark.parametrize("ring", [True, False], ids=["staging-ring", "no-ring"])
+def test_device_busy_spans_never_overlap(ring):
+    net = _one_validator_net(_RingVerifier if ring else _PlainVerifier)
+    net.start()
+    try:
+        _run(net, [b"busy-%d=v" % i for i in range(30)], gap_s=0.001)
+    finally:
+        net.stop()
+        if ring:
+            net.nodes[0].txflow.verifier.ring.close()
+    node = net.nodes[0]
+    spans = node.tracer.spans()
+    busy = sorted(_by_name(spans, "device_busy"), key=lambda s: s["start"])
+    assert len(busy) == node.txflow.pipeline_stats()["steps"] >= 2
+    for a, b in zip(busy, busy[1:]):
+        assert b["start"] >= a["end"], (a, b)
+    dispatch = {s["step"]: s for s in _by_name(spans, "dispatch")}
+    collect = {s["step"]: s for s in _by_name(spans, "collect_wait")}
+    for s in busy:
+        assert s["start"] >= dispatch[s["step"]]["end"]  # never before its dispatch
+        assert s["end"] <= collect[s["step"]]["end"] + 1e-9
+    if ring:
+        # the span ends when the ring's thread had the bytes, not when
+        # the engine got round to collecting them
+        assert sorted(s["end"] for s in busy) == sorted(node.txflow.verifier.ready_log)
+        assert all(s["end"] - s["start"] >= 0.0029 for s in busy)
+    else:
+        assert all(s["end"] == pytest.approx(collect[s["step"]]["end"]) for s in busy)
+
+
+class _GatedTicket(VerifyTicket):
+    def __init__(self, result, gate):
+        self._result, self._gate = result, gate
+
+    def result(self):
+        self._gate.wait(10.0)
+        return self._result
+
+
+class _GatedVerifier(ScalarVoteVerifier):
+    def __init__(self, val_set):
+        super().__init__(val_set)
+        self.gate = threading.Event()
+        self.submitted = threading.Event()
+
+    def submit(self, msgs, sigs, val_idx, tx_slot, n_slots, **kw):
+        res = self.verify_and_tally(msgs, sigs, val_idx, tx_slot, n_slots, **kw)
+        self.submitted.set()
+        return _GatedTicket(res, self.gate)
+
+
+def test_stop_with_a_ticket_in_flight_leaves_no_open_span():
+    net = _one_validator_net(_GatedVerifier)
+    node = net.nodes[0]
+    net.start()
+    try:
+        net.broadcast_tx(b"inflight=v")
+        assert node.txflow.verifier.submitted.wait(10.0)
+        deadline = time.monotonic() + 5.0
+        while node.tracer.open_count() == 0:  # the step's device_busy is open
+            assert time.monotonic() < deadline
+            time.sleep(0.005)
+        threading.Timer(0.2, node.txflow.verifier.gate.set).start()
+    finally:
+        net.stop()  # drains the tail: collects and routes the ticket
+    assert node.tracer.open_count() == 0
+    spans = node.tracer.spans()
+    assert len(_by_name(spans, "device_busy")) == 1
+    assert node.txflow.is_tx_committed(_hash(b"inflight=v"))
+
+
+# -- the served tx's waits --
+
+
+def _ws_subscribe(addr):
+    host, port = addr
+    s = socket.create_connection((host, port), timeout=30)
+    key = base64.b64encode(b"0123456789abcdef").decode()
+    s.sendall((f"GET /websocket HTTP/1.1\r\nHost: {host}\r\nUpgrade: websocket\r\n"
+               f"Connection: Upgrade\r\nSec-WebSocket-Key: {key}\r\n"
+               "Sec-WebSocket-Version: 13\r\n\r\n").encode())
+    buf = b""
+    while b"\r\n\r\n" not in buf:
+        buf += s.recv(1024)
+    rest = [buf.split(b"\r\n\r\n", 1)[1]]
+
+    def read_exact(n):
+        out = b""
+        while len(out) < n:
+            if not rest[0]:
+                rest[0] = s.recv(4096)
+                if not rest[0]:
+                    raise ConnectionError("closed")
+            out, rest[0] = out + rest[0][: n - len(out)], rest[0][n - len(out):]
+        return out
+
+    def read_text():
+        _, b1 = read_exact(2)
+        n = b1 & 0x7F
+        if n == 126:
+            (n,) = struct.unpack(">H", read_exact(2))
+        return json.loads(read_exact(n))
+
+    payload = json.dumps({"subscribe": "Tx"}).encode()
+    mask = b"\x01\x02\x03\x04"
+    s.sendall(bytes([0x81, 0x80 | len(payload)]) + mask
+              + bytes(c ^ mask[i % 4] for i, c in enumerate(payload)))
+    assert read_text() == {"subscribed": "Tx"}
+    return s, read_text
+
+
+@pytest.mark.parametrize("subscribed", [True, False], ids=["websocket", "nobody-listens"])
+def test_served_tx_waits_are_spans_in_order(subscribed):
+    import urllib.request
+
+    net = _one_validator_net(rpc=True)
+    node = net.nodes[0]
+    net.start()
+    try:
+        host, port = node.rpc.addr
+        sock = read_text = None
+        if subscribed:
+            sock, read_text = _ws_subscribe(node.rpc.addr)
+        txs = [b"served-%d=v" % i for i in range(6)]
+        for tx in txs:
+            urllib.request.urlopen(
+                f"http://{host}:{port}/broadcast_tx?tx=0x{tx.hex()}", timeout=30).read()
+            if subscribed:
+                assert read_text()["hash"] == _hash(tx)
+            time.sleep(0.04)  # under the front door's 50 tx/s bulk rate: no 429
+        _run(net, [])  # waits for the drain and for every span to close
+        assert net.wait_all_committed(txs, timeout=30.0)
+        if sock is not None:
+            sock.close()
+    finally:
+        net.stop()
+    spans = node.tracer.spans()
+    for tx in txs:
+        mine = {s["name"]: s for s in spans if s["tx"] == _hash(tx)}
+        chain = [mine[n] for n in ("rpc_ingest", "sign_wait", "sign_walk", "publish")]
+        for a, b in zip(chain, chain[1:]):
+            assert a["start"] <= b["start"] and a["end"] <= b["start"] + 1e-9, (a, b)
+        assert mine["rpc_ingest"]["end"] == mine["sign_wait"]["start"]  # the insert
+        assert mine["sign_wait"]["end"] == mine["sign_walk"]["start"]
+        # admission is rpc_ingest's child; the step that carried the quorum
+        # sits between the sign walk and the publish
+        assert mine["rpc_ingest"]["start"] <= mine["admission"]["start"]
+        assert mine["admission"]["end"] <= mine["rpc_ingest"]["end"]
+        step = mine["commit_apply"]["step"]
+        route = next(s for s in _by_name(spans, "route") if s["step"] == step)
+        prep = next(s for s in _by_name(spans, "host_prep") if s["step"] == step)
+        assert mine["sign_walk"]["start"] <= prep["start"]
+        assert route["start"] <= mine["publish"]["start"]
+    assert node.tracer.open_count() == 0
+
+
+# -- the two rings --
+
+
+def test_per_tx_spans_do_not_evict_a_stage_span():
+    tr = Tracer(TraceConfig(sample_rate=1))  # the default ring_capacity, 8,192
+    tr.span("", SPAN_PREP, 1.0, 2.0, 7)
+    for i in range(20_000):
+        tr.span(_hash(b"%d" % i), SPAN_COMMIT, 3.0 + i, 3.5 + i, 8)
+    spans = tr.spans()
+    assert spans[0] == {"tx": "", "name": SPAN_PREP, "start": 1.0, "end": 2.0, "step": 7}
+    assert len(spans) == 1 + tr.capacity
+    digest = tr.digest()
+    assert digest["dropped"] == 20_000 - tr.capacity and digest["stage_dropped"] == 0
+    assert digest["recorded"] == 20_001
+    assert tr.dump()["spans"][1]["step"] == 8
+    # and the stage ring wraps on its own
+    for i in range(tr.capacity + 5):
+        tr.span("", SPAN_PREP, 10.0 + i, 10.5 + i, i)
+    assert tr.digest()["stage_dropped"] == 6
+
+
+# -- the NullTracer and the collector's hook --
+
+
+def _hooks():
+    return [cb for cb in gc.callbacks if cb is _GC_HOOK]
+
+
+def test_null_tracer_no_spans_counters_advance_no_gc_hook():
+    net = _one_validator_net(enabled=False)
+    node = net.nodes[0]
+    before = list(gc.callbacks)
+    net.start()
+    try:
+        assert gc.callbacks == before  # no hook for the NullTracer
+        _run(net, [b"null-%d=v" % i for i in range(8)])
+        stats = node.txflow.pipeline_stats()
+    finally:
+        net.stop()
+    assert not node.tracer.active and node.tracer.spans() == []
+    assert stats["steps"] > 0 and stats["prep_s"] > 0 and stats["route_s"] > 0
+    # the scalar verifier works inline, inside dispatch: its tickets are
+    # ready as they are built, so nothing reads as device time
+    assert stats["device_busy_s"] == 0 and stats["overlap_ratio"] == 0
+    assert gc.callbacks == before
+
+
+def test_gc_pause_spans_and_one_hook_a_process():
+    before = list(gc.callbacks)
+    assert _hooks() == []
+    cfg = make_test_config()
+    net = LocalNet(2, config=cfg, use_device_verifier=False)
+    was_enabled = gc.isenabled()
+    gc.disable()  # only the collection below: the count is exact
+    try:
+        net.start()
+        assert len(_hooks()) == 1  # one entry however many nodes
+        net.broadcast_tx(b"gc=v")
+        assert net.wait_all_committed([b"gc=v"], timeout=60.0)
+        gc.collect()
+        pauses = [_by_name(n.tracer.spans(), "gc_pause") for n in net.nodes]
+        net.nodes[0].stop()
+        assert len(_hooks()) == 1  # the other node still runs
+    finally:
+        net.stop()
+        if was_enabled:
+            gc.enable()
+    assert gc.callbacks == before  # as they found it
+    for mine in pauses:
+        assert [s["tx"] for s in mine] == ["gen2"]
+        assert mine[0]["end"] > mine[0]["start"] and mine[0]["step"] == 0
+    gc.collect()  # after stop: nobody records
+    assert len(_by_name(net.nodes[1].tracer.spans(), "gc_pause")) == 1
+    assert "gc_pause" in net.nodes[1].tracer.digest().get("latency_ms", {"gc_pause": 1})

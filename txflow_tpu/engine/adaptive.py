@@ -11,8 +11,12 @@ static with a ROADMAP note).
 ``AdaptiveDepthController`` closes that loop from the engine's own
 pipeline accounting. The engine calls ``observe()`` once per collected
 ticket with its CUMULATIVE busy/active counters (TxFlow._pipe_busy_s /
-_pipe_active_s — busy is the unioned [submit, collect] device window,
-active the engine's prep+wait+route wall time); the controller windows
+_pipe_active_s — busy is the sum of the device_busy spans, each from a
+step's dispatch to its result usable on the host as the staging ring's
+thread stamps it (the device's time plus that thread's wait for the
+interpreter lock), no longer the [submit, collect] window that also
+counted the engine's own lateness (PR 28); active the engine's
+prep+wait+route wall time); the controller windows
 them into per-``window``-steps deltas and steers:
 
 - window overlap < ``grow_below``: the device sat idle while the engine

@@ -15,6 +15,7 @@ import time
 from ..abci.proxy import AppConnConsensus
 from ..analysis.lockgraph import make_lock, sanctioned_blocking
 from ..pool.mempool import Mempool
+from ..trace.tracer import NULL_TRACER, SPAN_PUBLISH
 from ..utils import failpoints
 from ..utils.events import EventBus, EventDataTx, EventTx
 from ..utils.metrics import TxFlowMetrics
@@ -48,6 +49,9 @@ class TxExecutor:
         # every queued commit event has actually reached the bus
         self._ev_enqueued = 0
         self._ev_published = 0
+        # per-tx tracing, wired by the node: a sampled commit's publish
+        # span opens when its event is queued here
+        self.tracer = NULL_TRACER
 
     def set_event_bus(self, bus: EventBus) -> None:
         self.event_bus = bus
@@ -183,16 +187,24 @@ class TxExecutor:
             )
             self._ev_thread.start()
         self._ev_enqueued += 1
-        self._ev_q.put((height, tx, deliver_res, tx_hash))
+        tr = self.tracer
+        sid = 0
+        if tr.active and tx_hash is not None and tr.sampled(tx_hash):
+            # event queued -> frame on a websocket subscriber's socket
+            # (rpc/server.py finishes it): the worker's queue, the bus,
+            # the subscriber's queue and the pump
+            sid = tr.begin(tx_hash, SPAN_PUBLISH)
+        self._ev_q.put((height, tx, deliver_res, tx_hash, sid))
 
     def _event_worker(self) -> None:
         while True:
             item = self._ev_q.get()
             if item is None:  # drain_events sentinel
                 return
-            height, tx, deliver_res, tx_hash = item
+            height, tx, deliver_res, tx_hash, sid = item
+            taken = 0
             try:
-                self.event_bus.publish(
+                taken = self.event_bus.publish(
                     EventTx,
                     EventDataTx(
                         height=height,
@@ -203,6 +215,7 @@ class TxExecutor:
                         result_log=deliver_res.log,
                         tags=list(getattr(deliver_res, "tags", []) or []),
                     ),
+                    span=sid,
                 )
             except Exception:
                 # a raising subscriber callback must not kill the worker
@@ -213,6 +226,9 @@ class TxExecutor:
 
                 traceback.print_exc()
             finally:
+                if not taken:
+                    # nobody subscribes: the span ends at publish's return
+                    self.tracer.finish(sid)
                 self._ev_published += 1
 
     def events_drained(self) -> bool:

@@ -28,6 +28,7 @@ Divergences from the reference (defects fixed, per SURVEY.md §0):
 
 from __future__ import annotations
 
+import contextlib
 import queue as _queue
 import threading
 
@@ -38,15 +39,23 @@ from ..pool.txvotepool import TxVotePool
 from ..store.tx_store import TxStore
 from ..trace.tracer import (
     NULL_TRACER,
+    SPAN_COLLECT,
     SPAN_COMMIT,
     SPAN_DEVICE,
-    SPAN_LINGER,
+    SPAN_DISPATCH,
     SPAN_LINGER_BULK,
     SPAN_LINGER_PRIO,
     SPAN_LOCK_WAIT,
+    SPAN_PICKUP,
+    SPAN_POOL_WAIT,
     SPAN_PREP,
     SPAN_QUORUM,
+    SPAN_ROUTE,
+    SPAN_ROUTE_COMMIT,
+    SPAN_ROUTE_PURGE,
+    SPAN_ROUTE_TALLY,
     SPAN_SPEC,
+    SPAN_VOTE_WAIT,
 )
 from ..types import TxVote, TxVoteSet
 from ..types.validator import ValidatorSet
@@ -66,6 +75,54 @@ from .execution import TxExecutor
 # _POOL_MIN_ROWS; light-load steps stay serial either way)
 _POOL_MIN_VOTES = 256
 
+_ANNOTATION: list = []  # the class below, looked up once
+
+
+def _annotation_cls():
+    """``jax.profiler.TraceAnnotation``, imported on first use (a null
+    context where JAX is absent). Outside a profiler session it is an
+    inactive TraceMe, well under a microsecond; inside one the stage
+    lands in the host plane of the ``.xplane.pb``, on the device
+    events' clock."""
+    if not _ANNOTATION:
+        try:
+            from jax.profiler import TraceAnnotation
+        except Exception:
+            def TraceAnnotation(name, **kw):
+                return contextlib.nullcontext()
+        _ANNOTATION.append(TraceAnnotation)
+    return _ANNOTATION[0]
+
+
+class _Stage:
+    """The ONE record site of an engine stage: two clock reads around the
+    work feed every sink (``TxFlow._stage_done``: the pipeline_stats()
+    counter, the Prometheus pipeline_*_seconds counter, the stage span)
+    and the work runs inside a profiler annotation of the same name.
+    Entered per stage per step, never per vote or per tx."""
+
+    __slots__ = ("_eng", "_name", "step", "skip", "_ann", "t0", "t1")
+
+    def __init__(self, eng: "TxFlow", name: str, step: int = 0, votes: int = 0):
+        self._eng = eng
+        self._name = name
+        self.step = step  # may be corrected before exit (host_prep)
+        self.skip = False  # set inside: the work turned out to be none
+        self._ann = _annotation_cls()(name, step=step, votes=votes)
+        self.t0 = self.t1 = 0.0
+
+    def __enter__(self) -> "_Stage":
+        self._ann.__enter__()
+        self.t0 = monotonic()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = monotonic()
+        self._ann.__exit__(*exc)
+        if not self.skip:
+            self._eng._stage_done(self._name, self.t0, self.t1, self.step)
+        return False
+
 
 class _StepPrep:
     """Host-side product of one pool drain: everything the verify call
@@ -78,8 +135,8 @@ class _StepPrep:
 
     __slots__ = (
         "keys", "votes", "slots", "n_slots", "prior", "msgs", "sigs",
-        "val_idx", "dropped", "drain_seq", "verifier", "t0", "submit_t",
-        "trace_txs", "device_sid", "lane",
+        "val_idx", "dropped", "drain_seq", "verifier", "t0", "step",
+        "trace_txs", "dispatch_end", "device_sid", "lane",
     )
 
     def __init__(self, drain_seq: int, t0: float, lane: str | None = None):
@@ -95,12 +152,15 @@ class _StepPrep:
         self.drain_seq = drain_seq
         self.verifier = None
         self.t0 = t0
-        self.submit_t = t0
-        # sampled tx hashes in this batch: batch-level spans (lock_wait,
-        # host_prep, device_verify) are recorded once, tagged with the
-        # first sampled tx, so a traced tx's timeline shows the batch
-        # stages it actually rode through
+        # the engine's step id (0 until a batch with votes is formed):
+        # every stage span of the step and every per-tx span the step
+        # decides carries it
+        self.step = 0
+        # sampled tx hashes in this batch: each gets its vote_wait span
         self.trace_txs: list[str] = []
+        self.dispatch_end = t0
+        # open device_busy span (begun at dispatch, finished at collect:
+        # the leak check proves no ticket is ever orphaned)
         self.device_sid = 0
         # which drain lane produced this batch ("prio" / "bulk" / None =
         # merged legacy drain): routes requeues back to the lane's own
@@ -130,12 +190,12 @@ class _BatchCoalescer:
     __slots__ = (
         "targets", "linger", "full_batches", "linger_flushes",
         "_deadline", "_idle", "_clock", "_metrics", "_tracer", "_hold_t0",
-        "_span_name", "wide_from", "wide_ok", "wide_full_batches",
+        "span_name", "flush_t0", "wide_from", "wide_ok", "wide_full_batches",
     )
 
     def __init__(self, buckets, cap: int, min_batch: int, linger: float,
                  metrics=None, clock=monotonic, tracer=None,
-                 multiple: int = 1, span_name: str = SPAN_LINGER,
+                 multiple: int = 1, span_name: str = SPAN_LINGER_BULK,
                  wide_from: int | None = None):
         # mesh divisibility: a sharded verifier pads every dispatch up to
         # a multiple of its shard count anyway (verifier.bucket_size), so
@@ -158,9 +218,13 @@ class _BatchCoalescer:
         self._metrics = metrics
         self._tracer = tracer or NULL_TRACER
         self._hold_t0 = 0.0
-        # per-lane trace family (linger / linger_prio / linger_bulk):
-        # report.py attributes the hold to the lane that paid it
-        self._span_name = span_name
+        # per-lane trace family (linger_prio / linger_bulk): report.py
+        # attributes the hold to the lane that paid it
+        self.span_name = span_name
+        # when the hold began that the last decide() flushed (0.0 = it
+        # handed out a full bucket, nothing was held): where the batch's
+        # pickup_wait ends
+        self.flush_t0 = 0.0
         # wide-rung gate (EngineConfig.wide_buckets): rungs ABOVE
         # wide_from are eligible only while wide_ok holds — the adaptive
         # linger controller clears it (set_wide) when batch latency
@@ -172,9 +236,10 @@ class _BatchCoalescer:
         self.wide_ok = True
         self.wide_full_batches = 0
 
-    def decide(self, pending: int) -> int:
+    def decide(self, pending: int, step: int = 0) -> int:
         """Votes to dispatch NOW: a full canonical bucket, the whole
-        backlog on linger/idle expiry, or 0 (keep coalescing)."""
+        backlog on linger/idle expiry, or 0 (keep coalescing). ``step``
+        is the id the dispatched batch will take (the linger span's)."""
         if pending <= 0:
             self._deadline = None
             self._idle = False
@@ -194,6 +259,7 @@ class _BatchCoalescer:
         if full:
             self._deadline = None
             self._idle = False
+            self.flush_t0 = 0.0
             self.full_batches += 1
             if self.wide_from is not None and full > self.wide_from:
                 self.wide_full_batches += 1
@@ -207,6 +273,7 @@ class _BatchCoalescer:
         if now >= self._deadline or self._idle:
             self._deadline = None
             self._idle = False
+            self.flush_t0 = self._hold_t0
             self.linger_flushes += 1
             if self._metrics is not None:
                 self._metrics.coalesce_linger_flushes.add(1)
@@ -214,9 +281,15 @@ class _BatchCoalescer:
                 # batch-level hold: no single tx owns it, so the span is
                 # tagged with the empty tx (report.py attributes linger
                 # from the histogram sum, not per tx)
-                self._tracer.span("", self._span_name, self._hold_t0, now)
+                self._tracer.span("", self.span_name, self._hold_t0, now, step)
             return pending
         return 0
+
+    @property
+    def holding(self) -> bool:
+        """A partial batch is held: the engine's pool waits meanwhile
+        belong to this lane's linger span."""
+        return self._deadline is not None
 
     def set_wide(self, ok: bool) -> None:
         """Gate the wide rungs (called from the engine thread by
@@ -390,19 +463,28 @@ class TxFlow:
         self._unapplied: dict[str, bytes] = {}
         self.app_hash = b""
         # verify-pipeline accounting (engine thread only; racy reads by
-        # pipeline_stats are fine): busy is the wall-clock union of
-        # [submit, collect] windows — device (or host-verify) occupancy —
-        # while active sums the engine's own prep/wait/route segments.
-        # overlap_ratio = busy/active; the gap (active - busy) is the
-        # device idle time the pipeline exists to close.
+        # pipeline_stats are fine), fed by _stage_done alone: busy is the
+        # sum of the device_busy spans (dispatched -> result usable on
+        # the host, never overlapping), active the engine's own
+        # host_prep/dispatch/collect_wait/route segments. overlap_ratio =
+        # busy/active. busy is a host-side reading: it holds the device's
+        # time AND what the readback thread waited for the interpreter
+        # lock before it could stamp the result (20 ms a step in the
+        # flood, PERF.md), so active - busy is a lower bound of the
+        # device's idle time, not the idle time.
         self._pipe_steps = 0
         self._pipe_prep_s = 0.0
         self._pipe_wait_s = 0.0
         self._pipe_route_s = 0.0
         self._pipe_busy_s = 0.0
         self._pipe_active_s = 0.0
-        self._pipe_last_collect = 0.0
         self._pipe_lock_wait_s = 0.0
+        # step ids (_prep_batch takes one per batch it forms), the last
+        # step's ready time (device_busy never starts before it), and the
+        # start of a pool_wait still open across idle polls (0 = none)
+        self._step_seq = 0
+        self._last_ready = 0.0
+        self._idle_t0 = 0.0
         # host-prep split (profile_host.py prep_serial vs prep_pool_wait):
         # sign_s is the assembly stage's wall time, pool_wait_s the slice
         # of it this thread spent parked behind pool shards it didn't run
@@ -722,6 +804,7 @@ class TxFlow:
         while True:
             with self._mtx:
                 if not self._running:
+                    self._end_pool_wait()
                     return
             seq_before = self.tx_vote_pool.seq()
             processed = 0
@@ -729,14 +812,14 @@ class TxFlow:
                 # priority lane first, always: a dispatchable priority
                 # batch (full small bucket or expired deadline) preempts
                 # any bulk work this iteration would start
-                plimit = pl.decide(self._prio_pending())
+                plimit = pl.decide(self._prio_pending(), self._step_seq + 1)
                 if plimit > 0:
                     processed += self.step(limit=plimit, lane="prio")
             if co is not None:
                 # shape-stable sizing replaces min_batch/_form_batch: the
                 # coalescer hands out full canonical buckets (or a linger
                 # flush), and 0 means keep accumulating
-                limit = co.decide(self._bulk_pending())
+                limit = co.decide(self._bulk_pending(), self._step_seq + 1)
                 if limit > 0:
                     processed += self.step(limit=limit, lane=lane_bulk)
             else:
@@ -766,12 +849,71 @@ class TxFlow:
                     budget = co.wait_budget(budget, self.config.idle_flush)
                 if pl is not None:
                     budget = pl.wait_budget(budget, self.config.idle_flush)
-                got = self.tx_vote_pool.wait_for_new(seq_before, timeout=budget)
+                got = self._pool_wait(seq_before, budget)
                 if got == seq_before:
                     if co is not None:
                         co.note_idle()
                     if pl is not None:
                         pl.note_idle()
+
+    def _pool_wait(self, seq: int, timeout: float) -> int:
+        """The engine thread blocked on the vote pool: one pool_wait span
+        from the first block to the wake, however many idle polls lie
+        between (_end_pool_wait closes it where work starts anyway).
+        While a coalescer holds a partial batch the wait belongs to that
+        lane's linger span instead, and is annotated as such."""
+        co, pl = self._coalescer, self._prio_lane
+        held = pl if pl is not None and pl.holding else co
+        if held is not None and held.holding:
+            self._end_pool_wait()
+            name = held.span_name
+        else:
+            name = SPAN_POOL_WAIT
+            if not self._idle_t0:
+                self._idle_t0 = monotonic()
+        with _annotation_cls()(name):
+            got = self.tx_vote_pool.wait_for_new(seq, timeout=timeout)
+        if got != seq:
+            self._end_pool_wait()
+        return got
+
+    def _end_pool_wait(self, t1: float | None = None) -> None:
+        if self._idle_t0:
+            t0, self._idle_t0 = self._idle_t0, 0.0
+            self._stage_done(SPAN_POOL_WAIT, t0, monotonic() if t1 is None else t1, 0)
+
+    def _stage_done(self, name: str, t0: float, t1: float, step: int,
+                    sid: int = 0) -> None:
+        """Every sink of one stage's two clock reads: the
+        pipeline_stats() counter, the Prometheus counter and the stage
+        span (``sid``: the span was begun at dispatch and is finished
+        here). prep_s keeps its meaning: host_prep + dispatch."""
+        dur = t1 - t0
+        m = self.metrics
+        if name == SPAN_PREP or name == SPAN_DISPATCH:
+            self._pipe_prep_s += dur
+            self._pipe_active_s += dur
+            m.pipeline_prep_seconds.add(dur)
+        elif name == SPAN_COLLECT:
+            self._pipe_wait_s += dur
+            self._pipe_active_s += dur
+            m.pipeline_wait_seconds.add(dur)
+        elif name == SPAN_ROUTE:
+            self._pipe_route_s += dur
+            self._pipe_active_s += dur
+            m.pipeline_route_seconds.add(dur)
+        elif name == SPAN_LOCK_WAIT:
+            self._pipe_lock_wait_s += dur
+        elif name == SPAN_DEVICE:
+            self._pipe_busy_s += dur
+            active, busy = self._pipe_active_s, self._pipe_busy_s
+            if active > 0:
+                m.pipeline_overlap_ratio.set(min(busy / active, 1.0))
+                m.pipeline_device_idle.set(max(active - busy, 0.0))
+        if sid:
+            self.tracer.finish(sid, end=t1, start=t0)
+        else:
+            self.tracer.span("", name, t0, t1, step)
 
     def _run_pipelined(self) -> None:
         """Three-stage verify pipeline: host prep (stage 1) and commit
@@ -818,7 +960,7 @@ class TxFlow:
                         # fill would make: a dispatchable priority batch
                         # (full small bucket or expired deadline) rides
                         # the NEXT ticket, never behind a bulk backlog
-                        plimit = pl.decide(self._prio_pending())
+                        plimit = pl.decide(self._prio_pending(), self._step_seq + 1)
                         if plimit > 0:
                             prep = self._prep_batch(limit=plimit, lane="prio")
                             if prep is not None:
@@ -831,7 +973,7 @@ class TxFlow:
                             # estimate raced a purge (nothing drained):
                             # fall through to the bulk lane this pass
                     if co is not None:
-                        limit = co.decide(self._bulk_pending())
+                        limit = co.decide(self._bulk_pending(), self._step_seq + 1)
                         if limit <= 0:
                             break
                         prep = self._prep_batch(limit=limit, lane=lane_bulk)
@@ -872,18 +1014,14 @@ class TxFlow:
                         self._apply_unapplied()
                     if co is None and pl is None:
                         if not self._retry:
-                            self.tx_vote_pool.wait_for_new(
-                                seq_before, timeout=self.config.poll_interval
-                            )
+                            self._pool_wait(seq_before, self.config.poll_interval)
                         continue
                     budget = self.config.poll_interval
                     if co is not None:
                         budget = co.wait_budget(budget, self.config.idle_flush)
                     if pl is not None:
                         budget = pl.wait_budget(budget, self.config.idle_flush)
-                    got = self.tx_vote_pool.wait_for_new(
-                        seq_before, timeout=budget
-                    )
+                    got = self._pool_wait(seq_before, budget)
                     if got == seq_before:
                         if co is not None:
                             co.note_idle()
@@ -913,9 +1051,7 @@ class TxFlow:
                     # deferred votes sit in _retry, and re-prepping them
                     # against claims the owner still holds just spins the
                     # fill stage against the owner's in-flight call
-                    self.tx_vote_pool.wait_for_new(
-                        prep.drain_seq, timeout=self.config.defer_backoff
-                    )
+                    self._pool_wait(prep.drain_seq, self.config.defer_backoff)
         finally:
             # drain stage: stop() (or a crash) must not orphan tickets —
             # collect and route the tail in submission order so cache
@@ -931,6 +1067,7 @@ class TxFlow:
                     import traceback
 
                     traceback.print_exc()
+            self._end_pool_wait()
             m.pipeline_depth.set(0)
 
     def _form_batch(self, budget: float | None = None) -> None:
@@ -967,7 +1104,7 @@ class TxFlow:
             timeout = remaining
             if idle_flush > 0 and pending > 0:
                 timeout = min(remaining, idle_flush)
-            got = self.tx_vote_pool.wait_for_new(seq_now, timeout=timeout)
+            got = self._pool_wait(seq_now, timeout)
             if got == seq_now and pending > 0:
                 return
 
@@ -1016,9 +1153,7 @@ class TxFlow:
             # owner's in-flight call for nothing. A pool wait (not a
             # sleep) against the PRE-drain seq snapshot, so votes that
             # arrived during the verify call wake the engine immediately.
-            self.tx_vote_pool.wait_for_new(
-                prep.drain_seq, timeout=self.config.defer_backoff
-            )
+            self._pool_wait(prep.drain_seq, self.config.defer_backoff)
         return decided + prep.dropped
 
     def _sign_bytes_proc(self, votes, pool) -> "list[bytes] | None":
@@ -1078,19 +1213,52 @@ class TxFlow:
         _prio_drained dedup set. None keeps the legacy merged drain
         (priority log ahead of the main-log walk, dedup via
         _prio_drained) for direct step() callers and lane_split=False."""
-        t0 = monotonic()
         target = self._drain_cap if limit is None else min(limit, self._drain_cap)
+        # the step id this drain will take if it forms a batch with votes
+        # (the engine thread is the only taker); a drain that only drops
+        # records under step 0, one that finds nothing records nothing
+        # (an idle loop without a coalescer comes here every poll)
+        with _Stage(self, SPAN_PREP, self._step_seq + 1, target) as st:
+            prep, lk_acq = self._drain_and_assemble(st.t0, target, lane)
+            if prep is None:
+                st.skip = True
+            else:
+                st.step = prep.step
+                self._end_pool_wait(st.t0)
+                self._stage_done(SPAN_LOCK_WAIT, st.t0, lk_acq, st.step)
+                self._votes_waited(prep, lane, st.t0, st.step)
+        return prep
+
+    def _votes_waited(self, prep: "_StepPrep", lane: str | None,
+                      t_prep: float, step: int) -> None:
+        """What the drained votes waited for this step. pickup_wait (the
+        step's): the pool's first vote since the last drain -> the batch
+        taken up (its lane's hold began, or this prep where a full bucket
+        was handed out at once). vote_wait (each sampled tx's): its first
+        vote in the pool -> this prep."""
+        co = self._prio_lane if lane == "prio" else self._coalescer
+        t_in = self.tx_vote_pool.take_first_new()
+        t_up = (co.flush_t0 if co is not None else 0.0) or t_prep
+        if 0.0 < t_in < t_up:
+            self._stage_done(SPAN_PICKUP, t_in, t_up, step)
+        tr = self.tracer
+        for tx_hash in prep.trace_txs:
+            t_vote = tr.take_first_vote(tx_hash)
+            if t_vote is not None:
+                tr.span(tx_hash, SPAN_VOTE_WAIT, t_vote, t_prep, step)
+
+    def _drain_and_assemble(
+        self, t0: float, target: int, lane: str | None
+    ) -> "tuple[_StepPrep | None, float]":
+        """_prep_batch's work; returns the prep and the time _mtx was
+        acquired (the gap from t0 is mutex queueing, not host prep —
+        report.py subtracts it from the host component)."""
         # seq snapshot BEFORE the drain: the defer-backoff wait must wake
         # for votes that arrive during the verify call, not only after a
         # post-step snapshot
         drain_seq = self.tx_vote_pool.seq()
         with self._mtx:
-            # lock-wait attribution: under contention (consensus-path
-            # claims, inflight_snapshot readers) the gap between t0 and
-            # here is mutex queueing, not host prep — report.py subtracts
-            # it from the host component
             lk_acq = monotonic()
-            self._pipe_lock_wait_s += lk_acq - t0
             if lane == "prio":
                 praw, self._prio_drain_cursor = (
                     self.tx_vote_pool.priority_entries_from(
@@ -1141,7 +1309,7 @@ class TxFlow:
                 )
                 self._retry = []
             if not batch:
-                return None
+                return None, lk_acq
             prep = _StepPrep(drain_seq, t0, lane=lane)
             keys, votes, slots = prep.keys, prep.votes, prep.slots
             slot_of: dict[str, int] = {}
@@ -1184,7 +1352,9 @@ class TxFlow:
                 self.tx_vote_pool.remove(drop_now)
             prep.dropped = len(drop_now)
             if not votes:
-                return prep
+                return prep, lk_acq
+            self._step_seq += 1
+            prep.step = self._step_seq
 
             n_slots = len(slot_of)
             prior = np.zeros(n_slots, np.int64)
@@ -1198,9 +1368,8 @@ class TxFlow:
             tr = self.tracer
             if tr.active:
                 # unique txs only (n_slots <= max_slots, not batch size):
-                # one int parse per distinct hash, capped — the overhead
-                # gate in tests/test_trace.py pins this whole path
-                prep.trace_txs = [h for h in slot_of if tr.sampled(h)][:8]
+                # one int parse per distinct hash
+                prep.trace_txs = [h for h in slot_of if tr.sampled(h)]
 
             # snapshot the set-epoch references this drain belongs to:
             # update_state replaces both wholesale under _mtx, so the
@@ -1218,6 +1387,7 @@ class TxFlow:
 
         pool = self._host_pool
         t_sign = monotonic()
+        msgs = None
         if (
             pool is not None
             and getattr(pool, "backend", "thread") == "process"
@@ -1229,25 +1399,14 @@ class TxFlow:
             # thread). None return = hostile field bounds or a broken
             # pool — fall through to the thread/serial paths below.
             msgs = self._sign_bytes_proc(votes, pool)
-            if msgs is not None:
-                prep.msgs = msgs
-                prep.sigs = [v.signature or b"" for v in votes]
-                prep.val_idx = np.array(
-                    [addr_to_idx.get(v.validator_address, -1) for v in votes],
-                    dtype=np.int64,
-                )
-                self._pipe_prep_sign_s += monotonic() - t_sign
-                end = monotonic()
-                dur = end - t0
-                self._pipe_prep_s += dur
-                self._pipe_active_s += dur
-                self.metrics.pipeline_prep_seconds.add(dur)
-                if prep.trace_txs:
-                    tx0 = prep.trace_txs[0]
-                    self.tracer.span(tx0, SPAN_LOCK_WAIT, t0, lk_acq)
-                    self.tracer.span(tx0, SPAN_PREP, t0, end)
-                return prep
-        if pool is not None and pool.workers > 1 and len(votes) >= _POOL_MIN_VOTES:
+        if msgs is not None:
+            prep.msgs = msgs
+            prep.sigs = [v.signature or b"" for v in votes]
+            prep.val_idx = np.array(
+                [addr_to_idx.get(v.validator_address, -1) for v in votes],
+                dtype=np.int64,
+            )
+        elif pool is not None and pool.workers > 1 and len(votes) >= _POOL_MIN_VOTES:
 
             def _assemble(lo: int, hi: int):
                 vs = votes[lo:hi]
@@ -1272,16 +1431,7 @@ class TxFlow:
                 dtype=np.int64,
             )
         self._pipe_prep_sign_s += monotonic() - t_sign
-        end = monotonic()
-        dur = end - t0
-        self._pipe_prep_s += dur
-        self._pipe_active_s += dur
-        self.metrics.pipeline_prep_seconds.add(dur)
-        if prep.trace_txs:
-            tx0 = prep.trace_txs[0]
-            self.tracer.span(tx0, SPAN_LOCK_WAIT, t0, lk_acq)
-            self.tracer.span(tx0, SPAN_PREP, t0, end)
-        return prep
+        return prep, lk_acq
 
     def _submit_prep(self, prep: "_StepPrep"):
         """Stage 2 dispatch: hand the prepped batch to the verifier. With
@@ -1296,8 +1446,16 @@ class TxFlow:
         pipeline behind a synchronous compile. The BackgroundWarmer
         flips the gate shape by shape; once warm, batches promote to the
         device and never come back."""
-        t0 = monotonic()
-        prep.submit_t = t0
+        with _Stage(self, SPAN_DISPATCH, prep.step, len(prep.votes)) as st:
+            ticket = self._dispatch(prep)
+        prep.dispatch_end = st.t1
+        # open across the pipelined in-flight gap — a begin/finish pair so
+        # the soak's leak check also proves no ticket is ever orphaned
+        # (the PR 3 drain-on-stop claim)
+        prep.device_sid = self.tracer.begin("", SPAN_DEVICE, st.t1, prep.step)
+        return ticket
+
+    def _dispatch(self, prep: "_StepPrep"):
         if prep.lane == "prio":
             self._lane_prio_batches += 1
             self._lane_prio_votes += len(prep.votes)
@@ -1328,43 +1486,27 @@ class TxFlow:
                     prior_stake=prep.prior,
                 )
             )
-        dur = monotonic() - t0
-        self._pipe_prep_s += dur
-        self._pipe_active_s += dur
-        self.metrics.pipeline_prep_seconds.add(dur)
-        if prep.trace_txs:
-            # device window is open across the pipelined in-flight gap —
-            # a begin/finish pair so the soak's leak check also proves no
-            # ticket is ever orphaned (the PR 3 drain-on-stop claim)
-            prep.device_sid = self.tracer.begin(
-                prep.trace_txs[0], SPAN_DEVICE, t0
-            )
         return ticket
 
     def _collect(self, prep: "_StepPrep", ticket):
-        """Stage 2 collect: block for the ticket's readback and account
-        the device-busy window ([submit, collect], unioned across
-        overlapping tickets) for the overlap ratio."""
-        t0 = monotonic()
-        result = ticket.result()
-        t1 = monotonic()
-        if prep.device_sid:
-            self.tracer.finish(prep.device_sid, t1)
-            prep.device_sid = 0
-        self._pipe_wait_s += t1 - t0
-        self._pipe_active_s += t1 - t0
-        self.metrics.pipeline_wait_seconds.add(t1 - t0)
-        # busy-union: overlapping [submit, collect] windows must not be
-        # double-counted, and in-order collection means the previous
-        # collect time is a sufficient watermark
-        start = max(prep.submit_t, self._pipe_last_collect)
-        if t1 > start:
-            self._pipe_busy_s += t1 - start
-        self._pipe_last_collect = t1
-        active, busy = self._pipe_active_s, self._pipe_busy_s
-        if active > 0:
-            self.metrics.pipeline_overlap_ratio.set(min(busy / active, 1.0))
-            self.metrics.pipeline_device_idle.set(max(active - busy, 0.0))
+        """Stage 2 collect: block for the ticket's readback
+        (collect_wait), then close the step's device_busy span: from the
+        later of its dispatch and the previous step's ready time to its
+        own ready time — the moment the packed result was usable on the
+        host, as the ticket stamped it (the staging ring's thread, once
+        it held the interpreter lock again), or the end of collect_wait
+        where the ticket carries no stamp. In-order collection makes the
+        spans of one verifier disjoint. Their sum is dispatch -> result
+        usable, step by step: the device's time plus the stamping
+        thread's wait for the lock, so an upper bound of the device's
+        busy time, never the device's own reading."""
+        with _Stage(self, SPAN_COLLECT, prep.step, len(prep.votes)) as st:
+            result = ticket.result()
+        start = max(prep.dispatch_end, self._last_ready)
+        ready = max(getattr(ticket, "ready_t", None) or st.t1, start)
+        self._last_ready = ready
+        sid, prep.device_sid = prep.device_sid, 0
+        self._stage_done(SPAN_DEVICE, start, ready, prep.step, sid=sid)
         return result
 
     def _route_result(self, prep: "_StepPrep", result) -> tuple[int, int, bool]:
@@ -1372,7 +1514,20 @@ class TxFlow:
         order into the authoritative vote sets, committing inline the
         moment a set crosses 2/3. Returns (decided, requeued,
         all_deferred); decided + requeued == len(prep.votes) always."""
-        t0 = monotonic()
+        with _Stage(self, SPAN_ROUTE, prep.step, len(prep.votes)) as st:
+            out = self._route(prep, result, st.t0)
+        self.metrics.step_time.observe(st.t1 - prep.t0)
+        return out
+
+    def _route(self, prep: "_StepPrep", result, t0: float) -> tuple[int, int, bool]:
+        """_route_result's work, in three child spans that tile it:
+        route_tally (under _mtx: routing, quorum decisions, removal of
+        votes that can never be added, then the accountability hook),
+        route_commit (the inline _commit_effects loop: TxStore, ABCI,
+        commitpool, events; not recorded where a committer thread
+        applies the commits, whose work is each tx's commit_apply) and
+        route_purge (the step's one pool purge)."""
+        step = prep.step
         keys, votes = prep.keys, prep.votes
         requeued = 0
         tr = self.tracer
@@ -1470,22 +1625,22 @@ class TxFlow:
                                 # routing latency up to THIS decision:
                                 # result available (route start) ->
                                 # quorum latched
-                                tr.span(vote.tx_hash, SPAN_QUORUM, t0, now)
+                                tr.span(vote.tx_hash, SPAN_QUORUM, t0, now, step)
                             if in_spec:
                                 spec_t.append(now)
                                 if traced:
                                     spec_sids.append(
-                                        tr.begin(vote.tx_hash, SPAN_SPEC, now)
+                                        tr.begin(vote.tx_hash, SPAN_SPEC, now, step)
                                     )
                         if self._committer is not None:
-                            self._enqueue_commit(vs)
+                            self._enqueue_commit(vs, step)
                         else:
                             # decision bookkeeping only — the effects
                             # (save_tx fsync, ABCI apply round trip) must
                             # not run under _mtx: they stalled every
                             # try_add_vote/claim/stat reader behind disk
                             # and socket (lock-blocking finding, fixed)
-                            inline_commits.append(self._decide_commit(vs))
+                            inline_commits.append(self._decide_commit(vs, step))
                 else:
                     bad_keys.append(keys[i])  # dup/conflict: can never add
             invalid_origins = None
@@ -1507,18 +1662,25 @@ class TxFlow:
             except Exception:
                 pass
 
-        for vs, quorum_votes, tx in inline_commits:
-            # decision order preserved; _commit_effects re-acquires _mtx
-            # only to resolve deferred-apply ownership
-            self._commit_effects(
-                vs, quorum_votes, purge_votes, tx=tx, deferred=tx is None
-            )
+        t_commit = t_tally = monotonic()
+        if inline_commits:
+            for vs, quorum_votes, tx in inline_commits:
+                # decision order preserved; _commit_effects re-acquires
+                # _mtx only to resolve deferred-apply ownership
+                self._commit_effects(
+                    vs, quorum_votes, purge_votes, tx=tx, deferred=tx is None
+                )
+            t_commit = monotonic()
         if purge_votes:
             # one pool update per step (per-tx updates paid an O(log)
             # bookkeeping walk per commit — r3 step profile: 0.9 ms each)
             self.tx_vote_pool.update(self.height, purge_votes)
 
         t1 = monotonic()
+        self._stage_done(SPAN_ROUTE_TALLY, t0, t_tally, step)
+        if inline_commits:
+            self._stage_done(SPAN_ROUTE_COMMIT, t_tally, t_commit, step)
+        self._stage_done(SPAN_ROUTE_PURGE, t_commit, t1, step)
         if spec_t:
             # saved tail per spec commit: route end minus its decision
             # time — the wait the early exit removed from its latency
@@ -1533,10 +1695,6 @@ class TxFlow:
             # always closed here — the drain-on-stop invariant (zero open
             # spec_commit spans) rides the same finally-drain as device
             tr.finish(sid, t1)
-        self._pipe_route_s += t1 - t0
-        self._pipe_active_s += t1 - t0
-        self.metrics.pipeline_route_seconds.add(t1 - t0)
-        self.metrics.step_time.observe(t1 - prep.t0)
         decided = len(votes) - requeued
         self.last_step_stats = {
             "decided": decided, "requeued": requeued,
@@ -1546,10 +1704,16 @@ class TxFlow:
 
     def pipeline_stats(self) -> dict:
         """Verify-pipeline observability snapshot (health registry,
-        profile_host, bench). overlap_ratio is device-busy wall time over
-        engine-active wall time: ~1.0 means the device (or host verify)
-        never waited on prep/routing; the idle gap is what raising
-        pipeline_depth / retuning min_batch+batch_wait should shrink."""
+        profile_host, bench), every second of it from _stage_done.
+        overlap_ratio is the device_busy spans' sum (dispatch -> result
+        usable on the host: the device's time and the readback thread's
+        wait for the interpreter lock) over engine-active wall time:
+        ~1.0 means a result was always on its way while the engine
+        prepped and routed (a scalar verifier works inline, inside
+        dispatch, and reads 0); idle_gap_s is a lower bound of the
+        device's idle time — what raising pipeline_depth / retuning
+        min_batch+batch_wait should shrink. The device's own idle share
+        is the profiler's to give."""
         active = self._pipe_active_s
         busy = min(self._pipe_busy_s, active)
         ctrl = self._depth_ctrl
@@ -1665,12 +1829,13 @@ class TxFlow:
 
     # ---- commit (reference addVote :216-232) ----
 
-    def _trace_commit_begin(self, tx_hash: str) -> None:
+    def _trace_commit_begin(self, tx_hash: str, step: int = 0) -> None:
         """Open the commit_apply span at DECISION time (caller holds
-        _mtx, like the _committed mark it shadows)."""
+        _mtx, like the _committed mark it shadows), under the id of the
+        step that decided it."""
         tr = self.tracer
         if tr.active and tr.sampled(tx_hash):
-            self._commit_spans[tx_hash] = tr.begin(tx_hash, SPAN_COMMIT)
+            self._commit_spans[tx_hash] = tr.begin(tx_hash, SPAN_COMMIT, None, step)
 
     def _trace_commit_end(self, tx_hash: str) -> None:
         """Close the commit_apply span from whichever path delivered the
@@ -1687,7 +1852,7 @@ class TxFlow:
         tr.latch(tx_hash)  # no-op when the tx was never anchored
 
     def _decide_commit(
-        self, vs: TxVoteSet
+        self, vs: TxVoteSet, step: int = 0
     ) -> tuple[TxVoteSet, list[TxVote], bytes | None]:
         """Locked half of an inline commit (pipeline_commits=False): the
         same decision bookkeeping _enqueue_commit does for the committer
@@ -1699,7 +1864,7 @@ class TxFlow:
         self._sh_votesets.note_write()
         self.vote_sets.pop(vs.tx_hash, None)
         self._committed.push(_hash_key(vs.tx_hash))
-        self._trace_commit_begin(vs.tx_hash)
+        self._trace_commit_begin(vs.tx_hash, step)
         tx = self.mempool.get_tx(vs.tx_key)
         if tx is None:
             self._unapplied[vs.tx_hash] = vs.tx_key
@@ -1716,7 +1881,7 @@ class TxFlow:
         if purge_batch is None:
             self.tx_vote_pool.update(self.height, quorum_votes)
 
-    def _enqueue_commit(self, vs: TxVoteSet) -> None:
+    def _enqueue_commit(self, vs: TxVoteSet, step: int = 0) -> None:
         """Step-side half of a pipelined commit: engine bookkeeping now,
         side-effects on the committer thread (in decision order). The tx
         BYTES are captured here — by the time the committer runs, a block
@@ -1727,7 +1892,7 @@ class TxFlow:
         self.vote_sets.pop(vs.tx_hash, None)
         self._committed.push(_hash_key(vs.tx_hash))
         self._decided_count += 1
-        self._trace_commit_begin(vs.tx_hash)
+        self._trace_commit_begin(vs.tx_hash, step)
         tx = self.mempool.get_tx(vs.tx_key)
         if tx is None:
             # bytes absent at DECISION time: the deferral must be visible

@@ -348,6 +348,7 @@ class Node:
         # before txflow.start(): the coalescer built at start() captures
         # the tracer for its linger spans
         self.txflow.tracer = self.tracer
+        self.tx_executor.tracer = self.tracer  # the publish span's begin
         # every valid=False verdict becomes a ledger strike against the
         # peer whose delivery originated the vote (engine _route_result)
         self.txflow.on_invalid_votes = self.byzantine_ledger.note_invalid_origins
@@ -647,6 +648,7 @@ class Node:
                 self.block_store, self.chain_state.last_block_height
             )
         self.switch.start()
+        self.tracer.install_gc_hook()  # gc_pause spans while the node runs
         self.txflow.start()
         if self.consensus is not None:
             self.consensus.start()
@@ -674,6 +676,7 @@ class Node:
         if self.consensus is not None:
             self.consensus.stop()
         self.txflow.stop()
+        self.tracer.remove_gc_hook()
         self.switch.stop()
         self.mempool.close_wal()
         self.tx_vote_pool.close_wal()

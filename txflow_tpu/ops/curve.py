@@ -174,10 +174,11 @@ def double_scalar_mul_indexed(
         acc = pniels_add(acc, table_select_indexed(tables_flat, base + h_nib))
         return acc
 
-    init = ext_identity(s_nibbles.shape[:-1])
-    if axis_name is not None:
-        init = tuple(jax.lax.pcast(t, axis_name, to="varying") for t in init)
-    return jax.lax.fori_loop(0, NWINDOWS, step, init)
+    with jax.named_scope("double_scalar_mul"):
+        init = ext_identity(s_nibbles.shape[:-1])
+        if axis_name is not None:
+            init = tuple(jax.lax.pcast(t, axis_name, to="varying") for t in init)
+        return jax.lax.fori_loop(0, NWINDOWS, step, init)
 
 
 def double_scalar_mul(s_nibbles, h_nibbles, base_table, a_tables, axis_name=None):
@@ -215,7 +216,8 @@ def double_scalar_mul(s_nibbles, h_nibbles, base_table, a_tables, axis_name=None
         # the sharded wrappers run with the VMA checker ON, which needs
         # this variance cast
         init = tuple(jax.lax.pcast(t, axis_name, to="varying") for t in init)
-    return jax.lax.fori_loop(0, NWINDOWS, step, init)
+    with jax.named_scope("double_scalar_mul"):
+        return jax.lax.fori_loop(0, NWINDOWS, step, init)
 
 
 def ext_encode(p):

@@ -155,9 +155,10 @@ def verify_kernel(s_nibbles, h_nibbles, a_tables, r_y, r_sign, pre_ok, axis_name
         s_nibbles, h_nibbles, jnp.asarray(curve.BASE_TABLE), a_tables,
         axis_name=axis_name,
     )
-    y, x_parity = curve.ext_encode(p)
-    enc_match = fe.fe_is_equal_frozen(y, r_y) & (x_parity == r_sign)
-    return enc_match & pre_ok
+    with jax.named_scope("encode_compare"):
+        y, x_parity = curve.ext_encode(p)
+        enc_match = fe.fe_is_equal_frozen(y, r_y) & (x_parity == r_sign)
+        return enc_match & pre_ok
 
 
 verify_kernel_jit = jax.jit(verify_kernel)
@@ -390,20 +391,24 @@ def verify_kernel_gather(
     are compact uint8; widened to int32 on device. Decisions are identical
     to ``verify_kernel``; the per-item window table is never materialized
     (``curve.double_scalar_mul_indexed`` selects inside the scan step).
+
+    Scope ``decompress``: the public keys were decompressed on the host,
+    once an epoch (``EpochTables``); what is left of it on the device is
+    the widening of the compact per-vote inputs to int32 limbs.
     """
+    with jax.named_scope("decompress"):
+        s_limbs = s_nibbles.astype(jnp.int32)
+        h_limbs = h_nibbles.astype(jnp.int32)
+        r_limbs = fe.bytes_to_limbs_device(r_y)
+        r_par = r_sign.astype(jnp.int32)
     p = curve.double_scalar_mul_indexed(
-        s_nibbles.astype(jnp.int32),
-        h_nibbles.astype(jnp.int32),
-        jnp.asarray(curve.BASE_TABLE),
-        tables,
-        val_idx,
+        s_limbs, h_limbs, jnp.asarray(curve.BASE_TABLE), tables, val_idx,
         axis_name=axis_name,
     )
-    y, x_parity = curve.ext_encode(p)
-    enc_match = fe.fe_is_equal_frozen(y, fe.bytes_to_limbs_device(r_y)) & (
-        x_parity == r_sign.astype(jnp.int32)
-    )
-    return enc_match & pre_ok
+    with jax.named_scope("encode_compare"):
+        y, x_parity = curve.ext_encode(p)
+        enc_match = fe.fe_is_equal_frozen(y, r_limbs) & (x_parity == r_par)
+        return enc_match & pre_ok
 
 
 def verify_batch(batch: PreparedBatch) -> np.ndarray:

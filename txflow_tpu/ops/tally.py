@@ -13,6 +13,14 @@ is below 2^30. ``DeviceVoteVerifier`` enforces that bound at construction
 and raises, directing such sets to ``ScalarVoteVerifier`` (host int64
 accumulation); tendermint itself caps total power at 2^63/8, and practical
 validator sets are far below 2^30.
+
+Names a profiler can find: the functions handed to ``jax.jit`` here are
+named, so the step's program is ``jit_txflow_verify_tally(<fingerprint>)``
+in a device trace (``_unpacked`` / ``_generic`` for the other two), and
+``jax.named_scope`` marks where ``decompress``, ``double_scalar_mul``,
+``encode_compare`` (ops/ed25519_batch.py, ops/curve.py) and ``tally``
+begin. Scopes only name operations: numerics, shapes and fusion are as
+before.
 """
 
 from __future__ import annotations
@@ -28,12 +36,13 @@ def tally_kernel(valid, tx_slot, power, n_slots: int):
     (-1 or >= n_slots = no slot / padding), power: int32[B] voting power of
     the vote's validator. Returns int32[n_slots].
     """
-    contrib = jnp.where(valid, power, 0)
-    slot = jnp.clip(tx_slot, 0, n_slots - 1)
-    in_range = (tx_slot >= 0) & (tx_slot < n_slots)
-    return jax.ops.segment_sum(
-        jnp.where(in_range, contrib, 0), slot, num_segments=n_slots
-    )
+    with jax.named_scope("tally"):
+        contrib = jnp.where(valid, power, 0)
+        slot = jnp.clip(tx_slot, 0, n_slots - 1)
+        in_range = (tx_slot >= 0) & (tx_slot < n_slots)
+        return jax.ops.segment_sum(
+            jnp.where(in_range, contrib, 0), slot, num_segments=n_slots
+        )
 
 
 def verify_and_tally(verify_fn, axis_name: str | None = None):
@@ -49,7 +58,7 @@ def verify_and_tally(verify_fn, axis_name: str | None = None):
     mesh axis (ICI collective), giving every shard the global tally.
     """
 
-    def f(verify_inputs, tx_slot, power, prior_stake, quorum):
+    def txflow_verify_tally_generic(verify_inputs, tx_slot, power, prior_stake, quorum):
         valid = verify_fn(*verify_inputs, axis_name=axis_name)
         stake = tally_kernel(valid, tx_slot, power, prior_stake.shape[0])
         if axis_name is not None:
@@ -57,7 +66,7 @@ def verify_and_tally(verify_fn, axis_name: str | None = None):
         total = prior_stake + stake
         return valid, total, total >= quorum
 
-    return f
+    return txflow_verify_tally_generic
 
 
 import functools
@@ -88,7 +97,10 @@ def compact_step(axis_name: str | None = None):
     """
     from . import ed25519_batch
 
-    def f(s_nib, h_nib, val_idx, r_y, r_sign, pre_ok, tx_slot, tables, powers, prior_stake, quorum):
+    def txflow_verify_tally_unpacked(
+        s_nib, h_nib, val_idx, r_y, r_sign, pre_ok, tx_slot, tables, powers,
+        prior_stake, quorum,
+    ):
         valid = ed25519_batch.verify_kernel_gather(
             s_nib, h_nib, val_idx, tables, r_y, r_sign, pre_ok,
             axis_name=axis_name,
@@ -100,7 +112,7 @@ def compact_step(axis_name: str | None = None):
         total = prior_stake + stake
         return valid, total, total >= quorum
 
-    return f
+    return txflow_verify_tally_unpacked
 
 
 @functools.lru_cache(maxsize=None)
@@ -121,7 +133,7 @@ def compact_step_packed(axis_name: str | None = None):
     """
     inner = compact_step(axis_name)
 
-    def f(*args):
+    def txflow_verify_tally(*args):
         valid, total, maj = inner(*args)
         total = total.astype(jnp.int32)
         maj = maj.astype(jnp.int32)
@@ -133,4 +145,4 @@ def compact_step_packed(axis_name: str | None = None):
             maj = jax.lax.pcast(maj, axis_name, to="varying")
         return jnp.concatenate([valid.astype(jnp.int32), total, maj])
 
-    return f
+    return txflow_verify_tally

@@ -58,8 +58,8 @@ class StageSlot:
     race auditor sees this as sanctioned handoffs, not a lockset."""
 
     __slots__ = (
-        "_dev", "_host", "_error", "_done", "readback_s", "_waited",
-        "_queued", "_sh",
+        "_dev", "_host", "_error", "_done", "readback_s", "ready_t",
+        "_waited", "_queued", "_sh",
     )
 
     def __init__(self, dev):
@@ -68,6 +68,10 @@ class StageSlot:
         self._error: BaseException | None = None
         self._done = threading.Event()
         self.readback_s = 0.0
+        # when the bytes were usable on the host, read on the thread that
+        # fetched them once it holds the interpreter lock again: the end
+        # of the engine's device_busy span for this step
+        self.ready_t: float | None = None
         self._waited = False
         self._queued = False
         self._sh = shared_field("parallel.StageSlot.buffer")  # txlint: shared(handoff)
@@ -82,7 +86,8 @@ class StageSlot:
             self._error = exc
         finally:
             self._dev = None  # drop the device ref as soon as bytes land
-            self.readback_s = monotonic() - t0
+            self.ready_t = monotonic()
+            self.readback_s = self.ready_t - t0
             self._sh.handoff(
                 "Event set()/wait() is the happens-before edge to the waiter"
             )

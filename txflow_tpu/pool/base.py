@@ -23,6 +23,7 @@ import threading
 
 from ..analysis.lockgraph import make_rlock
 from ..analysis.racegraph import shared_field
+from ..utils.clock import monotonic
 
 # Compact when at least this many dead entries can be dropped at once.
 COMPACT_THRESHOLD = 4096
@@ -40,6 +41,11 @@ class IngestLogPool:
         self._log: list[bytes] = []
         self._log_base = 0  # absolute position of _log[0]
         self._items: dict[bytes, object] = {}
+        # when the first item since the consumer's last take_first_new()
+        # was appended (0 = none since): the start of the engine's
+        # pickup_wait span, which so holds the rest of that frame's
+        # ingest too
+        self._first_new_t = 0.0
         # the ingest log + entry map, every reactor walk and engine drain
         # crosses threads through them
         self._sh_log = shared_field(f"pool.{type(self).__name__}.ingest_log")  # txlint: shared(self._mtx)
@@ -50,6 +56,8 @@ class IngestLogPool:
         self._sh_log.note_write()
         self._log.append(key)
         self._seq += 1
+        if not self._first_new_t:
+            self._first_new_t = monotonic()
         self._cond.notify_all()
 
     def _log_append_quiet(self, key: bytes) -> None:
@@ -61,9 +69,18 @@ class IngestLogPool:
         self._sh_log.note_write()
         self._log.append(key)
         self._seq += 1
+        if not self._first_new_t:
+            self._first_new_t = monotonic()
 
     def _log_notify(self) -> None:
         self._cond.notify_all()
+
+    def take_first_new(self) -> float:
+        """When the first item since the last call was accepted (0.0 =
+        none since), and start over: the consumer calls it as it drains."""
+        with self._mtx:
+            t, self._first_new_t = self._first_new_t, 0.0
+        return t
 
     def _log_compact(self) -> None:
         """Drop the longest dead prefix once it crosses the threshold.

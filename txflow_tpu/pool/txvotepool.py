@@ -377,6 +377,7 @@ class TxVotePool(IngestLogPool):
                     if tr.active and tr.sampled(vote.tx_hash):
                         t = monotonic()
                         tr.span(vote.tx_hash, SPAN_VOTE_INGEST, t, t)
+                        tr.first_vote(vote.tx_hash, t)
                 if accepted:  # an all-dup group must not wake consumers
                     self._log_notify()
                     self._notify_txs_available()
@@ -445,6 +446,7 @@ class TxVotePool(IngestLogPool):
         if tr.active and tr.sampled(vote.tx_hash):
             t = monotonic()
             tr.span(vote.tx_hash, SPAN_VOTE_INGEST, t, t)
+            tr.first_vote(vote.tx_hash, t)
 
     def _notify_txs_available(self) -> None:
         if self._notify_available and not self._notified_txs_available:

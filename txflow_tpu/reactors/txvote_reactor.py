@@ -28,7 +28,7 @@ from dataclasses import dataclass
 from typing import Callable
 
 from ..codec import amino
-from ..trace.tracer import NULL_TRACER, SPAN_PRE_DROP, SPAN_SIGN
+from ..trace.tracer import NULL_TRACER, SPAN_PRE_DROP, SPAN_SIGN, SPAN_SIGN_WAIT
 from ..utils.clock import monotonic
 from ..p2p.base import CHANNEL_TXVOTE, ChannelDescriptor, Reactor
 from ..pool.mempool import (
@@ -411,6 +411,11 @@ class TxVoteReactor(Reactor):
                 except (ErrTxInCache, ErrMempoolIsFull, ErrTxTooLarge):
                     continue
                 if traced:
+                    # sign_wait: mempool insert (where a sampled tx is
+                    # anchored) -> this walk took the tx up
+                    t_in = tr.anchored(vote.tx_hash)
+                    if t_in is not None:
+                        tr.span(vote.tx_hash, SPAN_SIGN_WAIT, t_in, t0)
                     tr.span(vote.tx_hash, SPAN_SIGN, t0, monotonic())
 
     # -- per-peer broadcast (reference :198-265) --

@@ -33,6 +33,8 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 from ..admission import ErrDuplicateTx, ErrOverloaded
 from ..pool.mempool import ErrMempoolIsFull, ErrTxInCache
+from ..trace.tracer import NULL_TRACER, SPAN_RPC
+from ..utils.clock import monotonic
 
 
 class RPCError(Exception):
@@ -346,6 +348,7 @@ class RPCServer:
         )
 
     def _broadcast_tx(self, q: dict) -> dict:
+        t0 = monotonic()  # rpc_ingest: request parsed -> tx in the mempool
         tx = _parse_tx_param(q["tx"])
         key = hashlib.sha256(tx).digest()
         adm = getattr(self.node, "admission", None)
@@ -374,7 +377,13 @@ class RPCServer:
             if adm is not None:
                 adm.forget(key)
             raise
-        return {"hash": key.hex().upper(), "code": 0}
+        tx_hash = key.hex().upper()
+        tr = getattr(self.node, "tracer", NULL_TRACER)
+        if tr.active and tr.sampled_key(key):
+            # ends at the mempool insert (where the tx was anchored and
+            # its sign_wait starts), so the two tile
+            tr.span(tx_hash, SPAN_RPC, t0, tr.anchored(tx_hash) or monotonic())
+        return {"hash": tx_hash, "code": 0}
 
     def _broadcast_tx_commit(self, q: dict) -> dict:
         """Submit + wait for the commit in one call (tendermint's
@@ -432,8 +441,11 @@ class RPCServer:
 
     def _health(self, q: dict) -> dict:
         """Full degraded-mode registry snapshot (health/registry.py) plus
-        the trace digest (p50/p99/p999 per span family + leak counters);
-        {} sections when the node runs without a monitor/tracer."""
+        the trace digest: p50/p99/p999 per span family, the engine's
+        stage families (pool_wait .. route, device_busy, gc_pause) among
+        them, the leak counter, and what each ring dropped (``dropped``
+        per-tx spans, ``stage_dropped`` stage spans). {} sections when
+        the node runs without a monitor/tracer."""
         mon = getattr(self.node, "health", None)
         out = dict(mon.snapshot()) if mon is not None else {}
         tracer = getattr(self.node, "tracer", None)
@@ -611,6 +623,7 @@ class RPCServer:
             # write to a just-reset connection raises before the pump
             # starts): everything past subscribe() runs under the finally
             sub = self.node.event_bus.subscribe(event_type)
+            tr = getattr(self.node, "tracer", NULL_TRACER)
             try:
                 send_frame(1, json.dumps({"subscribed": event_type}).encode())
 
@@ -640,8 +653,14 @@ class RPCServer:
                     ev = sub.get(timeout=0.5)
                     if ev is not None:
                         send_frame(1, json.dumps(_event_json(ev)).encode())
+                        # a sampled commit's publish span ends here: its
+                        # frame is on this subscriber's socket (the first
+                        # subscriber to send closes it)
+                        tr.finish(ev.span)
             finally:
                 self.node.event_bus.unsubscribe(event_type, sub)
+                for ev in sub.close():
+                    tr.abandon(ev.span)  # queued, never sent: no leak
                 # handler return closes the socket, unblocking the reader
         except (BrokenPipeError, ConnectionError, OSError):
             pass
@@ -916,7 +935,14 @@ class RPCServer:
 
     def _debug_jax_profile(self, q: dict) -> dict:
         """Start/stop a JAX profiler trace (the XLA-level tracing hook):
-        ?action=start&dir=/tmp/trace | ?action=stop."""
+        ?action=start&dir=/tmp/trace | ?action=stop. A session started
+        here holds, beside the device's planes (the step's module is
+        ``jit_txflow_verify_tally``, its scopes decompress /
+        double_scalar_mul / encode_compare / tally), the engine's stage
+        annotations in the host plane, on the same clock: pool_wait,
+        host_prep, dispatch, collect_wait, route, each with step= and
+        votes=, so an idle gap of the device can be put down to the stage
+        that covers it (perfbench/study/gap_stages.py reads both)."""
         import jax.profiler
 
         import os.path

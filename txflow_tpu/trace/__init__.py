@@ -12,7 +12,6 @@ from .tracer import (
     SPAN_DEVICE,
     SPAN_E2E,
     SPAN_GOSSIP_INGEST,
-    SPAN_LINGER,
     SPAN_LOCK_WAIT,
     SPAN_ORDER,
     SPAN_PREP,
@@ -31,7 +30,7 @@ __all__ = [
     "LATENCY_BUCKETS", "NULL_TRACER", "NullTracer", "TraceConfig",
     "TraceMetrics", "Tracer", "make_tracer",
     "SPAN_ADMISSION", "SPAN_COMMIT", "SPAN_DEVICE", "SPAN_E2E",
-    "SPAN_GOSSIP_INGEST", "SPAN_LINGER", "SPAN_LOCK_WAIT", "SPAN_ORDER",
+    "SPAN_GOSSIP_INGEST", "SPAN_LOCK_WAIT", "SPAN_ORDER",
     "SPAN_PREP", "SPAN_QUORUM", "SPAN_SIGN", "SPAN_TX_INGEST",
     "SPAN_VOTE_INGEST",
     "merge_by_tx", "to_chrome_trace", "write_chrome_trace",

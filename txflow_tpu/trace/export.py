@@ -9,8 +9,10 @@ and good enough to eyeball cross-host gossip latency).
 The output is the Chrome JSON trace format (the ``traceEvents`` array
 of ``ph:"X"`` complete events) which Perfetto and chrome://tracing open
 directly: one process per node, one track per span family in
-commit-path order, every event tagged with its tx hash in ``args`` so
-the Perfetto query engine can follow one transaction across nodes.
+commit-path order, every event tagged in ``args`` with its tx hash and
+the id of the engine step that carried it (0 = none), so the Perfetto
+query engine can follow one transaction across nodes and join it to the
+stage spans (``tx`` empty, same ``step``) of the step that decided it.
 """
 
 from __future__ import annotations
@@ -44,6 +46,7 @@ def merge_by_tx(dumps: list[dict]) -> dict[str, list[dict]]:
                     "name": s["name"],
                     "ts_us": ts,
                     "dur_us": max(0.0, (s["end"] - s["start"]) * 1e6),
+                    "step": s.get("step", 0),
                 }
             )
     for spans in out.values():
@@ -84,7 +87,7 @@ def to_chrome_trace(dumps: list[dict]) -> dict:
                     "tid": tid,
                     "ts": base_wall_us + (s["start"] - base_mono) * 1e6,
                     "dur": max(0.0, (s["end"] - s["start"]) * 1e6),
-                    "args": {"tx": s["tx"]},
+                    "args": {"tx": s["tx"], "step": s.get("step", 0)},
                 }
             )
     return {"traceEvents": events, "displayTimeUnit": "ms"}

@@ -17,7 +17,7 @@ def critical_path(pipeline_stats: dict, trace_digest: dict | None = None) -> dic
     Components: ``host_s`` (batch prep + commit routing, minus lock
     wait), ``device_s`` (blocked collecting verify tickets), ``lock_-
     wait_s`` (acquiring the engine mutex), ``linger_s`` (coalescer
-    deadline holds, from the trace histogram sums — merged + per-lane
+    deadline holds, from the trace histogram sums of the per-lane
     families; the priority/bulk split is exposed alongside as
     ``linger_prio_s`` / ``linger_bulk_s`` so a lane-split run shows
     WHICH lane paid the hold), ``network_residual_ms`` (e2e p50 minus
@@ -42,9 +42,7 @@ def critical_path(pipeline_stats: dict, trace_digest: dict | None = None) -> dic
         "host_s": host,
         "device_s": stats.get("dispatch_wait_s", 0.0),
         "lock_wait_s": lock_wait,
-        # legacy merged family + the per-lane families: a pre-lane trace
-        # has only "linger", a lane-split run only the per-lane ones
-        "linger_s": sum_s("linger") + linger_prio + linger_bulk,
+        "linger_s": linger_prio + linger_bulk,
     }
     busy = sum(parts.values())
     out = {k: round(v, 4) for k, v in parts.items()}
@@ -86,9 +84,9 @@ def critical_path(pipeline_stats: dict, trace_digest: dict | None = None) -> dic
     if e2e is not None:
         stage_sum = sum(
             lat.get(n, {}).get("p50") or 0.0
-            for n in ("vote_ingest", "host_prep", "device_verify",
-                      "quorum_latch", "commit_apply", "linger",
-                      "linger_prio", "linger_bulk")
+            for n in ("vote_ingest", "linger_prio", "linger_bulk",
+                      "host_prep", "dispatch", "device_busy", "route",
+                      "commit_apply")
         )
         out["network_residual_ms"] = round(max(0.0, e2e - stage_sum), 3)
     return out

@@ -19,13 +19,27 @@ constraints, in order:
    histograms.
 
 Leak accounting: ``begin()``/``finish()`` pairs (device tickets in
-flight, commit queue residency) are tracked in an open table;
-``open_count()`` must return 0 after quiescence — ``tools/soak.py
---overload`` asserts this over RPC, the same class of check as the
-PR 3 drain-on-stop claim-leak proof.
+flight, commit queue residency, commit events on their way to a
+socket) are tracked in an open table; ``open_count()`` must return 0
+after quiescence — ``tools/soak.py --overload`` asserts this over RPC,
+the same class of check as the PR 3 drain-on-stop claim-leak proof.
+
+Every record is ``(tx, name, start, end, step)``: ``step`` is the id of
+the engine step that carried or decided it (0 = none), so a tx's
+waterfall joins the step's. **Stage spans** (``STAGE_SPANS``: one per
+engine stage per step, ``tx`` empty, recorded whatever the tx sampling
+says) live in a ring of their own, so a flood of per-tx spans never
+evicts the step record; collector pauses (``gc_pause``, the generation
+in ``tx``) are stage spans kept in a third, lock-free ring because the
+hook that records them can fire while this tracer's lock is held.
 """
 
 from __future__ import annotations
+
+import gc
+import sys
+import threading
+from collections import deque
 
 from ..analysis.lockgraph import make_lock
 from ..utils.clock import monotonic, now_ns
@@ -44,12 +58,28 @@ SPAN_VOTE_INGEST = "vote_ingest"
 # marker at the drop instant, so a trace shows WHERE hostile traffic
 # died relative to the honest pipeline
 SPAN_PRE_DROP = "pre_verify_drop"
+# a sampled tx's first vote in the pool -> host_prep of the step that
+# drains it: what its votes waited for the engine and in the lane's hold
+# (per tx: a step that carries two txs has two, of different lengths)
+SPAN_VOTE_WAIT = "vote_wait"
+# served-tx waits: request parsed -> tx in the mempool (rpc/server.py),
+# mempool insert -> the sign walk takes the tx up (txvote_reactor.py)
+SPAN_RPC = "rpc_ingest"
+SPAN_SIGN_WAIT = "sign_wait"
+# engine stage spans, in engine order (engine/txflow.py records each
+# through ONE helper that also feeds pipeline_stats(), the Prometheus
+# pipeline_*_seconds counters and the profiler annotation)
+SPAN_POOL_WAIT = "pool_wait"
+# first vote accepted by the pool since the engine's last drain -> the
+# engine takes the batch up: its lane's hold begins, or its host_prep
+# where nothing is held. The thread hop from the inserting thread, or,
+# with a step in flight, the rest of that step's dispatch/collect/route.
+# It lies over those stages: no stretch of the engine thread's own time
+SPAN_PICKUP = "pickup_wait"
 SPAN_LOCK_WAIT = "lock_wait"
-SPAN_LINGER = "linger"
 # per-lane coalescer holds (ISSUE 12 verify lanes): the engine's bulk
-# lane records linger_bulk, the priority lane linger_prio; the plain
-# "linger" family remains for single-lane coalescers and old dumps —
-# report.py sums all three into the critical-path linger bucket
+# lane records linger_bulk, the priority lane linger_prio — report.py
+# sums both into the critical-path linger bucket
 SPAN_LINGER_PRIO = "linger_prio"
 SPAN_LINGER_BULK = "linger_bulk"
 # speculative quorum commit: decision-to-route-end window of a commit
@@ -57,9 +87,28 @@ SPAN_LINGER_BULK = "linger_bulk"
 # tail the early exit removed for that tx
 SPAN_SPEC = "spec_commit"
 SPAN_PREP = "host_prep"
-SPAN_DEVICE = "device_verify"
+SPAN_DISPATCH = "dispatch"
+# max(dispatch end, previous step's ready) -> packed result usable on
+# the host, as a host thread could stamp it (the staging ring's thread,
+# once it holds the interpreter lock again). Spans of one verifier never
+# overlap. NOT the device's own time: under load the stamp is late by
+# what that thread waited for the lock (the device's idle share is the
+# profiler's to give). Begun at dispatch, finished at collect: an
+# orphaned ticket is an open span
+SPAN_DEVICE = "device_busy"
+SPAN_COLLECT = "collect_wait"
+SPAN_ROUTE = "route"
+SPAN_ROUTE_TALLY = "route_tally"
+SPAN_ROUTE_COMMIT = "route_commit"
+SPAN_ROUTE_PURGE = "route_purge"
 SPAN_QUORUM = "quorum_latch"
 SPAN_COMMIT = "commit_apply"
+# commit event queued (engine/execution.py) -> frame handed to a
+# websocket subscriber's socket (rpc/server.py), or event_bus.publish's
+# return where nobody subscribes
+SPAN_PUBLISH = "publish"
+# a collection of Python's collector, generation in ``tx`` ("gen0".."gen2")
+SPAN_GC = "gc_pause"
 # catch-up sync (sync/): fetch = request sent -> response received,
 # verify = certificate batch re-verification, apply = commit-seam apply
 SPAN_SYNC_FETCH = "sync_fetch"
@@ -68,12 +117,21 @@ SPAN_SYNC_APPLY = "sync_apply"
 SPAN_E2E = "e2e"
 
 SPAN_ORDER = (
-    SPAN_ADMISSION, SPAN_TX_INGEST, SPAN_GOSSIP_INGEST, SPAN_SIGN,
-    SPAN_VOTE_INGEST, SPAN_PRE_DROP, SPAN_LOCK_WAIT, SPAN_LINGER, SPAN_LINGER_PRIO,
-    SPAN_LINGER_BULK, SPAN_PREP, SPAN_DEVICE, SPAN_QUORUM, SPAN_SPEC,
-    SPAN_COMMIT, SPAN_SYNC_FETCH, SPAN_SYNC_VERIFY, SPAN_SYNC_APPLY,
-    SPAN_E2E,
+    SPAN_RPC, SPAN_ADMISSION, SPAN_TX_INGEST, SPAN_GOSSIP_INGEST,
+    SPAN_SIGN_WAIT, SPAN_SIGN, SPAN_VOTE_INGEST, SPAN_PRE_DROP, SPAN_VOTE_WAIT,
+    SPAN_POOL_WAIT, SPAN_PICKUP, SPAN_LINGER_PRIO, SPAN_LINGER_BULK, SPAN_PREP,
+    SPAN_LOCK_WAIT, SPAN_DISPATCH, SPAN_DEVICE, SPAN_COLLECT, SPAN_ROUTE,
+    SPAN_ROUTE_TALLY, SPAN_QUORUM, SPAN_SPEC, SPAN_ROUTE_COMMIT,
+    SPAN_COMMIT, SPAN_ROUTE_PURGE, SPAN_PUBLISH, SPAN_GC,
+    SPAN_SYNC_FETCH, SPAN_SYNC_VERIFY, SPAN_SYNC_APPLY, SPAN_E2E,
 )
+
+# the step record: kept apart from the per-tx ring (see module docstring)
+STAGE_SPANS = frozenset((
+    SPAN_POOL_WAIT, SPAN_PICKUP, SPAN_LINGER_PRIO, SPAN_LINGER_BULK, SPAN_PREP,
+    SPAN_LOCK_WAIT, SPAN_DISPATCH, SPAN_DEVICE, SPAN_COLLECT, SPAN_ROUTE,
+    SPAN_ROUTE_TALLY, SPAN_ROUTE_COMMIT, SPAN_ROUTE_PURGE, SPAN_GC,
+))
 
 
 class NullTracer:
@@ -87,13 +145,13 @@ class NullTracer:
     def sampled_key(self, key) -> bool:
         return False
 
-    def span(self, tx_hash, name, start, end) -> None:
+    def span(self, tx_hash, name, start, end, step=0) -> None:
         pass
 
-    def begin(self, tx_hash, name, start=None) -> int:
+    def begin(self, tx_hash, name, start=None, step=0) -> int:
         return 0
 
-    def finish(self, span_id, end=None) -> None:
+    def finish(self, span_id, end=None, start=None) -> None:
         pass
 
     def abandon(self, span_id) -> None:
@@ -102,7 +160,22 @@ class NullTracer:
     def anchor(self, tx_hash, t=None) -> None:
         pass
 
+    def anchored(self, tx_hash) -> None:
+        return None
+
+    def first_vote(self, tx_hash, t) -> None:
+        pass
+
+    def take_first_vote(self, tx_hash) -> None:
+        return None
+
     def latch(self, tx_hash, name=SPAN_E2E, t=None) -> None:
+        pass
+
+    def install_gc_hook(self) -> None:
+        pass
+
+    def remove_gc_hook(self) -> None:
         pass
 
     def open_count(self) -> int:
@@ -183,6 +256,61 @@ class TraceMetrics:
         return out
 
 
+class _Ring:
+    """Preallocated overwrite-oldest record store (caller holds the lock)."""
+
+    __slots__ = ("cap", "buf", "n")
+
+    def __init__(self, cap: int):
+        self.cap = cap
+        self.buf: list = [None] * cap
+        self.n = 0  # records ever stored (Tracer.span); slot = n % cap
+
+    def items(self) -> list:
+        if self.n <= self.cap:
+            return self.buf[: self.n]
+        i = self.n % self.cap
+        return self.buf[i:] + self.buf[:i]
+
+    def dropped(self) -> int:
+        return max(0, self.n - self.cap)
+
+
+class _GcHook:
+    """The process's ONE ``gc.callbacks`` entry, fanned out to the
+    tracers of the nodes that are running. It never takes a tracer's
+    lock: a collection can start at any bytecode, inside ``span``
+    too, and the lock is not reentrant."""
+
+    def __init__(self):
+        self.tracers: list = []
+        self.mtx = threading.Lock()
+        self._t0 = 0.0
+        self._ann = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            # on the profiler's clock too, where the engine has brought
+            # JAX's profiler in (never imported from here)
+            prof = sys.modules.get("jax.profiler")
+            if prof is not None:
+                self._ann = prof.TraceAnnotation(SPAN_GC, generation=info["generation"])
+                self._ann.__enter__()
+            self._t0 = monotonic()
+            return
+        t1 = monotonic()
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        rec = (f"gen{info['generation']}", SPAN_GC, self._t0, t1, 0)
+        for tr in self.tracers:
+            tr._gc.append(rec)
+            tr._gc_n += 1
+
+
+_GC_HOOK = _GcHook()
+
+
 class Tracer:
     """Per-node span recorder. All timestamps are utils.clock.monotonic
     seconds; ``base_wall_ns``/``base_mono`` anchor them to the wall
@@ -201,11 +329,16 @@ class Tracer:
         self.seed = int(cfg.seed) & 0xFFFFFFFF
         self.capacity = max(16, int(cfg.ring_capacity))
         self.node_id = node_id
-        self._ring: list = [None] * self.capacity
-        self._n = 0  # spans ever recorded; ring index = _n % capacity
+        self._ring = _Ring(self.capacity)  # per-tx spans
+        self._stages = _Ring(self.capacity)  # STAGE_SPANS: the step record
+        # collector pauses, appended by _GcHook without the lock
+        self._gc: deque = deque(maxlen=self.capacity)
+        self._gc_n = 0
+        self._gc_folded = 0  # pauses already observed into the histogram
         self._open: dict[int, tuple] = {}
         self._next_id = 1
         self._anchors: dict[str, float] = {}
+        self._first_votes: dict[str, float] = {}
         self._anchor_cap = 4 * self.capacity
         self._lk = make_lock("trace.Tracer._lk")
         self.base_wall_ns = now_ns()
@@ -230,18 +363,20 @@ class Tracer:
 
     # -- span recording --
 
-    def _record(self, tx_hash: str, name: str, start: float, end: float) -> None:
+    def span(self, tx_hash: str, name: str, start: float, end: float,
+             step: int = 0) -> None:
+        """Record a complete span (both ends measured by the caller).
+        ``step`` names the engine step that carried or decided it."""
+        ring = self._stages if name in STAGE_SPANS else self._ring
+        rec = (tx_hash, name, start, end, step)
         with self._lk:
-            self._ring[self._n % self.capacity] = (tx_hash, name, start, end)
-            self._n += 1
+            ring.buf[ring.n % ring.cap] = rec
+            ring.n += 1
         if self.metrics is not None:
             self.metrics.observe(name, max(0.0, end - start))
 
-    def span(self, tx_hash: str, name: str, start: float, end: float) -> None:
-        """Record a complete span (both ends measured by the caller)."""
-        self._record(tx_hash, name, start, end)
-
-    def begin(self, tx_hash: str, name: str, start: float | None = None) -> int:
+    def begin(self, tx_hash: str, name: str, start: float | None = None,
+              step: int = 0) -> int:
         """Open a cross-thread span; returns an id for finish()/abandon().
         Every begun span must be closed — open_count() is the leak
         detector the soak asserts against."""
@@ -249,17 +384,22 @@ class Tracer:
         with self._lk:
             sid = self._next_id
             self._next_id += 1
-            self._open[sid] = (tx_hash, name, t)
+            self._open[sid] = (tx_hash, name, t, step)
         return sid
 
-    def finish(self, span_id: int, end: float | None = None) -> None:
+    def finish(self, span_id: int, end: float | None = None,
+               start: float | None = None) -> None:
+        """Close and record. ``start`` replaces the begin time where the
+        true start is known only now (device_busy: the later of its
+        dispatch and the previous step's ready time)."""
         if not span_id:
             return
         t = monotonic() if end is None else end
         with self._lk:
             entry = self._open.pop(span_id, None)
         if entry is not None:
-            self._record(entry[0], entry[1], entry[2], t)
+            t0 = entry[2] if start is None else start
+            self.span(entry[0], entry[1], t0, t, entry[3])
 
     def abandon(self, span_id: int) -> None:
         """Close without recording (work shed or superseded mid-span)."""
@@ -282,13 +422,55 @@ class Tracer:
                 self._anchors.pop(next(iter(self._anchors)))
             self._anchors[tx_hash] = tm
 
+    def anchored(self, tx_hash: str) -> float | None:
+        """The anchor's time, left in place (the sign walk's wait starts
+        at the mempool insert, which is where a tx is anchored)."""
+        with self._lk:
+            return self._anchors.get(tx_hash)
+
+    def first_vote(self, tx_hash: str, t: float) -> None:
+        """When a sampled tx's first vote entered the vote pool (kept
+        once, bounded like the anchors): vote_wait's start."""
+        with self._lk:
+            if tx_hash in self._first_votes:
+                return
+            if len(self._first_votes) >= self._anchor_cap:
+                self._first_votes.pop(next(iter(self._first_votes)))
+            self._first_votes[tx_hash] = t
+
+    def take_first_vote(self, tx_hash: str) -> float | None:
+        with self._lk:
+            return self._first_votes.pop(tx_hash, None)
+
     def latch(self, tx_hash: str, name: str = SPAN_E2E, t: float | None = None) -> None:
         """Close the anchored span (commit applied). No-op when the
         anchor aged out or the tx was never anchored."""
         with self._lk:
             t0 = self._anchors.pop(tx_hash, None)
         if t0 is not None:
-            self._record(tx_hash, name, t0, monotonic() if t is None else t)
+            self.span(tx_hash, name, t0, monotonic() if t is None else t)
+
+    # -- collector pauses --
+
+    def install_gc_hook(self) -> None:
+        """Record gc_pause spans from now on (Node.start). One
+        ``gc.callbacks`` entry a process however many tracers ask."""
+        hook = _GC_HOOK
+        with hook.mtx:
+            if self in hook.tracers:
+                return
+            if not hook.tracers:
+                gc.callbacks.append(hook)
+            hook.tracers = hook.tracers + [self]  # the hook iterates lock-free
+
+    def remove_gc_hook(self) -> None:
+        hook = _GC_HOOK
+        with hook.mtx:
+            if self not in hook.tracers:
+                return
+            hook.tracers = [t for t in hook.tracers if t is not self]
+            if not hook.tracers:
+                gc.callbacks.remove(hook)
 
     # -- introspection --
 
@@ -297,36 +479,46 @@ class Tracer:
             return len(self._open)
 
     def spans(self) -> list[dict]:
-        """Ring contents, oldest first, as export-ready dicts."""
+        """All three rings merged by start, as export-ready dicts."""
         with self._lk:
-            n = self._n
-            if n <= self.capacity:
-                buf = list(self._ring[:n])
-            else:
-                i = n % self.capacity
-                buf = self._ring[i:] + self._ring[:i]
+            buf = self._ring.items() + self._stages.items()
+        buf += list(self._gc)
+        buf.sort(key=lambda r: r[2])
         return [
-            {"tx": tx, "name": name, "start": s, "end": e}
-            for (tx, name, s, e) in buf
+            {"tx": tx, "name": name, "start": s, "end": e, "step": step}
+            for (tx, name, s, e, step) in buf
         ]
 
     def dropped(self) -> int:
+        """Per-tx spans overwritten before anyone read them."""
         with self._lk:
-            return max(0, self._n - self.capacity)
+            return self._ring.dropped()
 
     def digest(self) -> dict:
-        """p50/p99/p999 per span family + leak counters (/health)."""
+        """p50/p99/p999 per span family + leak counters (/health).
+        ``dropped`` counts overwritten per-tx spans, ``stage_dropped``
+        overwritten stage spans (each ring holds ``ring_capacity``)."""
         with self._lk:
-            recorded = self._n
+            recorded = self._ring.n + self._stages.n
+            dropped = self._ring.dropped()
+            stage_dropped = self._stages.dropped()
             open_spans = len(self._open)
+        gc_n = self._gc_n
         d = {
             "enabled": True,
             "sample_rate": self.sample_rate,
-            "recorded": recorded,
-            "dropped": max(0, recorded - self.capacity),
+            "recorded": recorded + gc_n,
+            "dropped": dropped,
+            "stage_dropped": stage_dropped + max(0, gc_n - self.capacity),
             "open_spans": open_spans,
         }
         if self.metrics is not None:
+            # the hook cannot touch the histograms' locks: fold here
+            new = min(gc_n - self._gc_folded, len(self._gc))
+            self._gc_folded = gc_n
+            if new > 0:
+                for rec in list(self._gc)[-new:]:
+                    self.metrics.observe(SPAN_GC, rec[3] - rec[2])
             self.metrics.open_spans.set(open_spans)
             d["latency_ms"] = self.metrics.quantiles_ms()
         return d
@@ -344,10 +536,12 @@ class Tracer:
 
     def reset(self) -> None:
         with self._lk:
-            self._ring = [None] * self.capacity
-            self._n = 0
+            self._ring = _Ring(self.capacity)
+            self._stages = _Ring(self.capacity)
             self._open.clear()
             self._anchors.clear()
+            self._first_votes.clear()
+        self._gc.clear()
 
 
 def make_tracer(

@@ -31,18 +31,34 @@ EventEvidence = "Evidence"  # equivocation captured (types/evidence.py)
 class Event:
     type: str
     data: object = None
+    # open txtrace ``publish`` span of a sampled commit event (0 = none):
+    # whoever hands the event's frame to a subscriber's socket finishes it
+    span: int = 0
 
 
 class Subscription:
     def __init__(self, capacity: int = 1000):
         self._q: queue.Queue[Event] = queue.Queue(maxsize=capacity)
+        self._mtx = threading.Lock()
+        self._closed = False
 
     def deliver(self, ev: Event) -> bool:
-        try:
-            self._q.put_nowait(ev)
-            return True
-        except queue.Full:
-            return False
+        with self._mtx:
+            if self._closed:
+                return False
+            try:
+                self._q.put_nowait(ev)
+                return True
+            except queue.Full:
+                return False
+
+    def close(self) -> list[Event]:
+        """Refuse further deliveries and hand back what was never read
+        (a publish that copied the subscriber list before the
+        unsubscribe cannot slip an event in after this)."""
+        with self._mtx:
+            self._closed = True
+        return self.drain()
 
     def get(self, timeout: float | None = None) -> Event | None:
         try:
@@ -81,15 +97,16 @@ class EventBus:
             if sub in subs:
                 subs.remove(sub)
 
-    def publish(self, event_type: str, data: object = None) -> None:
-        ev = Event(event_type, data)
+    def publish(self, event_type: str, data: object = None, span: int = 0) -> int:
+        """Returns how many subscriber queues took the event."""
+        ev = Event(event_type, data, span)
         with self._mtx:
             subs = list(self._subs.get(event_type, []))
             cbs = list(self._callbacks.get(event_type, []))
-        for s in subs:
-            s.deliver(ev)
+        taken = sum(1 for s in subs if s.deliver(ev))
         for cb in cbs:
             cb(ev)
+        return taken
 
 
 @dataclass

@@ -474,8 +474,8 @@ class TxFlowMetrics:
         # pipeline exists to close. The *_seconds counters are the
         # per-stage breakdown profile_host.py prints.
         self.pipeline_depth = r.gauge("txflow", "pipeline_depth", "verify tickets in flight")
-        self.pipeline_overlap_ratio = r.gauge("txflow", "pipeline_overlap_ratio", "device-busy / engine-active wall time")
-        self.pipeline_device_idle = r.gauge("txflow", "pipeline_device_idle_seconds", "engine-active seconds with no verify in flight")
+        self.pipeline_overlap_ratio = r.gauge("txflow", "pipeline_overlap_ratio", "dispatch -> result usable on the host (device time plus the readback thread waiting for the interpreter lock), summed, / engine-active wall time")
+        self.pipeline_device_idle = r.gauge("txflow", "pipeline_device_idle_seconds", "engine-active seconds less the dispatch -> result-usable seconds: a lower bound of device idle time")
         self.pipeline_prep_seconds = r.counter("txflow", "pipeline_prep_seconds", "host batch-prep + dispatch seconds")
         self.pipeline_wait_seconds = r.counter("txflow", "pipeline_wait_seconds", "seconds blocked collecting tickets")
         self.pipeline_route_seconds = r.counter("txflow", "pipeline_route_seconds", "commit-routing seconds")

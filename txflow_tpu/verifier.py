@@ -257,7 +257,18 @@ class VerifyTicket:
     it may be called exactly once per ticket from any thread, and any
     cache claims the call took are settled (stored or released) by the
     time it returns or raises — a ticket never leaks claims.
+
+    ``ready_t`` is the moment (utils.clock monotonic) the result was
+    usable on the host, where the ticket can tell: the staging ring's
+    thread stamps it when its readback has returned AND the thread holds
+    the interpreter lock again (under load that is later than the device
+    finished, by the lock wait), and wrapping tickets hand it through.
+    None = not stamped; the engine then takes the end of its own
+    ``result()`` call (engine/txflow.py ``_collect``, the device_busy
+    span's end).
     """
+
+    ready_t: float | None = None
 
     def result(self) -> TallyResult:
         raise NotImplementedError
@@ -267,10 +278,11 @@ class ReadyTicket(VerifyTicket):
     """Already-completed ticket: eager paths (scalar verifier, fallbacks)
     present the same submit/collect surface with the work done inline."""
 
-    __slots__ = ("_result",)
+    __slots__ = ("_result", "ready_t")
 
     def __init__(self, result: TallyResult):
         self._result = result
+        self.ready_t = monotonic()  # the work ran inline: ready as it is built
 
     def result(self) -> TallyResult:
         return self._result
@@ -293,6 +305,10 @@ class _RingHandle:
     def get(self) -> np.ndarray:
         return self._ring.result(self._slot)
 
+    @property
+    def ready_t(self) -> float | None:
+        return self._slot.ready_t
+
 
 def _force_readback(packed) -> np.ndarray:
     """The ONE blocking device->host readback, ring-aware: staged handles
@@ -309,7 +325,7 @@ class _FusedDeviceTicket(VerifyTicket):
     """Dispatched fused kernel (no cache): readback + unpack at result()."""
 
     __slots__ = ("_packed", "_n", "_n_slots", "_n_shards", "_b", "_b_slots",
-                 "_keep", "_done")
+                 "_keep", "_done", "ready_t")
 
     def __init__(self, packed, n, n_slots, n_shards, b, b_slots, keep):
         self._packed = packed  # device array, readback not yet forced
@@ -320,12 +336,14 @@ class _FusedDeviceTicket(VerifyTicket):
         self._b_slots = b_slots
         self._keep = keep
         self._done: TallyResult | None = None
+        self.ready_t = None
 
     def result(self) -> TallyResult:
         if self._done is not None:
             return self._done
         note_blocking("verifier.device-readback")
         packed = _force_readback(self._packed)  # the ONE blocking readback
+        self.ready_t = getattr(self._packed, "ready_t", None)  # ring-staged only
         self._packed = None
         rows = packed.reshape(self._n_shards, -1)
         bs = self._b // self._n_shards
@@ -348,7 +366,7 @@ class _CachedDeviceTicket(VerifyTicket):
     __slots__ = ("_cache", "_packed", "_keepalive", "_miss_idx", "_miss_keys",
                  "_keys", "_valid", "_tx_slot", "_n_slots", "_prior",
                  "_quorum", "_keep", "_pending", "_powers", "_val_idx",
-                 "_n_shards", "_b", "_done")
+                 "_n_shards", "_b", "_done", "ready_t")
 
     def __init__(self, cache, packed, keepalive, miss_idx, miss_keys, keys,
                  valid, tx_slot, n_slots, prior, quorum, keep, pending,
@@ -371,6 +389,7 @@ class _CachedDeviceTicket(VerifyTicket):
         self._n_shards = n_shards
         self._b = b
         self._done: TallyResult | None = None
+        self.ready_t = None
 
     def result(self) -> TallyResult:
         if self._done is not None:
@@ -389,6 +408,7 @@ class _CachedDeviceTicket(VerifyTicket):
             self._keepalive.__exit__(None, None, None)
             self._cache.release_many(self._miss_keys)
             raise
+        self.ready_t = getattr(self._packed, "ready_t", None)  # ring-staged only
         self._packed = None
         self._keepalive.__exit__(None, None, None)
         rows = packed.reshape(self._n_shards, -1)
@@ -1492,6 +1512,12 @@ class _ResilientTicket(VerifyTicket):
         self._args = args
         self._done: TallyResult | None = None
 
+    @property
+    def ready_t(self) -> float | None:
+        # a batch re-served by the policy path has no stamp (the failed
+        # inner ticket never set one): the collect time stands in
+        return self._inner.ready_t
+
     def result(self) -> TallyResult:
         if self._done is not None:
             return self._done
@@ -1863,6 +1889,7 @@ class VerifierMux:
                 continue
             self._split(batch, merged)
             for r in batch:
+                r.ready_t = ticket.ready_t  # the merged batch's, for each waiter
                 r.done.set()
 
     def _serve(self, batch: list) -> None:
@@ -1906,11 +1933,15 @@ class _MuxTicket(VerifyTicket):
             self._done = self._mux._await(self._req)
         return self._done
 
+    @property
+    def ready_t(self) -> float | None:
+        return self._req.ready_t
+
 
 class _MuxReq:
     __slots__ = (
         "msgs", "sigs", "val_idx", "tx_slot", "n_slots", "prior",
-        "done", "result", "error", "claimed",
+        "done", "result", "error", "claimed", "ready_t",
     )
 
     def __init__(self, msgs, sigs, val_idx, tx_slot, n_slots, prior, done):
@@ -1923,6 +1954,7 @@ class _MuxReq:
         self.done = done
         self.result = None
         self.error = None
+        self.ready_t: float | None = None  # the merged ticket's stamp
         # exactly-once service marker (set under the mux lock): the
         # dispatcher claims requests it serves; a caller that raced stop()
         # claims its own request back and serves it inline — never both
