@@ -359,3 +359,28 @@ def test_health_config_validation_defaults():
     assert cfg.tick_interval > 0
     assert cfg.reconnect_base <= cfg.reconnect_cap
     assert cfg.score_floor < 0 < cfg.score_max
+
+
+def test_registry_reports_dedup_evictions():
+    """/health's progress section counts what each dedup set has pushed
+    out at capacity: 0 on a fresh node, then exactly the pushes beyond
+    capacity (a refresh of a present key or a remove evicts nothing)."""
+    from txflow_tpu.node import LocalNet
+
+    net = LocalNet(1, use_device_verifier=False)  # never started: refresh reads state only
+    node = net.nodes[0]
+    fields = ("mempool_dedup_evictions", "txvote_dedup_evictions", "committed_evictions")
+    reg = node.health.registry
+    reg.refresh(node)
+    progress = reg.snapshot()["progress"]
+    assert {f: progress[f] for f in fields} == dict.fromkeys(fields, 0)
+
+    sets = (node.mempool.cache, node.tx_vote_pool.cache, node.txflow._committed)
+    for extra, lru in zip((7, 5, 3), sets):
+        for i in range(lru.size + extra):
+            assert lru.push(i.to_bytes(32, "big"))
+        assert not lru.push((lru.size + extra - 1).to_bytes(32, "big"))  # a refresh
+        lru.remove((lru.size + extra - 2).to_bytes(32, "big"))
+    reg.refresh(node)
+    progress = reg.snapshot()["progress"]
+    assert [progress[f] for f in fields] == [7, 5, 3]

@@ -5,6 +5,11 @@ max-msg-size boundary (:305), byte accounting (:357), cache LRU behavior.
 
 import hashlib
 import os
+import random
+import statistics
+import sys
+import threading
+import time
 
 import pytest
 
@@ -20,7 +25,7 @@ from txflow_tpu.pool import (
 from txflow_tpu.pool.txvotepool import vote_key
 from txflow_tpu.types import MockPV, TxVote
 from txflow_tpu.types.tx_vote import encode_tx_vote
-from txflow_tpu.utils.cache import LRUCache
+from txflow_tpu.utils.cache import LRUCache, UnlockedLRUCache
 from txflow_tpu.utils.config import MempoolConfig
 
 CHAIN_ID = "txflow-test"
@@ -86,6 +91,150 @@ def test_unlocked_lru_cache_matches_locked_and_guards_free_threading():
     # GIL build -> owner-serialized unlocked cache; size<=0 -> NopCache
     assert isinstance(cache_mod.make_lru(3), UnlockedLRUCache)
     assert isinstance(cache_mod.make_lru(0), cache_mod.NopCache)
+
+
+class _ListLRU:
+    """The plain model: a list, oldest first."""
+
+    def __init__(self, size: int):
+        self.size, self.keys, self.evictions = size, [], 0
+
+    def push(self, key) -> bool:
+        if key in self.keys:
+            self.keys.remove(key)
+            self.keys.append(key)
+            return False
+        if len(self.keys) >= self.size:
+            del self.keys[0]
+            self.evictions += 1
+        self.keys.append(key)
+        return True
+
+    def remove(self, key) -> None:
+        if key in self.keys:
+            self.keys.remove(key)
+
+
+@pytest.mark.parametrize("size", [3, 64, 4096])
+@pytest.mark.parametrize("cls", [LRUCache, UnlockedLRUCache])
+def test_lru_set_matches_list_model(cls, size):
+    """A seeded random walk of push / remove / reset / in / len: every
+    return value, the eviction count and the final membership equal a
+    list-based LRU's. Keys come from 1.5 capacities, so the set is full
+    and evicting for most of the walk, and refreshes are common."""
+    rng = random.Random(29_000 + size)
+    universe = [b"k%d" % i for i in range(size + size // 2 + 1)]
+    c, model = cls(size), _ListLRU(size)
+    n_ops = max(3000, 10 * size)
+    reset_at = {n_ops // 2, n_ops // 2 + 1}  # a reset, and one of an empty set
+    for step in range(n_ops):
+        key = rng.choice(universe)
+        op = rng.random()
+        if step in reset_at:
+            c.reset()
+            model.keys.clear()
+        elif op < 0.70:
+            assert c.push(key) == model.push(key), (step, key)
+        elif op < 0.80:
+            c.remove(key)
+            model.remove(key)
+        elif op < 0.95:
+            assert (key in c) == (key in model.keys), (step, key)
+        assert len(c) == len(model.keys), step
+    assert c.evictions == model.evictions > 0
+    assert [k for k in universe if k in c] == [k for k in universe if k in model.keys]
+    # recency order too: fresh keys push the old ones out oldest first
+    for i in range(size):
+        oldest, full = model.keys[0], len(model.keys) >= size
+        assert c.push(b"fresh%d" % i) and model.push(b"fresh%d" % i)
+        assert (oldest in c) == (not full), i
+    assert c.evictions == model.evictions
+
+
+@pytest.mark.parametrize("cls", [LRUCache, UnlockedLRUCache])
+def test_lru_eviction_cost_is_flat(cls):
+    """A ratio, not a time, so that it holds on any CPU: at the flood's
+    capacity (69,632) an evicting push costs at most 3 non-evicting ones,
+    and 300,000 evicting pushes later it costs no more than at the start.
+    (The plain-dict form read 23x and rising: each eviction scanned the
+    dead slots of the evictions before it.) Medians over blocks of 10,000
+    on the thread's CPU clock; the better of three walks, since the gate
+    is on the code's cost and not on what else runs on the box."""
+    size, block = 69_632, 10_000
+    keys = [i.to_bytes(8, "big") for i in range(370_000)]  # 6 blocks fill it, 30 evict
+    verdicts = []
+    for _ in range(3):
+        c = cls(size)
+        push = c.push
+        costs = []
+        for lo in range(0, len(keys) - block + 1, block):
+            t0 = time.thread_time()
+            for key in keys[lo : lo + block]:
+                push(key)
+            costs.append(time.thread_time() - t0)
+        n_fill = size // block  # whole blocks that never evict
+        filling, full = costs[:n_fill], costs[n_fill + 1 :]
+        assert c.evictions == len(keys) - size and len(c) == size
+        ratio = statistics.median(full) / statistics.median(filling)
+        growth = statistics.median(full[-5:]) / statistics.median(full[:5])
+        verdicts.append((ratio, growth))
+        if ratio <= 3.0 and growth <= 1.5:
+            break
+    else:
+        pytest.fail(f"(evicting / non-evicting, last five / first five full blocks): {verdicts}")
+    print(f"evicting / non-evicting push {ratio:.2f}, last / first {growth:.2f}")
+
+
+def test_unlocked_lru_membership_is_safe_beside_an_evicting_owner():
+    """The reads other threads make without the owner's mutex (`in`,
+    ``TxVotePool.in_cache`` and the engine's committed-set probe) beside
+    one owner that pushes, refreshes and evicts: no reader ever raises or
+    sees the set over capacity, a key the owner pinned by refreshing it
+    is never reported missing, and the owner's own bookkeeping comes out
+    exact. A short switch interval, more threads than the box has to
+    spare, bounded in time."""
+    size, n_push = 256, 120_000
+    c = UnlockedLRUCache(size)
+    pinned = b"pinned"
+    c.push(pinned)
+    stop = threading.Event()
+    faults: list = []
+
+    def reader():
+        try:
+            while not stop.is_set():
+                if pinned not in c:
+                    faults.append("the pinned key read as missing")
+                if len(c) > size:
+                    faults.append("over capacity")
+                if b"never pushed" in c:
+                    faults.append("a key nobody pushed read as present")
+        except Exception as e:  # surfaced below: a reader must never raise
+            faults.append(repr(e))
+
+    readers = [threading.Thread(target=reader, daemon=True) for _ in range(12)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in readers:
+            t.start()
+        deadline = time.monotonic() + 20
+        for i in range(n_push):
+            c.push(i.to_bytes(8, "big"))
+            if i % 100 == 0:  # well inside `size` pushes: never the oldest
+                assert not c.push(pinned)
+            if i % 4096 == 0:
+                assert time.monotonic() < deadline
+        stop.set()
+        for t in readers:
+            t.join(timeout=10)
+    finally:
+        stop.set()
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in readers)
+    assert faults == []
+    assert len(c) == size and c.evictions == n_push + 1 - size
+    assert all(i.to_bytes(8, "big") in c for i in range(n_push - size + 1, n_push))
 
 
 # ---- TxVotePool ----

@@ -2221,6 +2221,12 @@ class TxFlow:
             sets = list(self.vote_sets.values())
         return [(vs.tx_hash, vs.stake()) for vs in sets]
 
+    @property
+    def committed_evictions(self) -> int:
+        """Hashes the recently-committed set has pushed out at capacity
+        (health/registry.py reports it beside the pools' counters)."""
+        return self._committed.evictions
+
     def is_tx_committed(self, tx_hash: str) -> bool:
         """Committed via EITHER path: the fast path (TxStore certificate)
         or a block that carried it (engine claim mark). A tx reaped into a

@@ -145,6 +145,13 @@ class DegradedModeRegistry:
             "mempool_size": node.mempool.size(),
             "txvote_seq": node.tx_vote_pool.seq(),
             "txvotepool_size": node.tx_vote_pool.size(),
+            # keys the dedup sets have pushed out at capacity: once a set
+            # is full every new key evicts one (utils/cache.py), so these
+            # rise with the ingest rate; a vote set that evicts faster
+            # than txs commit re-admits relayed votes as new
+            "mempool_dedup_evictions": node.mempool.cache.evictions,
+            "txvote_dedup_evictions": node.tx_vote_pool.cache.evictions,
+            "committed_evictions": node.txflow.committed_evictions,
             "committed_txs": int(node.metrics.committed_txs.value()),
         }
         pipe = getattr(node.txflow, "pipeline_stats", None)

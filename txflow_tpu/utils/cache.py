@@ -3,14 +3,28 @@
 push() returns False when the key is already cached — refreshing its
 recency, exactly like the reference's Push (list.MoveToBack before the
 false return) — and at capacity the least-recently-pushed entry is
-evicted. Implemented on a plain insertion-ordered dict (delete +
-re-insert = move-to-back): measurably cheaper per push than the previous
-OrderedDict, and the hot pools pay this once per ingest (a top-10
-host-path item at bench rates, r5 instrumented profile).
+evicted, exactly one. The container is a ``collections.OrderedDict``
+(``move_to_end`` to refresh, ``popitem(last=False)`` to evict): both are
+constant-time at any fill. The plain dict this file used between r5 and
+PR 29 was cheaper per push only while a set never filled; once it did,
+``del m[next(iter(m))]`` walked the dead slots that earlier evictions
+had left at the front of the dict's entry array, tens of thousands
+between two resizes, and cost the flood over a third of its rate (PERF.md
+section 6, PR 29). The dedup sets fill within seconds and evict on every
+push from then on, and the hot pools pay a push per ingest, so the steady
+state is the case that counts.
+
+Thread safety, for every class here: mutations belong to the owner's
+lock (the set's own in ``LRUCache`` / ``LRUMap``, the owner's mutex for
+``UnlockedLRUCache``); the only operations other threads run without it
+are ``in`` on a set and ``LRUMap.peek``, both the C-level ``dict``
+lookup that ``OrderedDict`` inherits, atomic under the GIL (see
+``UnlockedLRUCache``).
 """
 
 from __future__ import annotations
 
+import functools
 import sys
 import threading
 from collections import OrderedDict
@@ -34,86 +48,68 @@ def _gil_enabled() -> bool:
 _GIL_ENABLED = _gil_enabled()
 
 
-class LRUCache:
+class _LRU:
+    """The file's one LRU core: an ``OrderedDict`` oldest-first, a
+    capacity, and the ONE place where recency and eviction are decided
+    (``_admit``). Every step is constant-time whatever the fill and
+    however long the set has been evicting: ``move_to_end`` relinks a
+    node, ``popitem(last=False)`` unlinks the head."""
+
     def __init__(self, size: int):
         if size <= 0:
             raise ValueError("cache size must be positive")
         self.size = size
-        self._mtx = threading.Lock()
-        self._map: dict[bytes, None] = {}
+        self.evictions = 0  # keys pushed out at capacity, ever (health/registry.py)
+        self._map: OrderedDict[bytes, object] = OrderedDict()
 
-    def push(self, key: bytes) -> bool:
-        """Add key; False if already present (recency refreshed)."""
-        with self._mtx:
-            m = self._map
-            if key in m:
-                del m[key]  # re-insert puts it at the back (MoveToBack)
-                m[key] = None
-                return False
-            if len(m) >= self.size:
-                del m[next(iter(m))]
-            m[key] = None
-            return True
-
-    def remove(self, key: bytes) -> None:
-        with self._mtx:
-            self._map.pop(key, None)
-
-    def reset(self) -> None:
-        with self._mtx:
-            self._map.clear()
-
-    def __contains__(self, key: bytes) -> bool:
-        with self._mtx:
-            return key in self._map
-
-    def __len__(self) -> int:
-        with self._mtx:
-            return len(self._map)
+    def _admit(self, key: bytes) -> bool:
+        """Add key, or refresh it (MoveToBack); False if it was present.
+        At capacity exactly one key goes, the least recently admitted."""
+        m = self._map
+        if key in m:
+            m.move_to_end(key)
+            return False
+        if len(m) >= self.size:
+            m.popitem(last=False)
+            self.evictions += 1
+        m[key] = None
+        return True
 
 
-class UnlockedLRUCache:
-    """LRUCache without the internal lock, for owners that already
-    serialize every MUTATION under their own mutex (both pools mutate
-    their dedup caches exclusively under the pool lock; the engine's
-    committed-set under the engine lock). Lock-free READS (``in``) from
-    other threads stay safe: membership tests on a plain dict never
-    observe torn state under the GIL, and the reactor's in_cache peek
-    tolerates stale answers by falling back to the authoritative
-    check_tx path.
+class UnlockedLRUCache(_LRU):
+    """The LRU set without a lock, for owners that already serialize
+    every MUTATION under their own mutex (both pools mutate their dedup
+    caches exclusively under the pool lock; the engine's committed-set
+    under the engine lock; the admission dedup under the controller's).
 
-    The safety argument is CPython-specific and GIL-specific: ``in``,
-    ``del``, and item assignment on a dict are single bytecode-dispatched
-    C operations, and the GIL guarantees a reader never observes a dict
-    mid-resize or mid-insert. It does NOT hold on free-threaded (PEP 703)
-    builds, where an unsynchronized reader racing push()'s delete +
-    re-insert pair is genuine undefined behavior. On such builds (checked
-    once at construction via sys._is_gil_enabled) the constructor
-    transparently returns a locked ``LRUCache`` instead — every call site
-    keeps its semantics and pays the lock only where the GIL no longer
-    provides it."""
+    What other threads may run WITHOUT that mutex is ``in`` alone
+    (``TxVotePool.in_cache``, the engine's ``_committed.__contains__``).
+    ``OrderedDict`` inherits ``__contains__`` from ``dict``: a C-level
+    hash lookup over bytes keys that reads the dict's table and never the
+    order list that ``move_to_end`` / ``popitem`` relink, runs no Python
+    code (bytes hash and compare in C) and so cannot lose the GIL half
+    way: a reader never observes a table mid-resize or mid-insert. Its
+    answer may be stale by one racing push, which the callers tolerate
+    by falling back to the authoritative check_tx path (``LRUMap.peek``
+    states the same for ``get``). ``len`` is as safe; ``push``,
+    ``remove`` and ``reset`` are the owner's, under its mutex: two
+    unserialized pushes could both evict.
+
+    The argument is CPython-specific and GIL-specific. It does NOT hold
+    on free-threaded (PEP 703) builds, where an unsynchronized reader
+    racing a push is genuine undefined behavior. On such builds (weighed
+    once at import, ``_GIL_ENABLED``) the constructor transparently
+    returns a locked ``LRUCache`` instead — every call site keeps its
+    semantics and pays the lock only where the GIL no longer provides
+    it."""
 
     def __new__(cls, size: int):
         if not _GIL_ENABLED:
             return LRUCache(size)
         return object.__new__(cls)
 
-    def __init__(self, size: int):
-        if size <= 0:
-            raise ValueError("cache size must be positive")
-        self.size = size
-        self._map: dict[bytes, None] = {}
-
-    def push(self, key: bytes) -> bool:
-        m = self._map
-        if key in m:
-            del m[key]  # re-insert = MoveToBack (reference mapTxCache)
-            m[key] = None
-            return False
-        if len(m) >= self.size:
-            del m[next(iter(m))]
-        m[key] = None
-        return True
+    # push(key) -> bool: False if already present (recency refreshed)
+    push = _LRU._admit
 
     def remove(self, key: bytes) -> None:
         self._map.pop(key, None)
@@ -126,6 +122,30 @@ class UnlockedLRUCache:
 
     def __len__(self) -> int:
         return len(self._map)
+
+
+def _locked(op):
+    @functools.wraps(op)
+    def locked(self, *args):
+        with self._mtx:
+            return op(self, *args)
+
+    return locked
+
+
+class LRUCache(_LRU):
+    """UnlockedLRUCache's five operations, each under the set's own lock:
+    the lock is the only difference between the two classes."""
+
+    def __init__(self, size: int):
+        super().__init__(size)
+        self._mtx = threading.Lock()
+
+    push = _locked(UnlockedLRUCache.push)
+    remove = _locked(UnlockedLRUCache.remove)
+    reset = _locked(UnlockedLRUCache.reset)
+    __contains__ = _locked(UnlockedLRUCache.__contains__)
+    __len__ = _locked(UnlockedLRUCache.__len__)
 
 
 def make_lru(size: int):
@@ -148,6 +168,8 @@ def make_lru(size: int):
 class NopCache:
     """Cache disabled (config.cache_size = 0): everything is new."""
 
+    evictions = 0
+
     def push(self, key: bytes) -> bool:
         return True
 
@@ -164,15 +186,12 @@ class NopCache:
         return 0
 
 
-class LRUMap:
+class LRUMap(_LRU):
     """Fixed-size LRU key->value map (wire-segment dedup in the reactors)."""
 
     def __init__(self, size: int):
-        if size <= 0:
-            raise ValueError("cache size must be positive")
-        self.size = size
+        super().__init__(size)
         self._mtx = threading.Lock()
-        self._map: OrderedDict[bytes, object] = OrderedDict()
 
     def get(self, key: bytes):
         with self._mtx:
@@ -194,8 +213,5 @@ class LRUMap:
 
     def put(self, key: bytes, value) -> None:
         with self._mtx:
-            if key in self._map:
-                self._map.move_to_end(key)
-            elif len(self._map) >= self.size:
-                self._map.popitem(last=False)
+            self._admit(key)  # a racing peek may read None: a miss, as ever
             self._map[key] = value
