@@ -39,10 +39,12 @@ from ..pool.txvotepool import TxVotePool
 from ..store.tx_store import TxStore
 from ..trace.tracer import (
     NULL_TRACER,
+    SPAN_CARRY,
     SPAN_COLLECT,
     SPAN_COMMIT,
     SPAN_DEVICE,
     SPAN_DISPATCH,
+    SPAN_LATE_DROP,
     SPAN_LINGER_BULK,
     SPAN_LINGER_PRIO,
     SPAN_LOCK_WAIT,
@@ -137,6 +139,7 @@ class _StepPrep:
         "keys", "votes", "slots", "n_slots", "prior", "msgs", "sigs",
         "val_idx", "dropped", "drain_seq", "verifier", "t0", "step",
         "trace_txs", "dispatch_end", "device_sid", "lane",
+        "drop_t", "carry_t",
     )
 
     def __init__(self, drain_seq: int, t0: float, lane: str | None = None):
@@ -166,6 +169,11 @@ class _StepPrep:
         # merged legacy drain): routes requeues back to the lane's own
         # retry list so a priority repeat never queues behind bulk
         self.lane = lane
+        # clock reads around the late_drop / carry_prior work, taken
+        # under _mtx and recorded by _prep_batch once the lock is free
+        # (None = the drain dropped nothing / formed no batch)
+        self.drop_t: tuple[float, float] | None = None
+        self.carry_t: tuple[float, float] | None = None
 
 
 class _BatchCoalescer:
@@ -485,6 +493,18 @@ class TxFlow:
         self._step_seq = 0
         self._last_ready = 0.0
         self._idle_t0 = 0.0
+        # in-flight state carried from step to step (engine thread only):
+        # drained votes dropped in prep because their tx had committed
+        # (late) or their validator's vote was already in the open set
+        # (dup; also counted where routing finds the same after the
+        # verify), votes the device verified for a tx that routing then
+        # found committed, slots of a step whose vote set already held
+        # stake, and the most vote sets open at once
+        self._late_votes = 0
+        self._dup_votes = 0
+        self._late_verified = 0
+        self._carried_slots = 0
+        self._open_vote_sets = 0
         # host-prep split (profile_host.py prep_serial vs prep_pool_wait):
         # sign_s is the assembly stage's wall time, pool_wait_s the slice
         # of it this thread spent parked behind pool shards it didn't run
@@ -1226,6 +1246,10 @@ class TxFlow:
                 st.step = prep.step
                 self._end_pool_wait(st.t0)
                 self._stage_done(SPAN_LOCK_WAIT, st.t0, lk_acq, st.step)
+                if prep.drop_t is not None:
+                    self._stage_done(SPAN_LATE_DROP, *prep.drop_t, st.step)
+                if prep.carry_t is not None:
+                    self._stage_done(SPAN_CARRY, *prep.carry_t, st.step)
                 self._votes_waited(prep, lane, st.t0, st.step)
         return prep
 
@@ -1314,6 +1338,7 @@ class TxFlow:
             keys, votes, slots = prep.keys, prep.votes, prep.slots
             slot_of: dict[str, int] = {}
             drop_now: list[bytes] = []
+            n_late = 0
             self._sh_votesets.note_read()
             for bi, (key, vote) in enumerate(batch):
                 if self._committed.__contains__(_hash_key(vote.tx_hash)) or (
@@ -1321,6 +1346,7 @@ class TxFlow:
                     and self.tx_store.has_tx(vote.tx_hash)
                 ):
                     drop_now.append(key)  # late vote for a committed tx
+                    n_late += 1
                     continue
                 vs = self.vote_sets.get(vote.tx_hash)
                 if vs is not None and vs.get_by_address(vote.validator_address) is not None:
@@ -1349,7 +1375,11 @@ class TxFlow:
                 votes.append(vote)
                 slots.append(slot)
             if drop_now:
+                t_drop = monotonic()
                 self.tx_vote_pool.remove(drop_now)
+                prep.drop_t = (t_drop, monotonic())
+                self._late_votes += n_late
+                self._dup_votes += len(drop_now) - n_late
             prep.dropped = len(drop_now)
             if not votes:
                 return prep, lk_acq
@@ -1358,10 +1388,15 @@ class TxFlow:
 
             n_slots = len(slot_of)
             prior = np.zeros(n_slots, np.int64)
+            t_carry = monotonic()
+            carried = 0
             for tx_hash, s in slot_of.items():
                 vs = self.vote_sets.get(tx_hash)
                 if vs is not None:
                     prior[s] = vs.stake()
+                    carried += 1
+            prep.carry_t = (t_carry, monotonic())
+            self._carried_slots += carried
             prep.n_slots = n_slots
             prep.prior = prior
 
@@ -1551,6 +1586,7 @@ class TxFlow:
             # certificates are identical to the serial path, not padded
             # with same-batch late votes
             bad_keys: list[bytes] = []
+            late_verified = dup_verified = 0
             # the valid=False slice only (bad_keys also carries late/dup
             # removals, which are NOT peer misbehavior): resolved to
             # ingest origins for the accountability hook below
@@ -1608,7 +1644,10 @@ class TxFlow:
                 vs = self.vote_sets.get(vote.tx_hash)
                 if vs is None:
                     if self._committed.__contains__(_hash_key(vote.tx_hash)):
-                        bad_keys.append(keys[i])  # late: committed this batch
+                        # late: committed since this batch was prepped
+                        # (earlier in it, or by the step in flight then)
+                        bad_keys.append(keys[i])
+                        late_verified += 1
                         continue
                     vs = TxVoteSet(
                         self.chain_id, self.height, vote.tx_hash, vote.tx_key, self.val_set
@@ -1643,6 +1682,11 @@ class TxFlow:
                             inline_commits.append(self._decide_commit(vs, step))
                 else:
                     bad_keys.append(keys[i])  # dup/conflict: can never add
+                    dup_verified += 1
+            self._late_verified += late_verified
+            self._dup_votes += dup_verified
+            if len(self.vote_sets) > self._open_vote_sets:
+                self._open_vote_sets = len(self.vote_sets)
             invalid_origins = None
             if invalid_keys and self.on_invalid_votes is not None:
                 # resolve BEFORE the remove below wipes the entries —
@@ -1730,6 +1774,14 @@ class TxFlow:
             "dispatch_wait_s": round(self._pipe_wait_s, 4),
             "route_s": round(self._pipe_route_s, 4),
             "lock_wait_s": round(self._pipe_lock_wait_s, 4),
+            # what the in-flight state cost (see __init__): votes dropped
+            # in prep as late or dup, votes verified and then found late,
+            # slots that carried prior stake, most vote sets open at once
+            "late_votes": self._late_votes,
+            "dup_votes": self._dup_votes,
+            "late_verified": self._late_verified,
+            "carried_slots": self._carried_slots,
+            "open_vote_sets": self._open_vote_sets,
             # host-prep split: sign/assembly stage wall time, and the
             # slice of it spent parked on host-pool shards (report.py
             # prep_serial vs prep_pool_wait)

@@ -158,7 +158,12 @@ class DegradedModeRegistry:
         if pipe is not None:
             # verify-pipeline health: a collapsing overlap ratio with a
             # healthy device lane means the engine is host-bound, not
-            # device-bound — a different remediation than demotion
+            # device-bound — a different remediation than demotion.
+            # progress.pipeline.late_votes / dup_votes / late_verified
+            # count the votes that arrived for nothing (dropped in prep,
+            # or verified before routing found the tx committed: a rising
+            # late_verified is device work thrown away); carried_slots and
+            # open_vote_sets what stays open from step to step
             stats = pipe()
             progress["pipeline"] = stats
             if stats["overlap_ratio"] is not None:

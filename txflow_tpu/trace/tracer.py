@@ -77,6 +77,15 @@ SPAN_POOL_WAIT = "pool_wait"
 # It lies over those stages: no stretch of the engine thread's own time
 SPAN_PICKUP = "pickup_wait"
 SPAN_LOCK_WAIT = "lock_wait"
+# children of host_prep, for the state a step carries over from earlier
+# steps. late_drop: the pool removal of drained votes that can never be
+# added (their tx has committed, or their validator's vote is already in
+# the open set); the scan that finds them is the drain loop itself and
+# is not separable, and a drain that drops nothing records none.
+# carry_prior: the loop that reads each slot's stake out of the open vote
+# sets into the step's prior array, one a step
+SPAN_LATE_DROP = "late_drop"
+SPAN_CARRY = "carry_prior"
 # per-lane coalescer holds (ISSUE 12 verify lanes): the engine's bulk
 # lane records linger_bulk, the priority lane linger_prio — report.py
 # sums both into the critical-path linger bucket
@@ -120,17 +129,18 @@ SPAN_ORDER = (
     SPAN_RPC, SPAN_ADMISSION, SPAN_TX_INGEST, SPAN_GOSSIP_INGEST,
     SPAN_SIGN_WAIT, SPAN_SIGN, SPAN_VOTE_INGEST, SPAN_PRE_DROP, SPAN_VOTE_WAIT,
     SPAN_POOL_WAIT, SPAN_PICKUP, SPAN_LINGER_PRIO, SPAN_LINGER_BULK, SPAN_PREP,
-    SPAN_LOCK_WAIT, SPAN_DISPATCH, SPAN_DEVICE, SPAN_COLLECT, SPAN_ROUTE,
-    SPAN_ROUTE_TALLY, SPAN_QUORUM, SPAN_SPEC, SPAN_ROUTE_COMMIT,
-    SPAN_COMMIT, SPAN_ROUTE_PURGE, SPAN_PUBLISH, SPAN_GC,
+    SPAN_LOCK_WAIT, SPAN_LATE_DROP, SPAN_CARRY, SPAN_DISPATCH, SPAN_DEVICE,
+    SPAN_COLLECT, SPAN_ROUTE, SPAN_ROUTE_TALLY, SPAN_QUORUM, SPAN_SPEC,
+    SPAN_ROUTE_COMMIT, SPAN_COMMIT, SPAN_ROUTE_PURGE, SPAN_PUBLISH, SPAN_GC,
     SPAN_SYNC_FETCH, SPAN_SYNC_VERIFY, SPAN_SYNC_APPLY, SPAN_E2E,
 )
 
 # the step record: kept apart from the per-tx ring (see module docstring)
 STAGE_SPANS = frozenset((
     SPAN_POOL_WAIT, SPAN_PICKUP, SPAN_LINGER_PRIO, SPAN_LINGER_BULK, SPAN_PREP,
-    SPAN_LOCK_WAIT, SPAN_DISPATCH, SPAN_DEVICE, SPAN_COLLECT, SPAN_ROUTE,
-    SPAN_ROUTE_TALLY, SPAN_ROUTE_COMMIT, SPAN_ROUTE_PURGE, SPAN_GC,
+    SPAN_LOCK_WAIT, SPAN_LATE_DROP, SPAN_CARRY, SPAN_DISPATCH, SPAN_DEVICE,
+    SPAN_COLLECT, SPAN_ROUTE, SPAN_ROUTE_TALLY, SPAN_ROUTE_COMMIT,
+    SPAN_ROUTE_PURGE, SPAN_GC,
 ))
 
 
