@@ -35,6 +35,7 @@ from txflow_tpu.trace.tracer import (
 )
 from txflow_tpu.types.priv_validator import MockPV
 from txflow_tpu.types.validator import Validator, ValidatorSet
+from txflow_tpu.utils.collector import COLLECTOR
 from txflow_tpu.utils.config import TraceConfig, test_config as make_test_config
 from txflow_tpu.verifier import ScalarVoteVerifier, VerifyTicket
 
@@ -546,7 +547,9 @@ def test_null_tracer_no_spans_counters_advance_no_gc_hook():
     before = list(gc.callbacks)
     net.start()
     try:
-        assert gc.callbacks == before  # no hook for the NullTracer
+        # no hook for the NullTracer: the collector's policy alone
+        assert _hooks() == []
+        assert [cb for cb in gc.callbacks if cb not in before] == [COLLECTOR]
         _run(net, [b"null-%d=v" % i for i in range(8)])
         stats = node.txflow.pipeline_stats()
     finally:

@@ -65,6 +65,7 @@ from ..analysis.lockgraph import make_rlock
 from ..analysis.racegraph import shared_field
 from ..utils.cache import make_lru
 from ..utils.clock import monotonic
+from ..utils.collector import COLLECTOR
 from ..utils.config import EngineConfig
 from ..utils.failpoints import FailpointError
 from ..utils.metrics import TxFlowMetrics
@@ -1799,6 +1800,11 @@ class TxFlow:
             ),
             "mesh_devices": self._verifier_shards(),
         }
+        # the process's collector schedule (utils/collector.py; one for
+        # every node of the process): full collections since the first
+        # node started and their seconds, what the last one left, and
+        # the start-up heap kept out of them
+        stats.update(COLLECTOR.stats())
         co = self._coalescer
         stats["coalesce"] = {
             "enabled": co is not None,

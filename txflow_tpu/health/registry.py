@@ -163,7 +163,9 @@ class DegradedModeRegistry:
             # count the votes that arrived for nothing (dropped in prep,
             # or verified before routing found the tx committed: a rising
             # late_verified is device work thrown away); carried_slots and
-            # open_vote_sets what stays open from step to step
+            # open_vote_sets what stays open from step to step;
+            # full_collections / full_collect_s / survivors /
+            # frozen_objects are the process's collector schedule
             stats = pipe()
             progress["pipeline"] = stats
             if stats["overlap_ratio"] is not None:

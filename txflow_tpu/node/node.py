@@ -35,6 +35,7 @@ from ..store.tx_store import TxStore
 from ..types.genesis import GenesisDoc, GenesisValidator
 from ..types.priv_validator import PrivValidator
 from ..types.validator import ValidatorSet
+from ..utils.collector import COLLECTOR
 from ..utils.config import Config, EngineConfig
 from ..utils.events import EventBus
 from ..utils.metrics import Registry, TxFlowMetrics
@@ -648,6 +649,9 @@ class Node:
                 self.block_store, self.chain_state.last_block_height
             )
         self.switch.start()
+        # the start-up heap frozen out of the collector, full collections
+        # by what survives; before the hook, so its own collection is no span
+        COLLECTOR.install()
         self.tracer.install_gc_hook()  # gc_pause spans while the node runs
         self.txflow.start()
         if self.consensus is not None:
@@ -677,6 +681,7 @@ class Node:
             self.consensus.stop()
         self.txflow.stop()
         self.tracer.remove_gc_hook()
+        COLLECTOR.remove()  # the last node of the process restores gc as found
         self.switch.stop()
         self.mempool.close_wal()
         self.tx_vote_pool.close_wal()
