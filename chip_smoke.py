@@ -33,7 +33,8 @@ Phase B  the served path: ``LocalNet(4, use_device_verifier=True,
          clients under a config that holds each engine step a second
          (``coalescing_config``): held to the same guarantees and
          counters, and at least one of its batches must ride the
-         4,096 rung — the engine leg at the size bench.py dispatches.
+         4,096 rung — the engine leg at the size the flood cells of
+         perfbench/ dispatch.
 --chips 4  the phase-A batch through ``DeviceVoteVerifier(mesh=
          make_mesh(4))``, the single-device verifier and the scalar
          model, identical; each device held a quarter of the vote axis.
@@ -79,7 +80,7 @@ CHAIN_ID = "txflow-smoke"
 # (votes, slots) programs exist: (64, 64), (4096, 64), (4096, 4096). Each
 # costs about a minute and a half of compile cold, nearly flat in size — the default
 # six-rung ladder would spend the chip call compiling. 4,096 is the rung
-# bench.py's default bucket uses.
+# the benchmark's flood cells ride (perfbench/configs/*.json).
 BUCKETS = (64, 4096)
 N_VOTES = 4096  # phase A / mesh phase batch
 N_TXS = 4096  # phase B, paced on LocalNet's default config
@@ -313,7 +314,7 @@ def coalescing_config(n_txs: int, n_nodes: int) -> Config:
     HOLD_S for votes to coalesce into one batch (the linger, and the two
     waits that would flush a partial batch the moment gossip pauses), and
     with pools and dedup caches sized for every vote of n_txs in flight
-    at once, as bench.py sizes them for its corpus."""
+    at once."""
     cfg = test_config()
     cfg.mempool.size = max(cfg.mempool.size, 2 * n_txs * n_nodes)
     cfg.mempool.cache_size = max(cfg.mempool.cache_size, 2 * cfg.mempool.size)

@@ -34,7 +34,6 @@ from txflow_tpu.engine.txflow import _BatchCoalescer
 from txflow_tpu.verifier import (
     DeviceVoteVerifier,
     ScalarVoteVerifier,
-    VerifyCache,
 )
 
 
@@ -208,7 +207,7 @@ class FakeWarmGate:
         return self.warm
 
     def enumerate_shapes(self, n=1, full=True):
-        return [("verify", 8, 8)]
+        return [("fused", 8, 8)]
 
 
 @pytest.mark.parametrize("seed", [41, 97])
@@ -347,9 +346,9 @@ def test_registry_enumerates_every_coalescer_shape():
     size, retry-inflated sizes up to the cap), the shapes the verifier
     can dispatch are inside the prewarm enumeration."""
     vals, _seeds = make_valset(4)
-    # cached config (the engine/bench default): slot width is pinned to
-    # the floor bucket, so containment must hold for ANY n_slots
-    dev = DeviceVoteVerifier(vals, buckets=(64, 256), shared_cache=VerifyCache())
+    # the slot bucket tracks n_slots; warmup's contract covers the
+    # floor-slot and full-width combos the engine dispatches
+    dev = DeviceVoteVerifier(vals, buckets=(64, 256))
     reg = ShapeWarmRegistry(dev)
     universe = set(reg.enumerate_shapes(full=True))
     sizes = sorted(
@@ -357,23 +356,12 @@ def test_registry_enumerates_every_coalescer_shape():
         | {b for b in dev.buckets}
         | {b - 1 for b in dev.buckets}
         | {b + 1 for b in dev.buckets if b + 1 <= dev.max_batch}
-        | set(dev.miss_buckets)
     )
     for n in sizes:
         for n_slots in (1, max(1, n // 2), n):
             got = set(reg.shapes_for_batch(n, n_slots))
             assert got, f"no shapes predicted for n={n}"
             assert got <= universe, (n, n_slots, got - universe)
-
-    # fused config: slot bucket tracks n_slots; warmup's contract covers
-    # the single-slot and full-width combos the engine dispatches
-    dev_f = DeviceVoteVerifier(vals, buckets=(64, 256))
-    reg_f = ShapeWarmRegistry(dev_f)
-    universe_f = set(reg_f.enumerate_shapes(full=True))
-    for n in (1, 63, 64, 65, 256):
-        for n_slots in (1, n):
-            got = set(reg_f.shapes_for_batch(n, n_slots))
-            assert got <= universe_f, (n, n_slots, got - universe_f)
 
     # scalar verifier: no compiled shapes, every batch warm by definition
     reg_s = ShapeWarmRegistry(ScalarVoteVerifier(vals))
@@ -386,7 +374,7 @@ def test_background_warmer_promotes_registry():
     registry flips from cold to warm without prewarm, and nothing the
     warmer compiled reads as an in-run compile."""
     vals, _seeds = make_valset(4)
-    dev = DeviceVoteVerifier(vals, buckets=(64,), shared_cache=VerifyCache())
+    dev = DeviceVoteVerifier(vals, buckets=(64,))
     reg = ShapeWarmRegistry(dev)
     assert not reg.is_batch_warm(5)
     warmer = BackgroundWarmer(reg, full=True)
@@ -404,87 +392,6 @@ def test_background_warmer_promotes_registry():
     w2 = BackgroundWarmer(ShapeWarmRegistry(ScalarVoteVerifier(vals)))
     w2.start()
     assert w2._thread is None and not w2.done()
-
-
-# ---- claim staleness across a slow dispatch (ADVICE r5) ---------------
-
-
-def test_dispatch_heartbeats_claims_across_slow_compile():
-    """_dispatch_verify_only must re-stamp the caller's VerifyCache
-    claims on BOTH sides of the self._fn call: a cold-shape compile in
-    there can exceed claim_ttl by orders of magnitude, and a stale claim
-    hands the same votes (and the same compile) to every other engine."""
-    vals, seeds = make_valset(4)
-    cache = VerifyCache(claim_ttl=0.2)
-    dev = DeviceVoteVerifier(vals, shared_cache=cache)
-    msgs, sigs, vidx, _slot = make_batch(vals, seeds, n_txs=2)
-    keys = [
-        VerifyCache.key(msgs[i], sigs[i], dev._pub_keys[int(vidx[i])])
-        for i in range(len(msgs))
-    ]
-    _, pend = cache.lookup_or_claim_many(keys)
-    assert not pend.any()  # this "engine" owns every claim
-    aged = time.monotonic() - 100 * cache.claim_ttl
-
-    def age_claims():
-        with cache._mtx:
-            for k in keys:
-                cache._inflight[k] = aged
-
-    age_claims()  # simulate the stamps going stale before dispatch
-    orig_fn = dev._fn
-    seen = {}
-
-    def slow_fn(*args):
-        # another engine probing MID-DISPATCH: the pre-dispatch heartbeat
-        # must have re-stamped, so the probe defers instead of stealing
-        # the claims (and launching its own compile of the same shape)
-        _, mid = cache.lookup_or_claim_many(keys)
-        seen["mid_owned"] = bool(mid.all())
-        out = orig_fn(*args)
-        # stale again while the dispatch finishes: only the POST-dispatch
-        # heartbeat can keep ownership into the readback window
-        age_claims()
-        return out
-
-    dev._fn = slow_fn
-    try:
-        dev._dispatch_verify_only(msgs, sigs, vidx, claim_keys=keys)
-    finally:
-        dev._fn = orig_fn
-    assert seen["mid_owned"], "claims went stale during the dispatch"
-    _, after = cache.lookup_or_claim_many(keys)
-    assert after.all(), "claims went stale between dispatch and readback"
-    cache.release_many(keys)
-
-
-def test_claim_keepalive_first_beat_is_immediate():
-    """claim_keepalive's first heartbeat fires at thread start, not one
-    interval in: with a short TTL the claims may be near-stale by the
-    time the thread is scheduled."""
-    cache = VerifyCache(claim_ttl=0.5)
-    keys = [b"k%d" % i for i in range(3)]
-    cache.lookup_or_claim_many(keys)
-    aged = time.monotonic() - 100 * cache.claim_ttl
-    with cache._mtx:
-        for k in keys:
-            cache._inflight[k] = aged
-    with cache.claim_keepalive(keys):
-        # well inside the first ttl/2 interval: the immediate beat must
-        # already have re-stamped the aged claims
-        deadline = time.monotonic() + 1.0
-        while time.monotonic() < deadline:
-            with cache._mtx:
-                fresh = all(
-                    cache._inflight[k] > aged for k in keys
-                )
-            if fresh:
-                break
-            time.sleep(0.005)
-        assert fresh, "first keepalive beat did not fire immediately"
-        _, pend = cache.lookup_or_claim_many(keys)
-        assert pend.all()
-    cache.release_many(keys)
 
 
 # ---- LocalNet guard (satellite: partial hosting + consensus) ----------

@@ -14,7 +14,6 @@ from txflow_tpu.store import MemDB, TxStore
 from txflow_tpu.types import MockPV, TxVote, Validator, ValidatorSet
 from txflow_tpu.utils.config import EngineConfig, MempoolConfig
 from txflow_tpu.utils.events import EventBus, EventTx
-from txflow_tpu.verifier import ScalarVoteVerifier
 
 CHAIN_ID = "txflow-test"
 HEIGHT = 1
@@ -319,60 +318,6 @@ def test_quorum_before_tx_defers_apply_until_bytes_arrive():
         assert tx_hash not in net.nodes[0].txflow._unapplied
     finally:
         net.stop()
-
-
-def test_two_engines_shared_cache_both_commit():
-    """Two co-located engines sharing one VerifyCache (the bench/LocalNet
-    deployment shape): claim semantics mean an engine meeting the other's
-    in-flight verifies DEFERS those votes and re-offers them next step —
-    both engines must still commit every tx, each verifying only a share
-    of the unique votes (process-wide verify count < 2x the vote count)."""
-    import threading
-    import time as _time
-
-    from txflow_tpu.verifier import ScalarVoteVerifier, VerifyCache
-
-    pvs, vals = make_pvs(4)
-    cache = VerifyCache()
-    engines = []
-    for _ in range(2):
-        ver = ScalarVoteVerifier(vals, shared_cache=cache)
-        flow, mempool, commitpool, votepool, tx_store, app, bus = make_engine(
-            vals, use_device=False, verifier=ver
-        )
-        engines.append((flow, mempool, votepool, app))
-
-    txs = [b"sc%d=v" % i for i in range(40)]
-    votes = [sign_vote(pv, tx) for tx in txs for pv in pvs[:3]]
-    for flow, mempool, votepool, app in engines:
-        for tx in txs:
-            mempool.check_tx(tx)
-
-    # start both engines, then feed votes so the step loops race on the
-    # same misses (the deterministic single-step path can't interleave)
-    for flow, *_ in engines:
-        flow.start()
-    try:
-        for v in votes:
-            for _, _, votepool, _ in engines:
-                votepool.check_tx(v)
-        deadline = _time.monotonic() + 20
-        while _time.monotonic() < deadline:
-            if all(app.tx_count == len(txs) for *_, app in engines):
-                break
-            _time.sleep(0.01)
-        for flow, _, votepool, app in engines:
-            assert app.tx_count == len(txs), (
-                f"engine committed {app.tx_count}/{len(txs)}"
-            )
-    finally:
-        for flow, *_ in engines:
-            flow.stop()
-    # sharing must have deduped verify work: misses == claimed verifies,
-    # and claims guarantee each unique vote is verified at most once
-    # process-wide (absent TTL expiry, which this run is too short for)
-    assert cache.misses <= len(votes)
-    assert cache.hits > 0
 
 
 def test_block_claim_before_committer_wake_credits_apply_once():

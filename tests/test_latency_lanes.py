@@ -17,9 +17,8 @@ committed:
 3. unit coverage for the new moving parts: the expired-deadline
    wait_budget fix, priority-lane bucket targets, the
    AdaptiveLingerController steering loop and its engine wiring, the
-   per-lane pool pending estimates, critical-path lane/spec
-   attribution, the latency-bank supersede contract, and the
-   lane-linger latency model in tools/sim_device.py.
+   per-lane pool pending estimates and critical-path lane/spec
+   attribution.
 """
 
 import hashlib
@@ -495,86 +494,3 @@ def test_critical_path_lane_and_spec_attribution():
     assert cp_bulk["linger_s"] == pytest.approx(1.5)
     assert cp_bulk["linger_bulk_s"] == pytest.approx(1.5)
     assert "spec_saved_s" not in cp_bulk
-
-
-# ---- unit: latency-bank supersede contract ----------------------------
-
-
-def test_latency_bank_supersede_contract(tmp_path, monkeypatch):
-    import bench
-
-    monkeypatch.setattr(bench, "_ARTIFACT_DIR", str(tmp_path))
-    monkeypatch.setattr(
-        bench, "_LATENCY_LATEST", str(tmp_path / "latency_latest.json")
-    )
-    clean = {
-        "priority_p50_ms": 12.0,
-        "priority_p99_ms": 30.0,
-        "slo_breach": False,
-    }
-    dirty_breach = dict(clean, slo_breach=True)
-    dirty_error = {"error": "timeout", "priority_p50_ms": 1.0,
-                   "priority_p99_ms": 2.0}
-    missing_lane = {"priority_p50_ms": 5.0}  # p99 absent
-    assert bench._latency_clean(clean)
-    assert not bench._latency_clean(dirty_breach)
-    assert not bench._latency_clean(dirty_error)
-    assert not bench._latency_clean(missing_lane)
-
-    # a dirty run banks when nothing is banked yet (some data > none)
-    bench._bank_latency_result(dirty_error)
-    assert bench._load_banked_latency()["error"] == "timeout"
-    # clean overwrites dirty, and is stamped
-    bench._bank_latency_result(clean)
-    banked = bench._load_banked_latency()
-    assert banked["priority_p50_ms"] == 12.0
-    assert "measured_at_unix" in banked
-    # dirty never displaces clean — a regression cannot silently
-    # replace the reference it regressed from
-    bench._bank_latency_result(dirty_breach)
-    assert bench._load_banked_latency()["priority_p50_ms"] == 12.0
-    bench._bank_latency_result(dirty_error)
-    assert bench._load_banked_latency()["priority_p50_ms"] == 12.0
-    # a newer clean run supersedes the older clean one
-    bench._bank_latency_result(dict(clean, priority_p50_ms=8.0))
-    assert bench._load_banked_latency()["priority_p50_ms"] == 8.0
-
-
-# ---- unit: lane-linger latency model (tools/sim_device.py) ------------
-
-
-def _sim_device():
-    import importlib.util
-    import pathlib
-
-    root = pathlib.Path(__file__).resolve().parent.parent
-    spec = importlib.util.spec_from_file_location(
-        "sim_device_for_tests", root / "tools" / "sim_device.py"
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_lane_latency_model_monotonic_and_capped():
-    m = _sim_device().lane_latency_model
-    lingers = (0.00025, 0.001, 0.004, 0.016)
-    rows = [m(800.0, l, 0.008, 27.6e-6, bucket_cap=512) for l in lingers]
-    p50s = [r["p50_ms"] for r in rows]
-    batches = [r["batch"] for r in rows]
-    # at fixed arrival, a longer hold only adds latency (p50 strictly
-    # rises) while buying batch occupancy (batch non-decreasing, capped)
-    assert p50s == sorted(p50s) and p50s[0] < p50s[-1]
-    assert batches == sorted(batches)
-    assert all(r["batch"] <= 512 for r in rows)
-    assert all(r["p99_ms"] >= r["p50_ms"] for r in rows)
-    # saturation: once linger exceeds cap/arrival the hold stops growing
-    sat_a = m(800.0, 10.0, 0.008, 27.6e-6, bucket_cap=512)
-    sat_b = m(800.0, 20.0, 0.008, 27.6e-6, bucket_cap=512)
-    sat_a.pop("linger_ms"), sat_b.pop("linger_ms")
-    assert sat_a == sat_b
-    # a mesh divides the per-slot bill: same linger, lower p50
-    assert (
-        m(800.0, 0.004, 0.008, 27.6e-6, mesh=4)["p50_ms"]
-        < m(800.0, 0.004, 0.008, 27.6e-6, mesh=1)["p50_ms"]
-    )

@@ -5,16 +5,15 @@ verify, and commit routing:
 
 - randomized parity: the threaded pipelined engine (pipeline_depth >= 2)
   produces BYTE-identical commit certificates and commit order to the
-  scalar ``try_add_vote`` golden path, shared VerifyCache on or off;
-- drain-on-stop: ``stop()`` collects every in-flight ticket — no leaked
-  cache claims, no lost votes, pipeline-depth gauge back to 0;
+  scalar ``try_add_vote`` golden path;
+- drain-on-stop: ``stop()`` collects every in-flight ticket — no lost
+  votes, pipeline-depth gauge back to 0;
 - step accounting: ``step()`` returns decided + dropped, and
   ``last_step_stats`` reconciles decided + requeued == verified batch;
 - ShapeWarmRegistry: prewarm covers every shape a run dispatches
   (compile_in_run() False), cold dispatches are detected;
-- async submit surfaces: VerifierMux ticket path and the
-  ResilientVoteVerifier collect-time fallback (FlakyVerifier
-  fail_at="result").
+- async submit surface: the ResilientVoteVerifier collect-time
+  fallback (FlakyVerifier fail_at="result").
 """
 
 import hashlib
@@ -36,8 +35,6 @@ from txflow_tpu.utils.events import EventBus
 from txflow_tpu.verifier import (
     ResilientVoteVerifier,
     ScalarVoteVerifier,
-    VerifierMux,
-    VerifyCache,
 )
 
 CHAIN_ID = "txflow-test"
@@ -124,8 +121,8 @@ def _wait_quiescent(flow, votepool, timeout=30.0):
     return False
 
 
-@pytest.mark.parametrize("seed,shared_cache", [(11, False), (23, True)])
-def test_pipelined_matches_scalar_golden_path(seed, shared_cache):
+@pytest.mark.parametrize("seed", [11, 23])
+def test_pipelined_matches_scalar_golden_path(seed):
     """Commit certificates from the threaded pipelined engine are
     BYTE-identical (same signatures, same order) to the scalar
     ``try_add_vote`` reference, for a shuffled honest/byzantine stream."""
@@ -142,13 +139,9 @@ def test_pipelined_matches_scalar_golden_path(seed, shared_cache):
 
     # pipelined engine: same stream via the pool, threaded run loop with
     # tickets in flight; small batches force many overlapping steps
-    verifier = None
-    if shared_cache:
-        verifier = ScalarVoteVerifier(vals, shared_cache=VerifyCache())
     flow_p, mem_p, pool_p, store_p, app_p = make_engine(
         vals,
         use_device=False,
-        verifier=verifier,
         max_batch=17,
         min_batch=1,
         pipeline_depth=3,
@@ -187,15 +180,13 @@ def test_pipelined_matches_scalar_golden_path(seed, shared_cache):
 
 
 def test_stop_drains_inflight_tickets():
-    """stop() must collect and route every in-flight ticket: the cache
-    holds no stranded claims, the depth gauge reads 0, and every injected
-    vote is either decided or still in the pool (none lost)."""
+    """stop() must collect and route every in-flight ticket: the depth
+    gauge reads 0, and every injected vote is either decided or still in
+    the pool (none lost)."""
     pvs, vals = make_pvs(4)
-    cache = VerifyCache()
     flow, mempool, votepool, store, app = make_engine(
         vals,
         use_device=False,
-        verifier=ScalarVoteVerifier(vals, shared_cache=cache),
         max_batch=8,
         min_batch=1,
         pipeline_depth=4,
@@ -214,7 +205,6 @@ def test_stop_drains_inflight_tickets():
         flow.stop()
 
     assert flow.metrics.pipeline_depth.value() == 0, "orphaned tickets"
-    assert not cache._inflight, "leaked cache claims after stop"
     # no vote lost: whatever was not decided is still in the pool or the
     # retry set, so serial steps can finish the job deterministically
     while flow.step():
@@ -223,7 +213,6 @@ def test_stop_drains_inflight_tickets():
     for tx in txs:
         cert = store.load_tx_commit(hashlib.sha256(tx).hexdigest().upper())
         assert cert is not None and len(cert.commits) == 3
-    assert not cache._inflight
 
 
 def test_step_accounting_reconciles():
@@ -270,7 +259,7 @@ def test_shape_warm_registry_covers_run():
     from txflow_tpu.verifier import DeviceVoteVerifier
 
     pvs, vals = make_pvs(4)
-    ver = DeviceVoteVerifier(vals, buckets=(8,), shared_cache=False)
+    ver = DeviceVoteVerifier(vals, buckets=(8,))
     reg = ShapeWarmRegistry(ver)
     warm = reg.prewarm(full=True)
     assert warm, "prewarm recorded no shapes"
@@ -301,7 +290,7 @@ def test_shape_warm_registry_covers_run():
     assert reg.compile_in_run() is False
 
     # an unwarmed registry flags the same dispatch as an in-run compile
-    ver2 = DeviceVoteVerifier(vals, buckets=(8,), shared_cache=False)
+    ver2 = DeviceVoteVerifier(vals, buckets=(8,))
     reg2 = ShapeWarmRegistry(ver2)  # no prewarm
     ver2.verify_and_tally(msgs, sigs, np.array(vidx), np.array(slot), 2)
     assert reg2.compile_in_run() is True
@@ -356,31 +345,6 @@ def _assert_same(result, golden):
     np.testing.assert_array_equal(result.valid, golden.valid)
     np.testing.assert_array_equal(result.stake, golden.stake)
     np.testing.assert_array_equal(result.maj23, golden.maj23)
-
-
-def test_mux_submit_returns_tickets():
-    """VerifierMux.submit: the caller gets a ticket immediately and can
-    dispatch the next batch before collecting — results identical to the
-    blocking path, in submission order, and stop() leaves nothing hung."""
-    pvs, vals = make_pvs(4)
-    golden_ver = ScalarVoteVerifier(vals)
-    mux = VerifierMux(ScalarVoteVerifier(vals), gather_wait=0.002, pipeline_depth=2)
-
-    # not started: passthrough still returns a working ticket
-    batch_a = _rig_batch(pvs, vals, n_txs=2)
-    t = mux.submit(*batch_a)
-    _assert_same(t.result(), golden_ver.verify_and_tally(*batch_a))
-
-    mux.start()
-    try:
-        t1 = mux.submit(*batch_a)
-        batch_b = _rig_batch(pvs, vals, n_txs=3)
-        t2 = mux.submit(*batch_b)  # dispatched before t1 is collected
-        _assert_same(t1.result(), golden_ver.verify_and_tally(*batch_a))
-        _assert_same(t2.result(), golden_ver.verify_and_tally(*batch_b))
-        _assert_same(t2.result(), golden_ver.verify_and_tally(*batch_b))  # memoized
-    finally:
-        mux.stop()
 
 
 def test_resilient_collect_failure_falls_back():

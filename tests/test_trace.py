@@ -279,27 +279,6 @@ def test_critical_path_attribution():
     }
 
 
-# -- bench helpers --
-
-
-def test_bench_lane_quantiles_and_slo_gate():
-    from bench import lane_quantiles, slo_breached
-
-    q = lane_quantiles([float(i) for i in range(1, 101)])
-    assert q["count"] == 100
-    assert q["p50_ms"] == 51.0 and q["p99_ms"] == 100.0  # nearest-rank
-    assert lane_quantiles([]) == {
-        "count": 0, "p50_ms": None, "p99_ms": None, "p999_ms": None,
-    }
-    ok = {"lanes": {"priority": {"p99_ms": 80.0}}}
-    assert not slo_breached(ok, None)  # no budget => no gate
-    assert not slo_breached(ok, 100.0)
-    assert slo_breached(ok, 50.0)
-    # the gate must not pass on absent data
-    assert slo_breached({}, 100.0)
-    assert slo_breached({"lanes": {"priority": {"p99_ms": None}}}, 100.0)
-
-
 # -- end-to-end: LocalNet span parity + export --
 
 

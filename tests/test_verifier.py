@@ -18,6 +18,7 @@ from txflow_tpu.verifier import (
     DeviceVoteVerifier,
     ScalarVoteVerifier,
     bucket_size,
+    first_occurrence_mask,
 )
 
 CHAIN_ID = "txflow-test"
@@ -62,9 +63,9 @@ def valset4():
     return make_valset(4)
 
 
-def assert_parity(vals, msgs, sigs, vidx, slot, n_slots, prior=None):
+def assert_parity(vals, msgs, sigs, vidx, slot, n_slots, prior=None, device=None):
     scalar = ScalarVoteVerifier(vals)
-    device = DeviceVoteVerifier(vals)
+    device = device or DeviceVoteVerifier(vals)
     r_s = scalar.verify_and_tally(msgs, sigs, vidx, slot, n_slots, prior)
     r_d = device.verify_and_tally(msgs, sigs, vidx, slot, n_slots, prior)
     np.testing.assert_array_equal(r_s.valid, r_d.valid)
@@ -150,90 +151,6 @@ def test_bucket_size():
     assert bucket_size(70001, multiple=8) == 70008
 
 
-def test_verifier_mux_matches_direct_calls():
-    """Concurrent verify calls through the mux must return bit-identical
-    results to direct per-caller calls (votes merged, slot ranges shifted,
-    results split)."""
-    import threading
-
-    from txflow_tpu.verifier import VerifierMux
-
-    vals, seeds = make_valset(4)
-    direct = ScalarVoteVerifier(vals)
-    mux = VerifierMux(ScalarVoteVerifier(vals), gather_wait=0.05)
-    mux.start()
-    try:
-        reqs = []
-        for t in range(3):  # three "engines" with different batch shapes
-            msgs, sigs, vidx, slot = make_batch(
-                vals, seeds, n_txs=2 + t, corrupt=("ok", "flip") if t == 1 else ()
-            )
-            reqs.append((msgs, sigs, vidx, slot, 2 + t))
-        want = [
-            direct.verify_and_tally(m, s, v, sl, ns) for m, s, v, sl, ns in reqs
-        ]
-        got = [None] * len(reqs)
-        errs = []
-
-        def call(i):
-            m, s, v, sl, ns = reqs[i]
-            try:
-                got[i] = mux.verify_and_tally(m, s, v, sl, ns)
-            except Exception as e:  # pragma: no cover
-                errs.append(e)
-
-        threads = [threading.Thread(target=call, args=(i,)) for i in range(len(reqs))]
-        for th in threads:
-            th.start()
-        for th in threads:
-            th.join(timeout=30)
-        assert not errs, errs
-        for w, g in zip(want, got):
-            np.testing.assert_array_equal(w.valid, g.valid)
-            np.testing.assert_array_equal(w.stake, g.stake)
-            np.testing.assert_array_equal(w.maj23, g.maj23)
-            np.testing.assert_array_equal(w.dropped, g.dropped)
-
-        # quorum overrides are not mergeable
-        m, s, v, sl, ns = reqs[0]
-        with pytest.raises(ValueError):
-            mux.verify_and_tally(m, s, v, sl, ns, quorum=1)
-    finally:
-        mux.stop()
-
-
-def test_verifier_mux_prior_stake_isolated():
-    """Each caller's prior_stake must only affect its own slots."""
-    from txflow_tpu.verifier import VerifierMux
-
-    vals, seeds = make_valset(4)
-    mux = VerifierMux(ScalarVoteVerifier(vals), gather_wait=0.05)
-    mux.start()
-    try:
-        import threading
-
-        msgs, sigs, vidx, slot = make_batch(vals, seeds, n_txs=2)
-        # caller A: one vote shy of quorum already (prior 20 of 30 needed);
-        # caller B: zero prior — same votes, different quorum outcomes
-        prior_a = np.array([20, 0], np.int64)
-        out = {}
-
-        def call(name, prior):
-            out[name] = mux.verify_and_tally(
-                msgs[:4], sigs[:4], vidx[:4], slot[:4], 2, prior_stake=prior
-            )
-
-        ta = threading.Thread(target=call, args=("a", prior_a))
-        tb = threading.Thread(target=call, args=("b", None))
-        ta.start(); tb.start(); ta.join(30); tb.join(30)
-        # first 4 votes are tx0's full validator quorum (4 x power 10)
-        assert out["a"].stake[0] == 20 + 40 and bool(out["a"].maj23[0])
-        assert out["b"].stake[0] == 40 and bool(out["b"].maj23[0])
-        assert out["a"].stake[1] == 0 and out["b"].stake[1] == 0
-    finally:
-        mux.stop()
-
-
 @pytest.mark.slow  # two 8-way mesh compiles: ~60s on the 1-core CPU CI box
 def test_ring_tally_matches_psum_step():
     """The explicit ppermute ring all-reduce must produce bit-identical
@@ -277,193 +194,6 @@ def test_ring_tally_matches_psum_step():
         _np.testing.assert_array_equal(maj[sh], _np.asarray(a[2]))
 
 
-def test_verifier_mux_error_propagates_to_all_waiters():
-    """An inner-verifier failure must surface to every merged caller and
-    leave the mux serviceable for the next call."""
-    import threading
-
-    from txflow_tpu.verifier import VerifierMux
-
-    vals, seeds = make_valset(4)
-
-    class Flaky:
-        def __init__(self, inner):
-            self.inner = inner
-            self.val_set = inner.val_set
-            self.fail = True
-
-        def verify_and_tally(self, *a, **k):
-            if self.fail:
-                raise RuntimeError("device fell over")
-            return self.inner.verify_and_tally(*a, **k)
-
-    flaky = Flaky(ScalarVoteVerifier(vals))
-    mux = VerifierMux(flaky, gather_wait=0.05)
-    mux.start()
-    try:
-        msgs, sigs, vidx, slot = make_batch(vals, seeds, n_txs=2)
-        errs, oks = [], []
-
-        def call():
-            try:
-                oks.append(mux.verify_and_tally(msgs, sigs, vidx, slot, 2))
-            except RuntimeError as e:
-                errs.append(e)
-
-        ts = [threading.Thread(target=call) for _ in range(3)]
-        for t in ts:
-            t.start()
-        for t in ts:
-            t.join(timeout=30)
-        assert len(errs) == 3 and not oks
-
-        flaky.fail = False  # mux must still serve after the failure
-        r = mux.verify_and_tally(msgs, sigs, vidx, slot, 2)
-        assert r.valid.all()
-    finally:
-        mux.stop()
-
-
-def test_verifier_mux_stop_strands_no_callers():
-    """stop() must release every in-flight caller: queued requests (even
-    ones enqueued concurrently with shutdown) either get served inline on
-    the inner verifier or fail with RuntimeError — no thread may block in
-    done.wait() forever (r3 advisor low)."""
-    import threading
-    import time
-
-    from txflow_tpu.verifier import VerifierMux
-
-    vals, seeds = make_valset(4)
-    mux = VerifierMux(ScalarVoteVerifier(vals), gather_wait=0.05)
-    mux.start()
-    results = []
-
-    def caller():
-        msgs, sigs, vidx, slot = make_batch(vals, seeds, n_txs=1)
-        try:
-            r = mux.verify_and_tally(msgs, sigs, vidx, slot, 1)
-            results.append(("ok", bool(r.valid.all())))
-        except RuntimeError as e:
-            results.append(("stopped", str(e)))
-
-    threads = [threading.Thread(target=caller) for _ in range(8)]
-    for t in threads:
-        t.start()
-    time.sleep(0.01)
-    mux.stop()
-    for t in threads:
-        t.join(timeout=10)
-        assert not t.is_alive(), "caller stranded in done.wait() after stop()"
-    assert len(results) == 8
-    # served results must be correct; failures must be the shutdown error
-    for kind, val in results:
-        assert (kind == "ok" and val is True) or kind == "stopped", results
-
-
-def test_verify_cache_parity_and_sharing():
-    """Cached verifiers must make bit-identical decisions to the plain
-    scalar golden model, while co-located engines sharing one cache skip
-    re-verifying votes the first engine already resolved (r4: the 4-node
-    bench ran 4x redundant kernel work without this)."""
-    from txflow_tpu.verifier import VerifyCache
-
-    vals, seeds = make_valset(4)
-    golden = ScalarVoteVerifier(vals)
-    cache = VerifyCache()
-    eng_a = ScalarVoteVerifier(vals, shared_cache=cache)
-    eng_b = ScalarVoteVerifier(vals, shared_cache=cache)
-
-    msgs, sigs, vidx, slot = make_batch(
-        vals, seeds, n_txs=6,
-        corrupt=("ok", "flip", "ok", "wrongkey", "badidx", "ok"),
-    )
-    n_slots = 6
-    want = golden.verify_and_tally(msgs, sigs, vidx, slot, n_slots)
-    got_a = eng_a.verify_and_tally(msgs, sigs, vidx, slot, n_slots)
-    np.testing.assert_array_equal(want.valid, got_a.valid)
-    np.testing.assert_array_equal(want.stake, got_a.stake)
-    np.testing.assert_array_equal(want.maj23, got_a.maj23)
-    np.testing.assert_array_equal(want.dropped, got_a.dropped)
-
-    # second engine, same gossip: all cacheable rows must hit
-    before_misses = cache.misses
-    got_b = eng_b.verify_and_tally(msgs, sigs, vidx, slot, n_slots)
-    np.testing.assert_array_equal(want.valid, got_b.valid)
-    np.testing.assert_array_equal(want.maj23, got_b.maj23)
-    assert cache.misses == before_misses, "engine B re-verified cached votes"
-    assert cache.hits > 0
-
-    # key binds the message: replaying a cached-valid signature on a
-    # DIFFERENT payload must NOT alias to the cached verdict
-    forged_msgs = [m + b"X" for m in msgs]
-    got_forged = eng_b.verify_and_tally(forged_msgs, sigs, vidx, slot, n_slots)
-    assert not got_forged.valid.any()
-
-
-def test_device_verifier_cached_parity(device_verifier_factory=None):
-    """Device verifier with the cache on: decisions identical to both the
-    plain device kernel and the scalar golden model; second call all-hits."""
-    vals, seeds = make_valset(4)
-    golden = ScalarVoteVerifier(vals)
-    dev = DeviceVoteVerifier(vals, shared_cache=True)
-    msgs, sigs, vidx, slot = make_batch(
-        vals, seeds, n_txs=5, corrupt=("ok", "flip", "ok", "wrongkey")
-    )
-    n_slots = 5
-    want = golden.verify_and_tally(msgs, sigs, vidx, slot, n_slots)
-    got = dev.verify_and_tally(msgs, sigs, vidx, slot, n_slots)
-    np.testing.assert_array_equal(want.valid, got.valid)
-    np.testing.assert_array_equal(want.stake, got.stake)
-    np.testing.assert_array_equal(want.maj23, got.maj23)
-    np.testing.assert_array_equal(want.dropped, got.dropped)
-    before = dev.cache.misses
-    got2 = dev.verify_and_tally(msgs, sigs, vidx, slot, n_slots)
-    np.testing.assert_array_equal(want.valid, got2.valid)
-    assert dev.cache.misses == before
-
-    # prior stake must latch through the cached host tally as well
-    prior = np.array([vals.quorum_power() - 10] + [0] * (n_slots - 1), np.int64)
-    got3 = dev.verify_and_tally(msgs, sigs, vidx, slot, n_slots, prior_stake=prior)
-    want3 = golden.verify_and_tally(msgs, sigs, vidx, slot, n_slots, prior_stake=prior)
-    np.testing.assert_array_equal(want3.stake, got3.stake)
-    np.testing.assert_array_equal(want3.maj23, got3.maj23)
-
-
-def test_verify_cache_binds_pubkey_not_index():
-    """A shared cache outliving a validator-set change must never replay a
-    'valid' verdict for a signature that was checked against a DIFFERENT
-    key now living at the same index (r4 advisor: keys previously bound
-    the index). Two sets are built so a seed-A validator sits at index 0
-    in set A and a different key sits at index 0 in set B."""
-    from txflow_tpu.verifier import VerifyCache
-
-    seed_a = hashlib.sha256(b"epoch-a-val").digest()
-    seed_b = hashlib.sha256(b"epoch-b-val").digest()
-    pub_a = host_ed.public_key_from_seed(seed_a)
-    pub_b = host_ed.public_key_from_seed(seed_b)
-    set_a = ValidatorSet([Validator.from_pub_key(pub_a, 10)])
-    set_b = ValidatorSet([Validator.from_pub_key(pub_b, 10)])
-
-    msg = canonical_sign_bytes(CHAIN_ID, 1, "AA" * 32, 1700000000_000000000)
-    sig = host_ed.sign(seed_a, msg)  # valid under pub_a only
-
-    cache = VerifyCache()
-    v_a = ScalarVoteVerifier(set_a, shared_cache=cache)
-    v_b = ScalarVoteVerifier(set_b, shared_cache=cache)
-
-    r_a = v_a.verify_and_tally([msg], [sig], np.array([0]), np.array([0]), 1)
-    assert r_a.valid[0]  # genuinely valid under set A, now cached
-    r_b = v_b.verify_and_tally([msg], [sig], np.array([0]), np.array([0]), 1)
-    assert not r_b.valid[0]  # same index, different key: MUST miss + fail
-
-    # and the key is split-unambiguous: shifting a boundary byte between
-    # msg and sig yields a different cache key
-    k1 = VerifyCache.key(msg, sig, pub_a)
-    k2 = VerifyCache.key(msg + sig[:1], sig[1:], pub_a)
-    assert k1 != k2
-
-
 @pytest.mark.parametrize("nv", [16, 64])
 def test_large_validator_set_parity(nv):
     """Device/scalar parity at BASELINE configs 2-3 validator counts (the
@@ -476,210 +206,80 @@ def test_large_validator_set_parity(nv):
     assert_parity(vals, msgs, sigs, vidx, slot, 3)
 
 
-def test_verify_cache_claims_dedupe_inflight():
-    """Claim semantics (r5: co-located engines racing on the same misses
-    each paid a full padded device call — 580 votes/s on TPU vs 12k
-    uncached): the first asker owns a miss; concurrent askers are told
-    it is pending and must defer; store resolves it for everyone;
-    release hands an abandoned claim to the next asker."""
-    from txflow_tpu.verifier import VerifyCache
+# ---- the one device path: first occurrences, rung edges, predicted shapes ----
 
-    cache = VerifyCache()
-    k = VerifyCache.key(b"m", b"s" * 64, b"p" * 32)
-    vals, pending = cache.lookup_or_claim_many([k])
-    assert vals == [None] and not pending[0]  # this caller owns the claim
-    vals2, pending2 = cache.lookup_or_claim_many([k])
-    assert vals2 == [None] and pending2[0]  # concurrent asker defers
-    cache.store_many([(k, True)])
-    vals3, pending3 = cache.lookup_or_claim_many([k])
-    assert vals3 == [True] and not pending3[0]  # resolved for everyone
-
-    # release without a verdict: next asker becomes the owner
-    k2 = VerifyCache.key(b"m2", b"s" * 64, b"p" * 32)
-    cache.lookup_or_claim_many([k2])
-    cache.release_many([k2])
-    v, p = cache.lookup_or_claim_many([k2])
-    assert v == [None] and not p[0]
-
-    # None keys are never claimed or pending
-    v, p = cache.lookup_or_claim_many([None])
-    assert v == [None] and not p[0]
+LADDER = (8, 32)  # two CPU-sized rungs (the ladder of test_staging_ring)
+R = LADDER[0]
 
 
-def test_verify_cache_claim_ttl_reclaims_abandoned():
-    """A claim whose owner died mid-verify must not stall waiters
-    forever: past claim_ttl the next asker takes ownership."""
-    import time as _time
-
-    from txflow_tpu.verifier import VerifyCache
-
-    cache = VerifyCache(claim_ttl=0.02)
-    k = VerifyCache.key(b"m", b"s" * 64, b"p" * 32)
-    cache.lookup_or_claim_many([k])
-    _, p = cache.lookup_or_claim_many([k])
-    assert p[0]  # fresh claim: still owned elsewhere
-    _time.sleep(0.03)
-    v, p = cache.lookup_or_claim_many([k])
-    assert v == [None] and not p[0]  # stale claim handed over
+@pytest.fixture(scope="module")
+def ladder_verifier(valset4):
+    return DeviceVoteVerifier(valset4[0], buckets=LADDER)
 
 
-def test_verify_cache_claim_keepalive_outlives_ttl():
-    """A device call slower than claim_ttl (a cold-shape compile runs
-    minutes) must NOT leak its claims mid-flight: the keepalive heartbeat
-    re-stamps them, so concurrent engines keep deferring instead of
-    re-verifying the same votes; once the owner exits, claims age out
-    normally."""
-    import time as _time
-
-    from txflow_tpu.verifier import VerifyCache
-
-    cache = VerifyCache(claim_ttl=0.05)
-    keys = [VerifyCache.key(b"m%d" % i, b"s" * 64, b"p" * 32) for i in range(3)]
-    _, pending = cache.lookup_or_claim_many(keys)
-    assert not any(pending)  # we own all three
-    with cache.claim_keepalive(keys):
-        _time.sleep(0.2)  # several TTLs inside the "device call"
-        _, p = cache.lookup_or_claim_many(keys)
-        assert all(p), "heartbeat must keep in-flight claims owned"
-    # owner exited without storing (the call failed): claims expire and
-    # the next asker takes over after the TTL
-    _time.sleep(0.08)
-    v, p = cache.lookup_or_claim_many(keys)
-    assert v == [None] * 3 and not any(p)
-    # keepalive over an empty claim list is a no-op context
-    with cache.claim_keepalive([]):
-        pass
+@pytest.mark.parametrize(
+    "slot,val",
+    [
+        pytest.param([], [], id="empty"),
+        pytest.param([0, 1, 0, 2, 1, 0], [0, 0, 0, 1, 0, 1], id="dense"),
+        pytest.param([0, 10**9, 0, 5 * 10**8, 10**9], [3, 1, 3, 2, 1], id="sort-branch"),
+        pytest.param([0, 0, 1, 1, 0, 1], [-1, 7, -1, 9, -1, 9], id="off-range-validators"),
+        pytest.param([4] * 6, [2] * 6, id="all-repeats-of-first"),
+    ],
+)
+def test_first_occurrence_mask_matches_dict_reference(slot, val):
+    seen, want = {}, []
+    for pair in zip(slot, val):
+        want.append(pair not in seen)
+        seen[pair] = True
+    got = first_occurrence_mask(np.array(slot, np.int64), np.array(val, np.int64))
+    assert got.dtype == bool
+    assert got.tolist() == want
 
 
-def test_shared_cache_pending_defers_instead_of_failing():
-    """An engine that meets another engine's in-flight verifies must
-    report those votes as dropped (deferred for retry) — never as
-    invalid — and must resolve them to the correct verdicts once the
-    owner stores. Deferred votes also must not contribute stake."""
-    from txflow_tpu.verifier import VerifyCache
-
-    vals, seeds = make_valset(4)
-    cache = VerifyCache()
-    golden = ScalarVoteVerifier(vals)
-    eng_b = ScalarVoteVerifier(vals, shared_cache=cache)
-
-    msgs, sigs, vidx, slot = make_batch(vals, seeds, n_txs=3)
-    n_slots = 3
-    keys = [
-        VerifyCache.key(msgs[i], sigs[i], eng_b._pub_keys[int(vidx[i])])
-        for i in range(len(msgs))
-    ]
-    # simulate engine A holding claims on every vote (mid-device-call)
-    _, pend = cache.lookup_or_claim_many(keys)
-    assert not pend.any()
-
-    got = eng_b.verify_and_tally(msgs, sigs, vidx, slot, n_slots)
-    assert got.dropped.all(), "pending votes must come back deferred"
-    assert not got.valid.any()
-    assert (got.stake == 0).all() and not got.maj23.any()
-
-    # engine A finishes: stores the true verdicts; B's retry is all hits
-    want = golden.verify_and_tally(msgs, sigs, vidx, slot, n_slots)
-    cache.store_many([(keys[i], bool(want.valid[i])) for i in range(len(keys))])
-    before = cache.misses
-    got2 = eng_b.verify_and_tally(msgs, sigs, vidx, slot, n_slots)
-    np.testing.assert_array_equal(want.valid, got2.valid)
-    np.testing.assert_array_equal(want.stake, got2.stake)
-    np.testing.assert_array_equal(want.maj23, got2.maj23)
-    np.testing.assert_array_equal(want.dropped, got2.dropped)
-    assert cache.misses == before, "retry after store must be all hits"
-
-
-def test_device_cached_pending_defers(valset4):
-    """Device cached path: same deferral contract as the scalar one."""
-    from txflow_tpu.verifier import VerifyCache
-
+@pytest.mark.parametrize("n", [1, R - 1, R, R + 1])
+def test_fused_parity_at_rung_edges(valset4, ladder_verifier, n):
+    """Either side of a rung's edge the device agrees with the golden
+    model row for row: padding rows never tally and never come back."""
     vals, seeds = valset4
-    cache = VerifyCache()
-    dev = DeviceVoteVerifier(vals, shared_cache=cache)
-    golden = ScalarVoteVerifier(vals)
-    msgs, sigs, vidx, slot = make_batch(vals, seeds, n_txs=2)
-    keys = [
-        VerifyCache_key_for(dev, msgs[i], sigs[i], int(vidx[i]))
-        for i in range(len(msgs))
-    ]
-    cache.lookup_or_claim_many(keys)  # another engine owns everything
-    got = dev.verify_and_tally(msgs, sigs, vidx, slot, 2)
-    assert got.dropped.all() and not got.valid.any()
-    cache.release_many(keys)  # owner aborted: dev may now verify
-    got2 = dev.verify_and_tally(msgs, sigs, vidx, slot, 2)
-    want = golden.verify_and_tally(msgs, sigs, vidx, slot, 2)
-    np.testing.assert_array_equal(want.valid, got2.valid)
-    np.testing.assert_array_equal(want.maj23, got2.maj23)
+    msgs, sigs, vidx, slot = make_batch(vals, seeds, n_txs=3)
+    msgs, sigs, vidx, slot = msgs[:n], sigs[:n], vidx[:n], slot[:n]
+    sigs[0] = sigs[0][:10] + bytes([sigs[0][10] ^ 1]) + sigs[0][11:]
+    n_slots = int(slot.max()) + 1
+    r = assert_parity(vals, msgs, sigs, vidx, slot, n_slots, device=ladder_verifier)
+    assert len(r.valid) == len(r.dropped) == n
+    assert len(r.stake) == len(r.maj23) == n_slots
+    assert r.valid.tolist() == [False] + [True] * (n - 1)
+    assert int(r.stake.sum()) == 10 * (n - 1)
 
 
-def VerifyCache_key_for(verifier, msg, sig, vi):
-    from txflow_tpu.verifier import VerifyCache
-
-    return VerifyCache.key(msg, sig, verifier._pub_keys[vi])
+@pytest.mark.parametrize("n,n_slots", [(1, 1), (R, R), (R + 1, 1), (R + 1, R + 1)])
+def test_predicted_shapes_is_what_submit_dispatches(ladder_verifier, n, n_slots):
+    """The cold-shape gate's prediction is the dispatch: a wrong one is a
+    compile inside a measured window that the gate called warm."""
+    dev = ladder_verifier
+    before = dev.shapes_used.counts()
+    predicted = dev.predicted_shapes(n, n_slots)
+    dev.submit(
+        [b""] * n, [b""] * n, np.zeros(n, np.int64),
+        np.arange(n, dtype=np.int64) % n_slots, n_slots,
+    ).result()
+    after = dev.shapes_used.counts()
+    dispatched = [s for s, c in after.items() if c != before.get(s, 0)]
+    assert dispatched == predicted
 
 
 def test_warmup_full_compiles_every_reachable_shape(valset4):
-    """warmup(full=True) must exercise _verify_only at EVERY miss bucket
-    (cached path) — a shape left cold compiles mid-measurement on the
-    first batch that hits it (r5: a 169 s throughput phase was ~160 s of
-    one such compile)."""
-    from txflow_tpu.verifier import VerifyCache
-
+    """warmup(full=True) dispatches (b, b) and (b, smallest) for every
+    rung b — a shape left cold compiles mid-measurement on the first
+    batch that hits it (r5: a 169 s throughput phase was ~160 s of one
+    such compile); the default warmup(n) only n's own combo."""
     vals, _seeds = valset4
-    dev = DeviceVoteVerifier(vals, buckets=(64, 256), shared_cache=VerifyCache())
-    seen: list[int] = []
-    orig = dev._verify_only
-
-    def spy(msgs, sigs, val_idx):
-        seen.append(len(msgs))
-        return orig(msgs, sigs, val_idx)
-
-    dev._verify_only = spy
+    dev = DeviceVoteVerifier(vals, buckets=LADDER)
     dev.warmup(full=True)
-    assert set(seen) >= set(dev.miss_buckets), (seen, dev.miss_buckets)
+    want = {("fused", b, s) for b in LADDER for s in (b, R)}
+    assert dev.shapes_used.snapshot() == want
 
-    # default warmup(n) keeps its contract: every shape an n-vote batch
-    # can hit is warm — all miss buckets up to n's coarse bucket
-    dev2 = DeviceVoteVerifier(vals, buckets=(64, 256), shared_cache=VerifyCache())
-    seen2: list[int] = []
-    orig2 = dev2._verify_only
-
-    def spy2(msgs, sigs, val_idx):
-        seen2.append(len(msgs))
-        return orig2(msgs, sigs, val_idx)
-
-    dev2._verify_only = spy2
-    dev2.warmup(256)
-    want = {b for b in dev2.miss_buckets if b <= 256}
-    assert set(seen2) >= want, (seen2, want)
-
-
-def test_replay_flood_costs_zero_repeat_dispatches(valset4):
-    """Replay-flood regression (accountable gossip): re-submitting a
-    batch the verifier has already judged must cost ZERO device
-    dispatches — the verdict cache replays every verdict, including the
-    False ones, so an identical-vote flood can never re-buy device time
-    with signatures that already failed."""
-    from txflow_tpu.verifier import VerifyCache
-
-    vals, seeds = valset4
-    dev = DeviceVoteVerifier(vals, shared_cache=VerifyCache())
-    dispatches: list[int] = []
-    orig = dev._dispatch_verify_only
-
-    def spy(msgs, sigs, val_idx, **kw):
-        dispatches.append(len(msgs))
-        return orig(msgs, sigs, val_idx, **kw)
-
-    dev._dispatch_verify_only = spy
-    msgs, sigs, vidx, slot = make_batch(vals, seeds, n_txs=3, corrupt=("ok", "flip"))
-    r1 = dev.verify_and_tally(msgs, sigs, vidx, slot, 3)
-    assert len(dispatches) == 1 and dispatches[0] == len(msgs)
-    assert r1.valid.any() and not r1.valid.all()  # mixed verdicts cached
-
-    r2 = dev.verify_and_tally(msgs, sigs, vidx, slot, 3)
-    assert len(dispatches) == 1, "an identical replay must not reach the device"
-    np.testing.assert_array_equal(r1.valid, r2.valid)
-    np.testing.assert_array_equal(r1.stake, r2.stake)
-    np.testing.assert_array_equal(r1.maj23, r2.maj23)
+    dev2 = DeviceVoteVerifier(vals, buckets=LADDER)
+    dev2.warmup(R + 1)
+    assert dev2.shapes_used.snapshot() == {("fused", LADDER[1], R)}

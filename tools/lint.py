@@ -4,7 +4,7 @@
 Usage:
     python tools/lint.py              # human-readable report, exit 0
     python tools/lint.py --check     # exit 1 on any unsuppressed violation
-    python tools/lint.py --json      # machine-readable report (profile_host)
+    python tools/lint.py --json      # machine-readable report
     python tools/lint.py --suppressed  # also list suppressed violations
     python tools/lint.py --update-pins # re-record twin-path fingerprints
     python tools/lint.py --prune-suppressions  # delete stale allow() comments

@@ -12,8 +12,7 @@ tiles over real-TCP ProcNets and bank the results matrix.
 ``--smoke`` walks the smoke diagonal (every level of every axis at
 least once, incl. one fully-composed tile — CI's bounded posture);
 ``--full`` walks the configured cross-product. ``--list``/``--dry-run``
-review the tile set before committing to a multi-hour run, exactly like
-tools/sim_device.py's preview flags.
+review the tile set before committing to a multi-hour run.
 
 Exit codes (scenario/harness.py contract): 0 = every tile green; a
 failed walk exits with the MOST SEVERE tile breach — 10 loss,

@@ -834,7 +834,7 @@ _SHAPE_FUNCS = {
 }
 
 # blessed shape-carrying attributes (ladder config, not raw input sizes)
-_SHAPE_ATTRS = {"buckets", "miss_buckets", "max_batch", "capacity", "_n_shards"}
+_SHAPE_ATTRS = {"buckets", "max_batch", "capacity", "_n_shards"}
 
 
 class RecompileHazardPass(LintPass):

@@ -60,10 +60,9 @@ class BatchCertVerifier(ScalarVoteVerifier):
     def __init__(
         self,
         val_set: ValidatorSet,
-        shared_cache=None,
         min_batch: int = 4,
     ):
-        super().__init__(val_set, shared_cache=shared_cache)
+        super().__init__(val_set)
         self.min_batch = int(min_batch)
         # one-tuple batch stage, same atomicity contract as the parent's
         # _stage: the batch path reads it ONCE per call, so a concurrent
@@ -74,7 +73,7 @@ class BatchCertVerifier(ScalarVoteVerifier):
             self._powers,
             ops_ed.EpochTables(self._pub_keys),
         )
-        # evidence counters (tests + bench stamp these): device
+        # evidence counters (tests read these): device
         # dispatches vs scalar fallthroughs, and total rows batched
         self.batch_calls = 0
         self.scalar_calls = 0
@@ -101,9 +100,7 @@ class BatchCertVerifier(ScalarVoteVerifier):
         quorum=None,
     ) -> TallyResult:
         n = len(msgs)
-        # the VerifyCache claim protocol is a per-signature host loop by
-        # construction; a cache-carrying instance keeps the parent path
-        if n < self.min_batch or self.cache is not None:
+        if n < self.min_batch:
             self.scalar_calls += 1
             return super().verify_and_tally(
                 msgs, sigs, val_idx, tx_slot, n_slots,

@@ -1,9 +1,9 @@
 """Sharded host-prep pool: the backend seam that parallelizes batch prep.
 
-The device-economics sim (tools/sim_device.py) and the r05 artifacts show
-the shared-cache configuration is host-bound: the serial Python prep —
-sign-bytes assembly, signature splitting, nibble/window-table extraction —
-caps throughput below the device-step rate. Two backends share one caller
+The engine is host-bound (PERF.md section 5: the device idles most of
+every cell): the serial Python prep — sign-bytes assembly, signature
+splitting, nibble/window-table extraction — caps throughput below the
+device-step rate. Two backends share one caller
 API behind ``make_host_pool``:
 
 - **thread** (``HostPrepPool``): worker threads. The two heavy prep
@@ -122,8 +122,8 @@ class HostPrepPool:
 
     ``workers`` counts the calling thread: a pool of 4 spawns 3 daemon
     threads and runs the caller's shard inline. Shared freely between
-    engines (the bench shares one pool across all four nodes via the
-    shared DeviceVoteVerifier); per-call wait accounting is returned to
+    engines (engines over one DeviceVoteVerifier share its pool,
+    ``ensure_host_pool``); per-call wait accounting is returned to
     each caller rather than accumulated globally.
     """
 
@@ -205,7 +205,7 @@ class HostPrepPool:
         Returns ``(results, pool_wait_s)``: per-shard results in shard
         order, and the wall time this caller spent blocked on shards it
         did not execute itself (the "host-bound on the queue" half of
-        the profile_host.py critical-path split). The last shard always
+        the trace/report.py critical-path split). The last shard always
         runs inline on the caller; while any submitted shard is still
         pending the caller drains the queue, so a congested shared pool
         costs queueing delay, never deadlock.

@@ -11,8 +11,7 @@ a compile stalls the in-flight ticket AND every batch queued behind it.
 
 1. ``enumerate_shapes()`` — predict the (kind, batch-bucket, slot-bucket)
    shapes reachable from the verifier's configuration (mirrors
-   ``DeviceVoteVerifier.warmup``'s coverage: the `_verify_only` miss
-   ladder when a cache is attached, the fused bucket combos when not);
+   ``DeviceVoteVerifier.warmup``'s coverage: the fused bucket combos);
 2. ``prewarm()`` — run ``warmup(full=...)`` once and SNAPSHOT the shapes
    the verifier actually dispatched (``DeviceVoteVerifier.shapes_used``),
    which is the authoritative warm set;
@@ -23,13 +22,13 @@ a compile stalls the in-flight ticket AND every batch queued behind it.
    the enumeration smallest-first on its own thread while the engine
    serves cold-shape batches through the scalar fallback);
 4. ``cold_shapes()`` / ``compile_in_run()`` — diff the shapes used since
-   the snapshot against it, so a run can assert (bench.py records
-   ``warm_shapes``/``compile_in_run`` in its JSON) that no compile
+   the snapshot against it, so a run can assert (perfbench and
+   chip_smoke.py report the cold shapes of a run) that no compile
    contaminated the timed phase instead of silently eating it. Shapes
    compiled by the warmer count as warm, not as in-run compiles: the
    compile ran concurrently with serving, never inside a dispatch.
 
-Wrapper verifiers (ResilientVoteVerifier, VerifierMux, FlakyVerifier) are
+Wrapper verifiers (ResilientVoteVerifier, FlakyVerifier) are
 unwrapped via their ``device``/``inner`` attributes; a scalar verifier has
 no compiled shapes and degrades every query to the empty set (and every
 batch to warm).
@@ -76,27 +75,7 @@ class ShapeWarmRegistry:
             return []
         shards = dev._n_shards
         shapes: set[tuple] = set()
-        if dev.cache is not None:
-            # cached config: every device call is a _verify_only over a
-            # miss set, padded on the fine miss ladder with the floor
-            # slot bucket. warmup(n)'s first probe collapses to one miss
-            # (identical warm keys), then the ladder itself.
-            shapes.add((
-                "verify",
-                bucket_size(1, dev.miss_buckets, multiple=shards),
-                dev.buckets[0],
-            ))
-            limit = dev.max_batch if full else bucket_size(n, dev.buckets)
-            for b in dev.miss_buckets:
-                if b > limit:
-                    break
-                shapes.add((
-                    "verify",
-                    bucket_size(b, dev.miss_buckets, multiple=shards),
-                    dev.buckets[0],
-                ))
-            return sorted(shapes)
-        # fused config: warmup(n) compiles n's own combo; full=True adds
+        # warmup(n) compiles n's own combo; full=True adds
         # (b, b) and (b, smallest) for every bucket b
         shapes.add((
             "fused",
@@ -112,13 +91,7 @@ class ShapeWarmRegistry:
         return sorted(shapes)
 
     def shapes_for_batch(self, n: int, n_slots: int = 1) -> list[tuple]:
-        """Every shape ONE n-vote / n_slots-tx batch can dispatch.
-
-        With a cache attached the device only ever sees the claimed miss
-        subset, whose size is unknown until dispatch (any m <= n), so the
-        prediction is the whole miss ladder up to n's rung — conservative
-        but exact: bucket_size is monotone, so no m <= n can land on a
-        rung above n's. Without a cache the batch maps to exactly one
+        """The shape ONE n-vote / n_slots-tx batch dispatches: exactly one
         fused (batch-bucket, slot-bucket) combo."""
         dev = self.device
         if dev is None:
@@ -153,28 +126,20 @@ class ShapeWarmRegistry:
         dev = self.device
         if dev is None:
             return False
-        kind, b, b_slots = shape
+        _, b, b_slots = shape
         with self._mtx:
             if shape in self.warmed:
                 return True
             self._warming.add(shape)
         seen_before = shape in dev.shapes_used
         try:
-            if kind == "verify":
-                m = _generating_size(b, dev.miss_buckets, dev._n_shards)
-                dev._verify_only(
-                    [b"bgwarm-%d" % i for i in range(m)],
-                    [b"\x00" * 64] * m,
-                    np.zeros(m, np.int64),
-                )
-            else:
-                nn = _generating_size(b, dev.buckets, dev._n_shards)
-                # slot buckets are not shard-rounded: b_slots IS a bucket
-                dev.verify_and_tally(
-                    [b""] * nn, [b""] * nn,
-                    np.zeros(nn, np.int64), np.zeros(nn, np.int64),
-                    b_slots,
-                )
+            nn = _generating_size(b, dev.buckets, dev._n_shards)
+            # slot buckets are not shard-rounded: b_slots IS a bucket
+            dev.verify_and_tally(
+                [b""] * nn, [b""] * nn,
+                np.zeros(nn, np.int64), np.zeros(nn, np.int64),
+                b_slots,
+            )
         except Exception:
             with self._mtx:
                 self._warming.discard(shape)

@@ -138,12 +138,12 @@ class _StepPrep:
 
     __slots__ = (
         "keys", "votes", "slots", "n_slots", "prior", "msgs", "sigs",
-        "val_idx", "dropped", "drain_seq", "verifier", "t0", "step",
+        "val_idx", "dropped", "verifier", "t0", "step",
         "trace_txs", "dispatch_end", "device_sid", "lane",
         "drop_t", "carry_t",
     )
 
-    def __init__(self, drain_seq: int, t0: float, lane: str | None = None):
+    def __init__(self, t0: float, lane: str | None = None):
         self.keys: list[bytes] = []
         self.votes: list[TxVote] = []
         self.slots: list[int] = []
@@ -153,7 +153,6 @@ class _StepPrep:
         self.sigs: list[bytes] = []
         self.val_idx = None
         self.dropped = 0
-        self.drain_seq = drain_seq
         self.verifier = None
         self.t0 = t0
         # the engine's step id (0 until a batch with votes is formed):
@@ -194,7 +193,7 @@ class _BatchCoalescer:
     size coalesced — still padded to a canonical bucket by the verifier.
 
     decide() is called from the engine thread only; the counters feed
-    txflow_coalesce_* metrics and the bench JSON."""
+    txflow_coalesce_* metrics and pipeline_stats()["coalesce"]."""
 
     __slots__ = (
         "targets", "linger", "full_batches", "linger_flushes",
@@ -506,7 +505,7 @@ class TxFlow:
         self._late_verified = 0
         self._carried_slots = 0
         self._open_vote_sets = 0
-        # host-prep split (profile_host.py prep_serial vs prep_pool_wait):
+        # host-prep split (trace/report.py prep_serial vs prep_pool_wait):
         # sign_s is the assembly stage's wall time, pool_wait_s the slice
         # of it this thread spent parked behind pool shards it didn't run
         self._pipe_prep_sign_s = 0.0
@@ -698,9 +697,8 @@ class TxFlow:
 
     def _setup_background_warmup(self) -> None:
         """Wire the cold-shape gate: a shared ShapeWarmRegistry as the
-        warmth oracle, a scalar fallback (sharing the device's
-        VerifyCache so verdicts memoize across the promotion boundary)
-        for batches whose shape is still cold, and the BackgroundWarmer
+        warmth oracle, a scalar fallback for batches whose shape is
+        still cold, and the BackgroundWarmer
         thread that compiles the enumeration concurrently with serving.
         No-op for scalar verifiers — nothing compiles there."""
         from .shapes import BackgroundWarmer, ShapeWarmRegistry
@@ -712,9 +710,7 @@ class TxFlow:
         if registry.device is None:
             return
         self._warm_gate = registry
-        self._cold_fallback = ScalarVoteVerifier(
-            self.val_set, shared_cache=registry.device.cache
-        )
+        self._cold_fallback = ScalarVoteVerifier(self.val_set)
         self._warmer = BackgroundWarmer(registry, full=True)
         self._warmer.start()
 
@@ -1052,7 +1048,7 @@ class TxFlow:
                 prep, ticket = inflight.popleft()
                 m.pipeline_depth.set(len(inflight))
                 result = self._collect(prep, ticket)
-                decided, requeued, all_deferred = self._route_result(prep, result)
+                self._route_result(prep, result)
                 self._pipe_steps += 1
                 self._steer_lingers()
                 if ctrl is not None:
@@ -1064,19 +1060,10 @@ class TxFlow:
                         m.pipeline_depth_changes.add(1)
                 if self._committer is None and self._unapplied:
                     self._apply_unapplied()
-                if all_deferred:
-                    # every vote deferred to another engine's in-flight
-                    # claims: back off on the owner's (~100 ms class)
-                    # timescale — the serial step()'s identical wait.
-                    # Unconditional (even with tickets in flight): the
-                    # deferred votes sit in _retry, and re-prepping them
-                    # against claims the owner still holds just spins the
-                    # fill stage against the owner's in-flight call
-                    self._pool_wait(prep.drain_seq, self.config.defer_backoff)
         finally:
             # drain stage: stop() (or a crash) must not orphan tickets —
-            # collect and route the tail in submission order so cache
-            # claims settle and decided votes reach their vote sets
+            # collect and route the tail in submission order so decided
+            # votes reach their vote sets
             while inflight:
                 prep, ticket = inflight.popleft()
                 try:
@@ -1135,9 +1122,9 @@ class TxFlow:
         """One serial verify+tally+commit round (prep -> submit -> collect
         -> route, no overlap); returns votes PROCESSED this step: votes
         routed to a decision (added / rejected / late) plus votes dropped
-        at drain time. Votes the verifier deferred (in-batch repeats,
-        cross-engine claim deferrals) are NOT counted — they re-enter via
-        _retry and are counted by the step that finally decides them (the
+        at drain time. Votes the verifier deferred (in-batch repeats) are
+        NOT counted — they re-enter via _retry and are counted by the step
+        that finally decides them (the
         old ``len(votes) + len(drop_now)`` counted those twice). The
         decided/requeued/dropped split is published in last_step_stats;
         decided + requeued always reconciles to the verified batch size.
@@ -1162,19 +1149,8 @@ class TxFlow:
         # claims during the call stay correct.
         ticket = self._submit_prep(prep)
         result = self._collect(prep, ticket)
-        decided, requeued, all_deferred = self._route_result(prep, result)
+        decided, _ = self._route_result(prep, result)
         self._pipe_steps += 1
-        if all_deferred:
-            # every vote deferred (another engine owns the in-flight
-            # verifies — shared VerifyCache claims): the results land in
-            # the cache when the owner's verify finishes, which takes a
-            # device step / a scalar sweep (~100 ms class, not ~1 ms) —
-            # back off on that scale or this loop busy-spins the whole
-            # step preamble (drain + sign-bytes + key build) against the
-            # owner's in-flight call for nothing. A pool wait (not a
-            # sleep) against the PRE-drain seq snapshot, so votes that
-            # arrived during the verify call wake the engine immediately.
-            self._pool_wait(prep.drain_seq, self.config.defer_backoff)
         return decided + prep.dropped
 
     def _sign_bytes_proc(self, votes, pool) -> "list[bytes] | None":
@@ -1278,10 +1254,6 @@ class TxFlow:
         """_prep_batch's work; returns the prep and the time _mtx was
         acquired (the gap from t0 is mutex queueing, not host prep —
         report.py subtracts it from the host component)."""
-        # seq snapshot BEFORE the drain: the defer-backoff wait must wake
-        # for votes that arrive during the verify call, not only after a
-        # post-step snapshot
-        drain_seq = self.tx_vote_pool.seq()
         with self._mtx:
             lk_acq = monotonic()
             if lane == "prio":
@@ -1335,7 +1307,7 @@ class TxFlow:
                 self._retry = []
             if not batch:
                 return None, lk_acq
-            prep = _StepPrep(drain_seq, t0, lane=lane)
+            prep = _StepPrep(t0, lane=lane)
             keys, votes, slots = prep.keys, prep.votes, prep.slots
             slot_of: dict[str, int] = {}
             drop_now: list[bytes] = []
@@ -1477,11 +1449,10 @@ class TxFlow:
 
         Cold-shape gate (background warmup): when the batch's device
         shape has not compiled yet, the batch is demoted to the scalar
-        fallback — the SAME verdicts (the fallback shares the device's
-        VerifyCache), just on the host — instead of stalling the whole
-        pipeline behind a synchronous compile. The BackgroundWarmer
-        flips the gate shape by shape; once warm, batches promote to the
-        device and never come back."""
+        fallback — the SAME verdicts, just on the host — instead of
+        stalling the whole pipeline behind a synchronous compile. The
+        BackgroundWarmer flips the gate shape by shape; once warm,
+        batches promote to the device and never come back."""
         with _Stage(self, SPAN_DISPATCH, prep.step, len(prep.votes)) as st:
             ticket = self._dispatch(prep)
         prep.dispatch_end = st.t1
@@ -1545,17 +1516,17 @@ class TxFlow:
         self._stage_done(SPAN_DEVICE, start, ready, prep.step, sid=sid)
         return result
 
-    def _route_result(self, prep: "_StepPrep", result) -> tuple[int, int, bool]:
+    def _route_result(self, prep: "_StepPrep", result) -> tuple[int, int]:
         """Stage 3: route the verified batch in submission (= pool ingest)
         order into the authoritative vote sets, committing inline the
-        moment a set crosses 2/3. Returns (decided, requeued,
-        all_deferred); decided + requeued == len(prep.votes) always."""
+        moment a set crosses 2/3. Returns (decided, requeued);
+        decided + requeued == len(prep.votes) always."""
         with _Stage(self, SPAN_ROUTE, prep.step, len(prep.votes)) as st:
             out = self._route(prep, result, st.t0)
         self.metrics.step_time.observe(st.t1 - prep.t0)
         return out
 
-    def _route(self, prep: "_StepPrep", result, t0: float) -> tuple[int, int, bool]:
+    def _route(self, prep: "_StepPrep", result, t0: float) -> tuple[int, int]:
         """_route_result's work, in three child spans that tile it:
         route_tally (under _mtx: routing, quorum decisions, removal of
         votes that can never be added, then the accountability hook),
@@ -1745,11 +1716,11 @@ class TxFlow:
             "decided": decided, "requeued": requeued,
             "dropped": prep.dropped, "batch": len(votes),
         }
-        return decided, requeued, requeued == len(votes)
+        return decided, requeued
 
     def pipeline_stats(self) -> dict:
         """Verify-pipeline observability snapshot (health registry,
-        profile_host, bench), every second of it from _stage_done.
+        perfbench), every second of it from _stage_done.
         overlap_ratio is the device_busy spans' sum (dispatch -> result
         usable on the host: the device's time and the readback thread's
         wait for the interpreter lock) over engine-active wall time:
@@ -2383,7 +2354,7 @@ class TxFlow:
 
         1. verifier RESTAGE, not rebuild: the device constants swap in
            place (same padded shapes, same bucket ladder, same compiled
-           programs, same VerifyCache and warm gate) — zero in-run
+           programs, same warm gate) — zero in-run
            compiles. Rebuild only when restage is impossible (capacity
            exceeded by a large join, int32 tally cap, or a non-restagable
            verifier type).
@@ -2403,14 +2374,9 @@ class TxFlow:
             # device transfers for an unchanged set
             if val_set is self.val_set or val_set.hash() == self.val_set.hash():
                 return
-            from ..verifier import ResilientVoteVerifier, VerifierMux
+            from ..verifier import ResilientVoteVerifier
 
             base = self.verifier
-            if isinstance(base, VerifierMux):
-                # a shared mux cannot follow one engine's rotation
-                # (other callers still run the old set): detach to a
-                # private verifier built like the mux's inner one
-                base = base.inner
             restaged = False
             rs = getattr(base, "restage", None)
             if rs is not None:

@@ -34,7 +34,6 @@ class FlakyVerifier:
             raise ValueError("fail_at must be 'result' or 'submit'")
         self.inner = inner
         self.val_set = inner.val_set
-        self.cache = getattr(inner, "cache", None)
         mb = getattr(inner, "max_batch", None)
         if mb is not None:
             self.max_batch = mb

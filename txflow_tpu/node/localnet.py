@@ -97,7 +97,7 @@ class LocalNet:
                 f"n_nodes must be in [1, {len(priv_vals)}], got {n_nodes}"
             )
         if enable_consensus and n_nodes is not None and n_nodes < len(priv_vals):
-            # mirror the bench.py guard: a hosted subset cannot reach block
+            # a hosted subset cannot reach block
             # quorum — the missing validators never prevote, so consensus
             # silently hangs at round 0 instead of failing fast
             raise ValueError(

@@ -175,7 +175,7 @@ fe_inv = _common.make_inv(fe_mul)
 # tables, and kernels all build on these symbols at import time, so the
 # choice must be made before anything imports ops.curve. Default stays
 # radix-8 (the TPU-measured configuration) until a live A/B on hardware
-# confirms the 20-limb kernel; bench.py exposes the knob.
+# confirms the 20-limb kernel.
 if os.environ.get("TXFLOW_FE_RADIX") == "13":
     from . import fe13 as _fe13
 

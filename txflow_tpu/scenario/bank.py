@@ -1,5 +1,5 @@
-"""Results-matrix banking for the scenario grid, under bench.py's
-clean-supersede contract.
+"""Results-matrix banking for the scenario grid, under a clean-supersede
+contract.
 
 The banked artifact (``bench_artifacts/scenario_grid_latest.json``) is
 the regression reference: "handles every scenario" as a matrix of tile

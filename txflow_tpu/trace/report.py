@@ -2,11 +2,9 @@
 
 Folds one node's engine pipeline accounting (``TxFlow.pipeline_stats``)
 and its trace digest into the host-prep / device / linger / lock-wait /
-network breakdown the ROADMAP's two open perf frontiers are steered by
-(the sim predicts the shared-cache config is HOST-bound; this report is
-what validates or falsifies that on a live run). Wired into
-``profile_host.py`` (per-node lines) and ``bench.py --latency-slo``
-(result-JSON ``critical_path``)."""
+network breakdown. Not read by the benchmark (perfbench/ reads the
+spans and ``pipeline_stats`` itself); a caller with a live node's stats
+and digest gets the split from here."""
 
 from __future__ import annotations
 
@@ -94,7 +92,7 @@ def critical_path(pipeline_stats: dict, trace_digest: dict | None = None) -> dic
 
 def merge_critical_paths(per_node: list[dict]) -> dict:
     """Sum the seconds components across nodes, recompute fractions —
-    the fleet-level line bench.py emits."""
+    the fleet-level line."""
     keys = ("host_s", "device_s", "lock_wait_s", "linger_s")
     total = {k: round(sum(cp.get(k, 0.0) for cp in per_node), 4) for k in keys}
     for k in ("prep_serial_s", "prep_pool_wait_s", "linger_prio_s",
@@ -125,7 +123,7 @@ def merge_critical_paths(per_node: list[dict]) -> dict:
 
 
 def format_line(cp: dict) -> str:
-    """One-line rendering for profile_host.py."""
+    """One-line rendering."""
     f = cp.get("fractions") or {}
     parts = " ".join(
         f"{k.removesuffix('_s')}={cp.get(k, 0.0):.3f}s({f.get(k.removesuffix('_s'), 0):.0%})"

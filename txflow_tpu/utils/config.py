@@ -103,11 +103,6 @@ class EngineConfig:
     # min_batch only adds latency (r4 verdict item 9: the reference's
     # headline is realtime per-tx commit, README.md:10). 0 disables.
     idle_flush: float = 0.002
-    # backoff when a whole step was deferred to another engine's
-    # in-flight verifies (shared VerifyCache claims): the owner's call
-    # completes on the device-step / scalar-sweep timescale, so re-trying
-    # sooner only burns the step preamble against its in-flight work
-    defer_backoff: float = 0.005
     # verify pipeline: how many device verify calls the engine keeps in
     # flight via the verifier's submit/collect split (verifier.VerifyTicket).
     # At 2, batch N+1's host prep (drain + sign bytes + prepare_compact)
@@ -215,7 +210,7 @@ class EngineConfig:
     # adaptive per-lane linger (engine.adaptive.AdaptiveLingerController):
     # steer both lane lingers from the live trace digest against
     # slo_budget_ms. Off by default — it needs an active tracer and
-    # windows of traffic to say anything; bench.py --latency-slo opts in.
+    # windows of traffic to say anything.
     adaptive_linger: bool = False
     slo_budget_ms: float = 50.0
     # speculative quorum commit (engine.txflow._route_result): at collect

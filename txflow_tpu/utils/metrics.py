@@ -472,7 +472,7 @@ class TxFlowMetrics:
         # waited on host prep/routing); device_idle is the accumulated
         # active time with NO verify call in flight — the gap the
         # pipeline exists to close. The *_seconds counters are the
-        # per-stage breakdown profile_host.py prints.
+        # per-stage breakdown.
         self.pipeline_depth = r.gauge("txflow", "pipeline_depth", "verify tickets in flight")
         self.pipeline_overlap_ratio = r.gauge("txflow", "pipeline_overlap_ratio", "dispatch -> result usable on the host (device time plus the readback thread waiting for the interpreter lock), summed, / engine-active wall time")
         self.pipeline_device_idle = r.gauge("txflow", "pipeline_device_idle_seconds", "engine-active seconds less the dispatch -> result-usable seconds: a lower bound of device idle time")
