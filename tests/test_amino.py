@@ -5,6 +5,8 @@ The zero-time vector is taken from the reference's pinned amino output
 it proves seconds use two's-complement uvarint, not zigzag.
 """
 
+import pytest
+
 from txflow_tpu.codec import amino
 
 
@@ -76,3 +78,47 @@ def test_uvarint_overflow_rejected():
     # Max uint64 still decodes.
     r = amino.AminoReader(amino.uvarint(2**64 - 1))
     assert r.read_uvarint() == 2**64 - 1
+
+
+def _time_body_by_the_rule(unix_ns: int) -> bytes:
+    """The amino rule spelled out with the plain primitives: seconds and
+    nanos as ``field_key + varint``, each elided when zero."""
+    seconds, nanos = divmod(unix_ns, 1_000_000_000)
+    out = b""
+    if seconds:
+        out += amino.field_key(1, amino.TYP3_VARINT) + amino.varint(seconds)
+    if nanos:
+        out += amino.field_key(2, amino.TYP3_VARINT) + amino.uvarint(nanos)
+    return out
+
+
+@pytest.mark.parametrize("unix_ns", [
+    0, 1, -1, 127, 128, 16_383, 16_384, 2**21 - 1, 2**21, 2**28 - 1, 2**28,
+    999_999_999, 1_000_000_000, 1_000_000_001, 1_999_999_999,
+    1_700_000_000_000_000_000, 1_700_000_000_123_456_789,
+    1_700_000_000_999_999_999, 1_700_000_000_000_016_384,
+    -62135596800 * 1_000_000_000, -62135596800 * 1_000_000_000 + 5,
+    2**62, -(2**62), 2**63 - 1,
+])
+def test_time_body_is_field_key_plus_varint(unix_ns):
+    # encode_time_body reads its varints from tables and memoizes the
+    # seconds' field (PR 34): the bytes are the rule's, for every value
+    body = amino.encode_time_body(unix_ns)
+    assert body == _time_body_by_the_rule(unix_ns)
+    assert amino.encode_time_body(unix_ns) == body  # from the memo now
+    assert amino.decode_time_body(body) == unix_ns
+
+
+def test_time_body_random_and_memo_turnover():
+    import random
+
+    rng = random.Random(34)
+    for _ in range(20_000):
+        ns = rng.randrange(-(2**63), 2**63)
+        assert amino.encode_time_body(ns) == _time_body_by_the_rule(ns)
+    # more distinct seconds than the memo holds: it is dropped and refilled
+    base = 1_700_000_000
+    for s in range(3 * amino._SECONDS_MEMO):
+        ns = (base + s) * 1_000_000_000 + s
+        assert amino.encode_time_body(ns) == _time_body_by_the_rule(ns)
+    assert len(amino._seconds_field) <= amino._SECONDS_MEMO

@@ -190,14 +190,14 @@ def test_ledger_sync_strike_quarantines_without_double_charge():
 # -- TxVotePool origin bookkeeping ----------------------------------------
 
 
-def test_pool_origin_set_by_both_ingest_twins():
+def test_pool_origin_set_by_the_one_ingest_core():
     pvs, _vals = make_pvs(4)
     pool = TxVotePool(MempoolConfig(cache_size=100))
     v1 = sign_vote(pvs[0], b"origin-a")
     v2 = sign_vote(pvs[1], b"origin-b")
     v3 = sign_vote(pvs[2], b"origin-c")
-    pool.check_tx(v1, tx_info=TxInfo(sender_id=5))       # raising twin
-    pool.check_tx_many([v2], tx_info=TxInfo(sender_id=7))  # batch twin
+    pool.check_tx(v1, tx_info=TxInfo(sender_id=5))       # the one-vote call
+    pool.check_tx_many([v2], tx_info=TxInfo(sender_id=7))  # the frame call
     pool.check_tx(v3)  # local ingest: no peer to strike
     keys = [v.vote_key() for v in (v1, v2, v3)]
     assert pool.origins_of(keys) == [5, 7, 0]

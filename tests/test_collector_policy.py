@@ -11,12 +11,14 @@ against the stock rule's for the same traffic, a planted cycle still
 collected, ``gc`` left as found by nodes that start and stop, and the
 four counters in ``pipeline_stats()`` and ``/health``.
 
-The churn keeps 102,400 votes resident (307,200 tracked objects): in a
-smaller heap the stock rule's other condition, ten middle collections,
-binds long before the quarter does, and a run would count that. A frame
-is 512 votes: the young generation's count falls with every object freed
-by reference count, so a churn in steps of under 700 objects never
-starts a collection of any generation.
+The churn keeps 204,800 votes resident (409,600 tracked objects: a
+resident vote is its ``TxVote`` and ONE record tuple since PR 34, where
+it was three objects): in a smaller heap the stock rule's other
+condition, ten middle collections, binds long before the quarter does,
+and a run would count that. A frame is 1,024 votes: the young
+generation's count falls with every object freed by reference count, so
+a churn in steps of under 700 objects never starts a collection of any
+generation.
 """
 
 import conftest  # noqa: F401
@@ -33,7 +35,7 @@ from txflow_tpu.types import TxVote
 from txflow_tpu.utils.collector import COLLECTOR, CollectorPolicy
 from txflow_tpu.utils.config import MempoolConfig
 
-FRAME = 512
+FRAME = 1024
 RESIDENT = 200 * FRAME
 CHURN = 4_000 * FRAME  # the most votes a phase puts through the pool
 
@@ -79,9 +81,8 @@ def votes():
 class _Churn:
     """A TxVotePool held at RESIDENT votes: a frame in through
     ``check_tx_many``, the oldest frame out through ``remove``. Every
-    ingest allocates the vote's ``_PoolVote`` and its ``senders`` set,
-    which live through hundreds of young collections and die by
-    reference count."""
+    ingest allocates the vote's record, one tuple, which lives through
+    hundreds of young collections and dies by reference count."""
 
     def __init__(self, votes):
         self.votes = votes
@@ -127,10 +128,11 @@ def test_a_third_of_the_stock_rules_full_collections_under_pool_churn(votes):
     churn = _Churn(votes)
     gc.collect()
     # the traffic is what gives the stock rule eight full collections: in
-    # a process of 400,000 tracked objects 88 middle collections of 11
-    # frames (a full one every 11th: a quarter of the heap is 9 of them);
-    # this one runs a full one every 52nd (400,000 over the 7,711 a
-    # middle collection is taken for). A larger process stretches both
+    # a process of 400,000 tracked objects it was 88 middle collections of
+    # 11 frames (a full one every 11th: a quarter of the heap is 9 of
+    # them) where this one runs a full one every 52nd (400,000 over the
+    # 7,711 a middle collection is taken for). A larger process (this
+    # one is 500,000 since PR 34 doubled the votes) stretches both
     with _FullCollections() as stock:
         traffic = churn.step(CHURN, until=lambda: stock.n == 8)
     assert stock.n == 8, (stock.n, traffic)
@@ -144,8 +146,8 @@ def test_a_third_of_the_stock_rules_full_collections_under_pool_churn(votes):
         policy.remove()
     assert 1 <= mine.n and 3 * mine.n <= stock.n, (mine.n, stock.n)
     assert stats["full_collections"] == mine.n and stats["full_collect_s"] > 0
-    # a vote's _PoolVote and its senders set; the TxVotes are frozen
-    assert stats["survivors"] >= 2 * RESIDENT
+    # a vote's record; the TxVotes are frozen
+    assert stats["survivors"] >= RESIDENT
     assert stats["frozen_objects"] >= len(votes)
     del churn
     assert _as_found() == found
@@ -186,7 +188,7 @@ def test_a_planted_cycle_is_collected_within_two_doublings(votes, policy):
     promoted = 0
     while gone() is not None and promoted <= 2 * heap:
         churn.step(10 * FRAME)
-        promoted += 2 * 10 * FRAME  # a _PoolVote and a set an ingest
+        promoted += 10 * FRAME  # a record an ingest
     assert gone() is None, (promoted, heap, gc.get_threshold())
     assert policy.stats()["full_collections"] == 2  # the one above, and the one that found it
 

@@ -428,7 +428,11 @@ def test_ingest_log_compaction_bounds_memory():
         pool_base.COMPACT_THRESHOLD = old_threshold
 
 
-# ---- check_tx vs check_tx_many parity (the batched twins must not drift) ----
+# ---- check_tx vs check_tx_many parity ----
+# The vote pool has ONE ingest since PR 34 (check_tx is a one-vote frame
+# through check_tx_many): its parity tests hold the frame's 64-vote groups
+# and the one-vote call to the same decisions. The mempool still keeps a
+# hand-inlined pair (analysis/twins.json pins it to this file).
 
 
 def _drive_one_by_one(check, items):
@@ -446,8 +450,8 @@ def test_votepool_check_tx_many_parity():
     """One ingest sequence — accepts, a duplicate, an oversized vote, a
     pool-full rejection — pushed through check_tx one-by-one and through
     check_tx_many as a batch: identical per-position error types and
-    identical final pool state (check_tx_many inlines a non-raising twin
-    of _ingest_locked; this is the drift alarm)."""
+    identical final pool state (one ingest core since PR 34: the one-vote
+    call raises what the frame call returns)."""
     pv = MockPV()
     v0, v1, v2, v3 = (make_vote(i, pv) for i in range(4))
     big = make_vote(99, pv)
@@ -480,12 +484,12 @@ def test_votepool_check_tx_many_parity():
 
 
 def test_votepool_origin_parity():
-    """Ingest-origin stamping through both twins: the sender id frozen on
+    """Ingest-origin stamping through both calls: the sender id frozen on
     an entry at ingest (what invalid-verdict attribution charges) must be
     identical whether the vote arrived via check_tx or check_tx_many, a
     later add_sender must never rewrite it, and a local/unattributed
-    ingest must read back as UNKNOWN_PEER_ID (drift alarm for the
-    accountable-gossip origin branch of the twins)."""
+    ingest must read back as UNKNOWN_PEER_ID (the accountable-gossip
+    origin: the record's first sender)."""
     from txflow_tpu.pool.txvotepool import UNKNOWN_PEER_ID
 
     pv = MockPV()
@@ -515,7 +519,7 @@ def test_votepool_origin_parity():
 
 
 def test_votepool_lane_eviction_parity():
-    """Lane-aware ingest through both twins: priority votes land on the
+    """Lane-aware ingest through both calls: priority votes land on the
     priority log, and at pool-full a priority vote evicts the oldest
     bulk vote while a bulk vote still bounces — identically via check_tx
     and check_tx_many (drift alarm for the lane/eviction branch)."""
@@ -549,7 +553,7 @@ def test_votepool_lane_eviction_parity():
         assert not p.in_cache(vote_key(bulk[0]))  # re-deliverable
         items, _ = p.priority_entries_from(0, limit=10)
         assert [k for k, _v, _h, _s in items] == [vote_key(prio)]
-        # ingest-time lane freezing (both twins must stamp it): the
+        # ingest-time lane freezing (the record carries it): the
         # priority log + the bulk walk are an exact partition of the
         # live entries, even after the hook's answer changes
         assert p.prio_seq() == 1
@@ -563,7 +567,7 @@ def test_votepool_lane_eviction_parity():
 
 
 def test_votepool_wal_degradation_parity(tmp_path):
-    """WAL EIO through both twins (drift alarm for the degrade branch):
+    """WAL EIO through both calls (the degrade branch):
     a failing WAL append must not raise out of either ingest path, must
     flip wal_degraded identically, and the votes must still land — the
     WAL is a restart-recovery aid, not the admission ledger."""
@@ -595,6 +599,302 @@ def test_votepool_wal_degradation_parity(tmp_path):
     assert [v.signature for _, v in a.entries()] == [
         v.signature for _, v in b.entries()
     ]
+
+
+# ---- the one ingest core, the record and the counters (PR 34) ----
+
+
+def _pool_state(p: TxVotePool):
+    """Everything a reader of the pool can see, in walk order."""
+    items, pos = p.entries_from(0, limit=10_000)
+    keys = [k for k, *_ in items]
+    return {
+        "entries": [(k, v.signature, h, seg) for k, v, h, seg in items],
+        "cursor": pos,
+        "seq": p.seq(),
+        "log": list(p._log),
+        "bytes": p.txs_bytes(),
+        "size": p.size(),
+        "dedup": [k for k in keys if p.in_cache(k)],
+        "origins": p.origins_of(keys),
+        "by_tx": {h: list(ks) for h, ks in p._by_tx.items()},
+        "drain": [k for k, _ in p.drain_batch(10_000)],
+    }
+
+
+@pytest.mark.parametrize("n_lead", [0, 60, 130])
+def test_votepool_mixed_frame_parity(tmp_path, n_lead):
+    """One frame that mixes primed and unprimed votes, a duplicate of a
+    resident vote, a duplicate within the frame, an oversized vote and
+    votes past the pool's capacity — through check_tx one by one and
+    through check_tx_many as a frame, with the mix placed in the frame's
+    first, first-and-second and third 64-vote lock group: the same error
+    type at every position, the same records, log, dedup set, bytes and
+    WAL, and the counters add up."""
+    from txflow_tpu.types.tx_vote import decode_tx_vote
+
+    pv = MockPV()
+    resident = make_vote(1000, pv)
+    lead = [make_vote(2000 + i, pv) for i in range(n_lead)]
+    fresh = [make_vote(i, pv) for i in range(8)]
+    primed = [decode_tx_vote(encode_tx_vote(make_vote(100 + i, pv))) for i in range(3)]
+    assert all(v._wire_cache is not None and v._seg_cache is None for v in primed)
+    big = make_vote(99, pv)
+    big.tx_hash = "A" * 1024  # encodes past max_msg_bytes
+    unsigned = TxVote(1, "AB" * 32, b"\x01" * 32, 5, b"\x02" * 20, None)
+    frame = lead + [
+        fresh[0], primed[0], resident, fresh[1], fresh[0], big, primed[1],
+        unsigned, fresh[2], primed[2], fresh[3], fresh[4], fresh[5],
+    ]
+    size = n_lead + 1 + 8  # the resident vote, then eight of the mix fit
+
+    def mk(name):
+        p = TxVotePool(MempoolConfig(size=size, cache_size=1000, max_msg_bytes=512))
+        p.init_wal(str(tmp_path / name))
+        p.check_tx(cold([resident])[0], tx_info=TxInfo(sender_id=4))
+        return p
+
+    def cold(votes):  # both pools get votes in the same state
+        return [v.copy() if v._wire_cache else
+                TxVote(v.height, v.tx_hash, v.tx_key, v.timestamp_ns,
+                       v.validator_address, v.signature) for v in votes]
+
+    a, b = mk("one"), mk("many")
+    info = TxInfo(sender_id=9)
+    errs_one = _drive_one_by_one(lambda v: a.check_tx(v, tx_info=info), cold(frame))
+    errs_many = b.check_tx_many(cold(frame), info)
+    want = [type(None)] * n_lead + [
+        type(None), type(None), ErrTxInCache, type(None), ErrTxInCache,
+        ErrTxTooLarge, type(None), type(None), type(None), type(None),
+        type(None), ErrMempoolIsFull, ErrMempoolIsFull,
+    ]
+    assert [type(e) for e in errs_one] == want
+    assert [type(e) for e in errs_many] == want
+    sa, sb = _pool_state(a), _pool_state(b)
+    assert sa == sb
+    assert sa["size"] == size and len(sa["log"]) == size
+    # the duplicate recorded its sender on the resident vote, origin kept
+    for p in (a, b):
+        assert p.has_sender(vote_key(resident), 4) and p.has_sender(vote_key(resident), 9)
+        assert p.origins_of([vote_key(resident)]) == [4]
+        # a rejected vote left no residue
+        for v in (big, fresh[4], fresh[5]):
+            assert not p.has(vote_key(v))
+        st = p.ingest_stats()
+        assert st["votes"] == 1 + len(frame)
+        assert st["fast"] + st["general"] + st["primed"] == st["votes"]
+        assert st["primed"] == 3 and st["cpu_s"] >= 0.0
+        # make_vote's hashes, keys, addresses and signatures are canonical:
+        # only the oversized and the unsigned vote take encode_tx_vote
+        assert st["general"] == 2
+    a.close_wal()
+    b.close_wal()
+    from txflow_tpu.utils.wal import WAL
+
+    wal_a = list(WAL(str(tmp_path / "one")).replay())
+    wal_b = list(WAL(str(tmp_path / "many")).replay())
+    assert wal_a == wal_b == [encode_tx_vote(v) for _k, v in a.entries()]
+
+
+def test_votepool_check_tx_is_a_one_vote_frame():
+    """check_tx raises exactly the error object check_tx_many returns for
+    the vote, and accepts what it accepts."""
+    pv = MockPV()
+    pool = TxVotePool(MempoolConfig(size=2, cache_size=10))
+    v0, v1, v2 = (make_vote(i, pv) for i in range(3))
+    assert pool.check_tx(v0) is None
+    with pytest.raises(ErrTxInCache):
+        pool.check_tx(v0)
+    assert pool.check_tx(v1) is None
+    with pytest.raises(ErrMempoolIsFull) as full:
+        pool.check_tx(v2)
+    assert "number of txs 2 (max: 2)" in str(full.value)
+    with pytest.raises(ErrMempoolIsFull):  # the capacity test comes first
+        pool.check_tx(v0)
+    assert pool.check_tx_many([]) == []
+    assert pool.ingest_stats()["votes"] == 5
+
+
+def test_votepool_record_senders_and_origin():
+    """The record holds ONE sender id until a second peer delivers the
+    vote, then a tuple in order of arrival: has_sender, has_sender_many,
+    add_sender's three codes and origins_of read both forms, and a
+    record replaced for a new sender keeps its place in the walk."""
+    from txflow_tpu.pool import txvotepool as tvp
+
+    pv = MockPV()
+    v0, v1, v2 = (make_vote(i, pv) for i in range(3))
+    k0, k1, k2 = (vote_key(v) for v in (v0, v1, v2))
+    pool = TxVotePool(MempoolConfig(size=10, cache_size=100))
+    assert pool.check_tx_many([v0, v1], TxInfo(sender_id=5)) == [None, None]
+    pool.check_tx(v2)  # local: UNKNOWN_PEER_ID
+    assert pool._votes[k0][tvp._SENDERS] == 5  # one int, no container
+    assert pool.has_sender(k0, 5) and not pool.has_sender(k0, 6)
+    assert pool.has_sender_many([k0, k1, k2, b"gone"], 5) == [True, True, False, False]
+    assert pool.has_sender_many([k0, k1, k2], tvp.UNKNOWN_PEER_ID) == [False, False, True]
+    assert pool.add_sender(k0, 5) == TxVotePool.SENDER_REPEAT
+    assert pool.add_sender(k0, 6) == TxVotePool.SENDER_ADDED
+    assert pool._votes[k0][tvp._SENDERS] == (5, 6)
+    assert pool.add_sender(k0, 6) == TxVotePool.SENDER_REPEAT
+    assert pool.add_sender(k0, 5) == TxVotePool.SENDER_REPEAT
+    assert pool.add_sender(k0, 7) == TxVotePool.SENDER_ADDED
+    # a duplicate through the ingest records its sender the same way
+    assert isinstance(pool.check_tx_many([v1], TxInfo(sender_id=8))[0], ErrTxInCache)
+    assert isinstance(pool.check_tx_many([v1], TxInfo(sender_id=8))[0], ErrTxInCache)
+    assert pool._votes[k1][tvp._SENDERS] == (5, 8)
+    assert pool.has_sender_many([k0, k1, k2], 8) == [False, True, False]
+    assert pool.has_sender_many([k0, k1, k2], 7) == [True, False, False]
+    assert pool.origins_of([k0, k1, k2, b"gone"]) == [5, 5, tvp.UNKNOWN_PEER_ID, tvp.UNKNOWN_PEER_ID]
+    # replaced records kept their place, their segment, height and lane
+    items, _ = pool.entries_from(0)
+    assert [k for k, *_ in items] == [k0, k1, k2]
+    assert [seg for *_, seg in items] == [
+        b"".join(pool.segs_for_tx(v.tx_hash)) for v in (v0, v1, v2)
+    ]
+    assert pool._votes[k0][tvp._LANE] == -1 and pool._votes[k0][tvp._HEIGHT] == 0
+    pool.remove([k0])
+    assert pool.add_sender(k0, 9) == TxVotePool.SENDER_GONE
+    assert not pool.has_sender(k0, 5)
+
+
+@pytest.mark.parametrize("payload", [0, 1, 127, 128, 129, 223, 16_383, 16_384, 2_097_151, 2_097_152])
+def test_votepool_wire_size_is_the_segments_payload(payload):
+    """The byte accounting takes a vote's size off its segment, whatever
+    the prefix's length: len(length_prefixed(x)) -> len(x)."""
+    from txflow_tpu.codec import amino
+    from txflow_tpu.pool.txvotepool import _wire_size
+
+    assert _wire_size(amino.length_prefixed(bytes(payload))) == payload
+
+
+def test_votepool_bytes_return_to_zero_by_every_removal_path():
+    pv = MockPV()
+    votes = [make_vote(i, pv) for i in range(6)]
+    sizes = [len(encode_tx_vote(v)) for v in votes]
+    pool = TxVotePool(MempoolConfig(size=10, cache_size=100))
+    pool.check_tx_many(votes)
+    assert pool.txs_bytes() == sum(sizes)
+    pool.remove([vote_key(votes[0])])
+    pool.update(2, [votes[1]])
+    pool.lane_of_vote = lambda v: -1
+    with pool._mtx:
+        assert pool._evict_bulk_locked()  # the oldest left: votes[2]
+    assert pool.txs_bytes() == sum(sizes[3:]) and pool.size() == 3
+    assert not pool.has(vote_key(votes[2]))
+    pool.remove([vote_key(v) for v in votes])
+    assert pool.txs_bytes() == 0 and pool.size() == 0 and pool._by_tx == {}
+
+
+def test_votepool_wal_of_a_256_vote_frame_and_its_replay(tmp_path):
+    """A 256-vote frame of cold votes (one validator, 256 txs): the WAL
+    holds, in ingest order, the bytes the amino rule gives (the parent's
+    encoder's, spelled out in tests/test_tx_vote.py), and a replay into a
+    new pool gives the same records."""
+    from test_tx_vote import _wire_by_the_rule
+    from txflow_tpu.utils.wal import WAL
+
+    addr, votes = b"\x05" * 20, []
+    for i in range(256):
+        key = hashlib.sha256(b"frame-%d" % i).digest()
+        votes.append(TxVote(i % 3, key.hex().upper(), key, 1_700_000_000_000_000_000 + 64 * i,
+                            addr, hashlib.sha512(b"sig-%d" % i).digest()))
+    want = [_wire_by_the_rule(v) for v in votes]
+    path = str(tmp_path / "votes.wal")
+    pool = TxVotePool(MempoolConfig(size=1000, cache_size=1000), wal_path=path)
+    assert pool.check_tx_many(votes, TxInfo(sender_id=3)) == [None] * 256
+    st = pool.ingest_stats()
+    assert (st["votes"], st["fast"], st["general"], st["primed"]) == (256, 256, 0, 0)
+    pool.close_wal()
+    assert list(WAL(path).replay()) == want
+    again = TxVotePool(MempoolConfig(size=1000, cache_size=1000), wal_path=path)
+    assert again.replay_wal() == 256
+    st = again.ingest_stats()  # decoded from the log: none re-encoded
+    assert (st["votes"], st["fast"], st["general"], st["primed"]) == (256, 0, 0, 256)
+    a, _ = pool.entries_from(0, limit=1000)
+    b, _ = again.entries_from(0, limit=1000)
+    assert [(k, v.signature, h, seg) for k, v, h, seg in a] == [
+        (k, v.signature, h, seg) for k, v, h, seg in b
+    ]
+    assert [seg for *_, seg in a] == [bytes([len(w) & 0x7F | 0x80, len(w) >> 7]) + w for w in want]
+    assert again.txs_bytes() == pool.txs_bytes() == sum(len(w) for w in want)
+    # replayed votes have no peer to strike; the frame's had sender 3
+    assert set(again.origins_of([k for k, *_ in b])) == {0}
+    assert set(pool.origins_of([k for k, *_ in a])) == {3}
+    again.close_wal()
+
+
+def test_votepool_one_tracked_object_a_resident_vote():
+    """10,000 resident votes add at most one object the collector tracks
+    each besides their TxVote (the record tuple), plus one index dict a
+    tx: counted with the collector frozen, so nothing is collected or
+    promoted in between."""
+    import gc
+
+    n, per_tx = 10_000, 50
+    addr, votes = b"\x05" * 20, []
+    for i in range(n):
+        key = hashlib.sha256(b"tracked-%d" % (i // per_tx)).digest()
+        votes.append(TxVote(0, key.hex().upper(), key, 1_700_000_000_000_000_000 + i,
+                            addr, hashlib.sha512(b"tracked-sig-%d" % i).digest()))
+    pool = TxVotePool(MempoolConfig(size=2 * n, cache_size=2 * n))
+    info = TxInfo(sender_id=3)
+    was_enabled = gc.isenabled()
+    gc.collect()
+    gc.freeze()
+    gc.disable()
+    try:
+        before = len(gc.get_objects())
+        for lo in range(0, n, 250):
+            assert not any(pool.check_tx_many(votes[lo : lo + 250], info))
+        added = len(gc.get_objects()) - before
+    finally:
+        if was_enabled:
+            gc.enable()
+        gc.unfreeze()
+    assert pool.size() == n
+    # the index dicts hold only bytes and None, which the collector does
+    # not track at all; the slack is the interpreter's own (frames, lists)
+    assert n <= added <= n + n // per_tx + 64, added
+    # a second sender makes the one int a tuple of ints: still one record
+    keys = [vote_key(v) for v in votes]
+    for k in keys[:100]:
+        assert pool.add_sender(k, 4) == TxVotePool.SENDER_ADDED
+    assert all(type(pool._votes[k]) is tuple and len(pool._votes[k]) == 5 for k in keys)
+
+
+def test_votepool_ingest_counters_on_health_and_metrics():
+    """txvote_ingest_votes / _cpu_s / _fast / _general / _primed: on the
+    pool, on /health's progress and in the Prometheus exposition, the
+    three ways adding up to the votes."""
+    from txflow_tpu.node import LocalNet
+
+    net = LocalNet(2, use_device_verifier=False)
+    net.start()
+    try:
+        txs = [b"ingest-counter-%d=v" % i for i in range(6)]
+        for tx in txs:
+            net.broadcast_tx(tx)
+        assert net.wait_all_committed(txs, timeout=60)
+        node = net.nodes[0]
+        node.health.registry.refresh(node)
+        progress = node.health.snapshot()["progress"]
+        st = node.tx_vote_pool.ingest_stats()
+        names = ["votes", "cpu_s", "fast", "general", "primed"]
+        assert [progress["txvote_ingest_" + n] <= st[n] for n in names] == [True] * 5
+        votes = progress["txvote_ingest_votes"]
+        assert votes >= 2 * len(txs)  # its own vote and its peer's, a tx
+        assert (progress["txvote_ingest_fast"] + progress["txvote_ingest_general"]
+                + progress["txvote_ingest_primed"]) == votes
+        # its own votes come as objects (one pass each), its peer's decoded
+        assert progress["txvote_ingest_fast"] >= len(txs)
+        assert progress["txvote_ingest_primed"] >= len(txs)
+        assert progress["txvote_ingest_general"] == 0
+        assert progress["txvote_ingest_cpu_s"] > 0.0
+        text = node.metrics_registry.expose()
+        for n in names:
+            assert f"txflow_health_txvote_ingest_{n} " in text, n
+    finally:
+        net.stop()
 
 
 def test_mempool_check_tx_many_parity():
@@ -631,7 +931,7 @@ def test_mempool_check_tx_many_parity():
 
 
 def test_pool_trace_span_parity():
-    """The twins must also agree on tracing: one accepted item = exactly
+    """Both calls must also agree on tracing: one accepted item = exactly
     one ingest span, duplicates and rejections record nothing — whether
     ingested one-by-one or as a batch, in both pools (sample_rate=1 so
     every tx is sampled)."""

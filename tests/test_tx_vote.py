@@ -18,6 +18,8 @@ CanonicalTxVote and diff."""
 
 import time
 
+import pytest
+
 from txflow_tpu.codec import amino
 from txflow_tpu.crypto import ed25519
 from txflow_tpu.crypto.hash import address_hash, tx_hash, tx_key
@@ -269,3 +271,169 @@ def test_decode_rejects_empty_timestamp_field_from_cache():
     assert d.timestamp_ns == 0
     assert d._wire_cache is None
     assert encode_tx_vote(d) == wire
+
+
+# -- the ingest's one-pass wire form (types/tx_vote.py ingest_bytes_many, PR 34) --
+#
+# Byte identity is the contract: WAL records, gossip segments and vote keys
+# are the bytes encode_tx_vote / amino.length_prefixed / sha256 give, and
+# those are the bytes the amino rule gives when it is spelled out with the
+# plain primitives below (what encode_tx_vote was before it read its
+# timestamp body from tables).
+
+
+def _wire_by_the_rule(v: TxVote) -> bytes:
+    out = b""
+    if v.height != 0:
+        out += amino.field_key(1, amino.TYP3_VARINT) + amino.varint(v.height)
+    if v.tx_hash:
+        out += amino.field_key(2, amino.TYP3_BYTELEN) + amino.length_prefixed(v.tx_hash.encode())
+    out += amino.field_key(3, amino.TYP3_BYTELEN) + amino.length_prefixed(v.tx_key or bytes(32))
+    seconds, nanos = divmod(v.timestamp_ns, 1_000_000_000)
+    ts = b""
+    if seconds:
+        ts += amino.field_key(1, amino.TYP3_VARINT) + amino.varint(seconds)
+    if nanos:
+        ts += amino.field_key(2, amino.TYP3_VARINT) + amino.uvarint(nanos)
+    if ts:
+        out += amino.field_key(4, amino.TYP3_BYTELEN) + amino.length_prefixed(ts)
+    if v.validator_address:
+        out += amino.field_key(5, amino.TYP3_BYTELEN) + amino.length_prefixed(v.validator_address)
+    if v.signature:
+        out += amino.field_key(6, amino.TYP3_BYTELEN) + amino.length_prefixed(v.signature)
+    return out
+
+
+def _cold(height=0, tx_hash=None, key=None, ts=1_700_000_000_123_456_789,
+          addr=b"\x07" * 20, sig=b"\x09" * 64) -> TxVote:
+    """A vote with empty caches, as a feeder or a signer hands it over."""
+    import hashlib
+
+    key = hashlib.sha256(b"one-pass").digest() if key is None else key
+    v = TxVote(height, key.hex().upper() if tx_hash is None else tx_hash, key, ts, addr, sig)
+    assert v._wire_cache is None and v._seg_cache is None and v._vk_cache is None
+    return v
+
+
+# (id, vote fields, which way its bytes come: "fast" is the one-pass
+# layout, "general" encode_tx_vote)
+_SHAPES = [
+    ("canonical-height-0", {}, "fast"),
+    ("height-7", {"height": 7}, "fast"),
+    ("height-two-byte-varint", {"height": 300}, "fast"),
+    ("height-2**40", {"height": 2**40}, "fast"),
+    ("height-negative", {"height": -1}, "fast"),
+    ("timestamp-0", {"ts": 0}, "fast"),
+    ("timestamp-negative", {"ts": -1}, "fast"),
+    ("timestamp-go-zero-time", {"ts": -62135596800 * 10**9}, "fast"),
+    ("timestamp-whole-seconds", {"ts": 1_700_000_000 * 10**9}, "fast"),
+    ("timestamp-999999999-ns", {"ts": 1_700_000_000 * 10**9 + 999_999_999}, "fast"),
+    ("timestamp-nanos-only", {"ts": 16_384}, "fast"),
+    ("hash-lower-case", {"tx_hash": "ab" * 32}, "fast"),
+    ("hash-empty", {"tx_hash": ""}, "general"),
+    ("hash-short", {"tx_hash": "A" * 63}, "general"),
+    ("hash-long", {"tx_hash": "A" * 200}, "general"),
+    ("hash-64-chars-not-64-bytes", {"tx_hash": "é" * 64}, "general"),
+    ("key-empty", {"key": b"", "tx_hash": "A" * 64}, "general"),
+    ("address-short", {"addr": b"\x07" * 19}, "general"),
+    ("address-long", {"addr": b"\x07" * 21}, "general"),
+    ("address-empty", {"addr": b""}, "general"),
+    ("signature-absent", {"sig": None}, "general"),
+    ("signature-empty", {"sig": b""}, "general"),
+    ("signature-short", {"sig": b"\x09" * 63}, "general"),
+    ("signature-long", {"sig": b"\x09" * 65}, "general"),
+]
+
+
+def _check_ingest_bytes(v: TxVote, way: str) -> None:
+    from txflow_tpu.crypto.hash import sha256
+    from txflow_tpu.types.tx_vote import ingest_bytes_many
+
+    want = _wire_by_the_rule(v)
+    arrived_with = v._wire_cache
+    wires, segs, keys, fast, general = ingest_bytes_many([v])
+    assert wires == [want]
+    assert segs == [amino.length_prefixed(want)]
+    assert keys == [sha256(v.signature or b"")]
+    assert (fast, general) == {"fast": (1, 0), "general": (0, 1), "primed": (0, 0)}[way]
+    if way == "primed":
+        assert wires[0] is arrived_with  # never re-encoded
+    # the caches as encode_tx_vote, vote_key and the pool have always left
+    # them: an unsigned vote's wire form is not kept
+    assert v._wire_cache == (want if v.signature is not None else None)
+    assert v._seg_cache == segs[0] and v._vk_cache == keys[0]
+    assert v.vote_key() == keys[0]
+    # the definition agrees, from the cache and without it
+    assert encode_tx_vote(v) == want
+    assert encode_tx_vote(TxVote(v.height, v.tx_hash, v.tx_key, v.timestamp_ns,
+                                 v.validator_address, v.signature)) == want
+    # a second pass over the same vote reads what the first left
+    again = ingest_bytes_many([v])
+    assert again[:3] == (wires, segs, keys)
+    assert again[3:] == ((0, 1) if v.signature is None else (0, 0))
+
+
+@pytest.mark.parametrize("fields,way", [s[1:] for s in _SHAPES], ids=[s[0] for s in _SHAPES])
+def test_ingest_bytes_identity_by_field_shape(fields, way):
+    _check_ingest_bytes(_cold(**fields), way)
+
+
+@pytest.mark.parametrize("how", ["decoded", "decoded-many", "over-long-varint", "copied"])
+def test_ingest_bytes_identity_of_a_vote_that_arrives_primed(how):
+    from txflow_tpu.types.tx_vote import decode_tx_votes_many
+
+    src = _cold(height=5)
+    wire = encode_tx_vote(src)
+    if how == "decoded":  # the gossip reactor, WAL replay
+        v, way = decode_tx_vote(wire), "primed"
+    elif how == "decoded-many":
+        v, way = decode_tx_votes_many([wire] * 16)[3], "primed"
+    elif how == "copied":
+        v, way = src.copy(), "primed"
+    else:
+        # height 5 as a two-byte varint: accepted, but the decoder withholds
+        # the wire cache, and what the pool writes is the minimal form
+        assert wire[:2] == b"\x08\x05"
+        v, way = decode_tx_vote(b"\x08\x85\x00" + wire[2:]), "fast"
+        assert v._wire_cache is None and v.height == 5
+    _check_ingest_bytes(v, way)
+    assert v._wire_cache == wire
+
+
+def test_ingest_bytes_identity_over_1000_seeded_random_votes():
+    import random
+
+    from txflow_tpu.crypto.hash import sha256
+    from txflow_tpu.types.tx_vote import ingest_bytes_many
+
+    rng = random.Random(3434)
+
+    def pick(canonical, others):
+        return canonical if rng.random() < 0.8 else rng.choice(others)
+
+    votes, n_primed = [], 0
+    for _ in range(1000):
+        key = rng.randbytes(32)
+        v = TxVote(
+            pick(0, [1, 127, 128, rng.randrange(2**63), -rng.randrange(1, 2**63)]),
+            pick(key.hex().upper(), ["", key.hex(), key.hex()[:40], "Z" * 130]),
+            pick(key, [b""]),
+            pick(rng.randrange(1_600_000_000 * 10**9, 1_900_000_000 * 10**9),
+                 [0, rng.randrange(10**9), -rng.randrange(2**62), rng.randrange(2**62),
+                  rng.randrange(1_600_000_000, 1_900_000_000) * 10**9]),
+            pick(rng.randbytes(20), [b"", rng.randbytes(19), rng.randbytes(32)]),
+            pick(rng.randbytes(64), [None, b"", rng.randbytes(63), rng.randbytes(70)]),
+        )
+        if rng.random() < 0.2 and v.signature and v.tx_key:
+            v = decode_tx_vote(_wire_by_the_rule(v))
+            n_primed += v._wire_cache is not None
+        votes.append(v)
+    want = [_wire_by_the_rule(v) for v in votes]
+    wires, segs, keys, fast, general = ingest_bytes_many(votes)
+    assert wires == want
+    assert segs == [amino.length_prefixed(w) for w in want]
+    assert keys == [sha256(v.signature or b"") for v in votes]
+    # which way the bytes came adds up, and every way was taken
+    assert fast + general + n_primed == 1000
+    assert fast > 300 and general > 200 and n_primed > 50
+    assert [encode_tx_vote(v) for v in votes] == want

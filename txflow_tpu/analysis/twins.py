@@ -1,10 +1,10 @@
 """twin-path: pin hand-synced duplicate logic to its parity tests.
 
-The pools deliberately keep an inlined, non-raising batch twin of their
-scalar ingest path (``check_tx_many`` vs ``check_tx``/``_ingest_locked``
-— see the 64-item lock-group rationale in pool/txvotepool.py). The twins
+The mempool deliberately keeps an inlined, non-raising batch twin of its
+scalar ingest path (``check_tx_many`` vs ``_check_tx_locked``). The twins
 MUST evolve together, and the only mechanical guard is the parity tests
-that replay both paths against each other.
+that replay both paths against each other. (The vote pool needs none:
+its ``check_tx`` is a one-vote frame through ``check_tx_many``.)
 
 This pass pins each twin function's AST fingerprint together with its
 registered parity test file's content hash in ``twins.json`` (committed).

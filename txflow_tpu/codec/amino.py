@@ -66,31 +66,45 @@ def length_prefixed(payload: bytes) -> bytes:
     return uvarint(len(payload)) + payload
 
 
+# A varint in groups of 14 bits, two bytes a group: _VAR14_MORE[x] is x
+# with both continuation bits set (more groups follow), _VAR14_LAST[x] the
+# minimal varint of x (the last group). A timestamp's nanoseconds are 30
+# bits: two lookups and a byte where the 7-bit loop runs five rounds.
+_VAR14_MORE = [bytes(((x & 0x7F) | 0x80, (x >> 7) | 0x80)) for x in range(1 << 14)]
+_VAR14_LAST = [uvarint(x) for x in range(1 << 14)]
+
+# seconds -> their field (key 0x08 + varint), the votes of a frame share a
+# handful of seconds; dropped whole when it has grown past _SECONDS_MEMO
+_seconds_field: dict[int, bytes] = {}
+_SECONDS_MEMO = 512
+
+
 def encode_time_body(unix_ns: int) -> bytes:
     """Body of an amino-embedded time.Time given integer unix nanoseconds.
 
     seconds = floor(unix_ns / 1e9) (matches Go Time.Unix() for negative
-    times), nanos in [0, 1e9). Each field elided when zero. Runs on the
-    per-vote encode/sign-bytes paths, hence the inlined varint loops
-    (field keys 0x08/0x10 = (fnum << 3) | TYP3_VARINT).
+    times), nanos in [0, 1e9). Each field elided when zero. Runs once a
+    vote on the encode and sign-bytes paths, hence the memo and the
+    tables (field keys 0x08/0x10 = (fnum << 3) | TYP3_VARINT); the bytes
+    are ``field_key + varint`` of each, pinned against exactly that by
+    tests/test_amino.py.
     """
     seconds, nanos = divmod(unix_ns, 1_000_000_000)
-    out = bytearray()
-    if seconds != 0:
-        out.append(0x08)
-        n = seconds & _U64_MASK
-        while n > 0x7F:
-            out.append((n & 0x7F) | 0x80)
-            n >>= 7
-        out.append(n)
-    if nanos != 0:
-        out.append(0x10)
-        n = nanos
-        while n > 0x7F:
-            out.append((n & 0x7F) | 0x80)
-            n >>= 7
-        out.append(n)
-    return bytes(out)
+    out = _seconds_field.get(seconds)
+    if out is None:
+        if len(_seconds_field) >= _SECONDS_MEMO:
+            _seconds_field.clear()
+        out = _seconds_field[seconds] = b"\x08" + varint(seconds) if seconds else b""
+    if nanos >= 0x10000000:
+        return (
+            out + b"\x10" + _VAR14_MORE[nanos & 0x3FFF]
+            + _VAR14_MORE[(nanos >> 14) & 0x3FFF] + _SMALL[nanos >> 28]
+        )
+    if nanos >= 0x4000:
+        return out + b"\x10" + _VAR14_MORE[nanos & 0x3FFF] + _VAR14_LAST[nanos >> 14]
+    if nanos:
+        return out + b"\x10" + _VAR14_LAST[nanos]
+    return out
 
 
 class AminoReader:

@@ -154,6 +154,14 @@ class DegradedModeRegistry:
             "committed_evictions": node.txflow.committed_evictions,
             "committed_txs": int(node.metrics.committed_txs.value()),
         }
+        # what the vote pool's ingest costs (thread CPU: another thread's
+        # hold of the interpreter lock is not in it) and which way the
+        # votes' bytes came: fast + general + primed = votes; general > 0
+        # means votes of a shape off the canonical one are being offered
+        ingest = node.tx_vote_pool.ingest_stats()
+        for name, value in ingest.items():
+            progress["txvote_ingest_" + name] = value
+            getattr(self.metrics, "txvote_ingest_" + name).set(value)
         pipe = getattr(node.txflow, "pipeline_stats", None)
         if pipe is not None:
             # verify-pipeline health: a collapsing overlap ratio with a

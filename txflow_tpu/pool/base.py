@@ -72,6 +72,14 @@ class IngestLogPool:
         if not self._first_new_t:
             self._first_new_t = monotonic()
 
+    def _log_extend_quiet(self, keys: list[bytes]) -> None:
+        """_log_append_quiet for a whole lock-group's keys at once, in
+        their ingest order. The caller stamps _first_new_t itself, when
+        the group's first item is accepted, and follows with _log_notify."""
+        self._sh_log.note_write()
+        self._log.extend(keys)
+        self._seq += len(keys)
+
     def _log_notify(self) -> None:
         self._cond.notify_all()
 

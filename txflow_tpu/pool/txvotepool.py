@@ -20,14 +20,12 @@ popping).
 from __future__ import annotations
 
 import threading
-from dataclasses import dataclass, field
 
-from ..codec import amino
-from ..crypto.hash import sha256
 from ..trace.tracer import NULL_TRACER, SPAN_VOTE_INGEST
-from ..types import TxVote, decode_tx_vote, encode_tx_vote
+from ..types import TxVote, decode_tx_vote
+from ..types.tx_vote import ingest_bytes_many
 from ..utils.cache import make_lru
-from ..utils.clock import monotonic
+from ..utils.clock import monotonic, thread_time
 from ..utils.config import MempoolConfig
 from ..utils.failpoints import FailpointError
 from ..utils.wal import WAL
@@ -52,30 +50,53 @@ def vote_key(vote: TxVote) -> bytes:
     return vote.vote_key()  # cached on the immutable vote
 
 
-@dataclass(slots=True)
-class _PoolVote:
-    height: int
-    vote: TxVote
-    senders: set[int] = field(default_factory=set)
-    size: int = 0  # encoded wire size, cached so removals never re-encode
-    # uvarint-length-prefixed wire form, built once at ingest: the gossip
-    # batch frame is a plain b"".join of these, so per-peer broadcast
-    # walks never re-serialize (r4 profile: lp+append per vote per peer)
-    seg: bytes = b""
-    # ingest-time admission lane (LANE_PRIORITY or -1): the partition
-    # key for the engine's lane-split drain. Frozen at ingest so the
-    # priority log and bulk_entries_from stay an exact partition of the
-    # main log even if the lane hook's answer drifts later (mempool
-    # eviction, late tx arrival) — a vote is delivered by EXACTLY the
-    # log its ingest classified it into. Set by BOTH ingest twins.
-    lane: int = -1
-    # ORIGIN: the sender whose delivery created this entry (first element
-    # of `senders`, frozen at ingest). Invalid-signature verdicts are
-    # attributed to the origin, not the whole sender set — later
-    # duplicate senders never cost a device slot, and striking them
-    # would punish honest gossip redundancy (health/byzantine.py).
-    # UNKNOWN_PEER_ID = local/RPC/WAL ingest: no peer to strike.
-    origin: int = 0
+# A resident vote's record is ONE tuple, the only object the collector
+# tracks for it besides the TxVote itself (a quarter of a million votes are
+# resident under a flood, and every young collection walks what the ingest
+# allocated): ``(vote, height, seg, senders, lane)``, read by index.
+#
+# height: the pool's height at ingest.
+# seg: the uvarint-length-prefixed wire form, built once at ingest: the
+#   gossip batch frame is a plain b"".join of these, so per-peer broadcast
+#   walks never re-serialize (r4 profile: lp+append per vote per peer). The
+#   vote's encoded size, which the byte accounting adds at ingest and takes
+#   off at removal, is this segment's payload (_wire_size): never stored,
+#   never re-encoded.
+# senders: the ONE sender id until a second peer delivers the vote, then a
+#   tuple of ids in order of arrival. Its first element (or the id itself)
+#   is the ORIGIN: the sender whose delivery created this entry, frozen at
+#   ingest. Invalid-signature verdicts are attributed to the origin, not
+#   the whole sender set — later duplicate senders never cost a device
+#   slot, and striking them would punish honest gossip redundancy
+#   (health/byzantine.py). UNKNOWN_PEER_ID = local/RPC/WAL ingest: no peer
+#   to strike.
+# lane: ingest-time admission lane (LANE_PRIORITY or -1): the partition
+#   key for the engine's lane-split drain. Frozen at ingest so the
+#   priority log and bulk_entries_from stay an exact partition of the main
+#   log even if the lane hook's answer drifts later (mempool eviction,
+#   late tx arrival) — a vote is delivered by EXACTLY the log its ingest
+#   classified it into.
+_VOTE, _HEIGHT, _SEG, _SENDERS, _LANE = range(5)
+_Record = tuple  # (TxVote, int, bytes, int | tuple[int, ...], int)
+
+
+def _wire_size(seg: bytes) -> int:
+    """Payload length of a length-prefixed segment: the vote's wire size."""
+    n = len(seg)
+    if n <= 128:  # a one-byte prefix: payloads under 128
+        return n - 1
+    if n <= 16385:  # two bytes: payloads under 16,384, every real vote
+        return n - 2
+    k = 3
+    while n - k >= 1 << (7 * k):
+        k += 1
+    return n - k
+
+
+def _has_sender(senders, sender_id: int) -> bool:
+    if type(senders) is tuple:
+        return sender_id in senders
+    return senders == sender_id
 
 
 class TxVotePool(IngestLogPool):
@@ -83,13 +104,12 @@ class TxVotePool(IngestLogPool):
         super().__init__()  # _mtx/_cond/_seq + compacted ingest log
         self.config = config
         self.height = height
-        self._votes: dict[bytes, _PoolVote] = self._items  # vote_key -> entry
+        self._votes: dict[bytes, _Record] = self._items  # vote_key -> record
         # secondary index: tx_hash -> {vote_key: None} (an insertion-
         # ordered set), so segs_for_tx is O(votes-for-tx) instead of a
         # full O(pool) scan — the quorum-stall watchdog calls it per
         # stalled tx, and at bench depth the scan was the whole pool.
-        # Maintained by BOTH ingest paths (check_tx's _ingest_locked and
-        # the inlined check_tx_many twin) and every removal path.
+        # Maintained by the ingest (check_tx_many) and every removal path.
         self._by_tx: dict[str, dict[bytes, None]] = {}
         self._votes_bytes = 0
         # vote-pool lanes: a vote inherits its tx's admission lane via the
@@ -105,6 +125,17 @@ class TxVotePool(IngestLogPool):
         # network-residual attribution; wired by the node, NULL_TRACER =
         # one attribute check per accepted vote
         self.tracer = NULL_TRACER
+        # what the ingest costs and which way a vote's bytes came
+        # (types/tx_vote.py ingest_bytes_many): votes offered, the thread
+        # CPU seconds of their frames (two clock reads a frame, another
+        # thread's hold of the interpreter lock is not in them), and of
+        # the votes how many were laid out in one pass (fast), went
+        # through encode_tx_vote (general), or arrived with their wire
+        # form (primed). Written under _mtx, in a frame's last hold.
+        self.ingest_votes = 0
+        self.ingest_cpu_s = 0.0
+        self.ingest_fast = 0
+        self.ingest_general = 0
         self.cache = make_lru(config.cache_size)
         self._txs_available = threading.Event()
         self._notified_txs_available = False
@@ -160,6 +191,18 @@ class TxVotePool(IngestLogPool):
     def enable_txs_available(self) -> None:
         self._notify_available = True
 
+    def ingest_stats(self) -> dict:
+        """The ingest's counters, one consistent reading: ``votes``,
+        ``cpu_s``, ``fast``, ``general``, ``primed`` (the three ways add
+        up to the votes)."""
+        with self._mtx:
+            votes, fast, general = self.ingest_votes, self.ingest_fast, self.ingest_general
+            cpu_s = self.ingest_cpu_s
+        return {
+            "votes": votes, "cpu_s": round(cpu_s, 6), "fast": fast,
+            "general": general, "primed": votes - fast - general,
+        }
+
     def has(self, key: bytes) -> bool:
         with self._mtx:
             return key in self._votes
@@ -167,7 +210,7 @@ class TxVotePool(IngestLogPool):
     def has_sender(self, key: bytes, sender_id: int) -> bool:
         with self._mtx:
             entry = self._votes.get(key)
-            return entry is not None and sender_id in entry.senders
+            return entry is not None and _has_sender(entry[_SENDERS], sender_id)
 
     def in_cache(self, key: bytes) -> bool:
         """Non-mutating dedup-cache membership: True means a check_tx for
@@ -186,7 +229,9 @@ class TxVotePool(IngestLogPool):
             out = []
             for k in keys:
                 entry = votes.get(k)
-                out.append(entry is not None and sender_id in entry.senders)
+                out.append(
+                    entry is not None and _has_sender(entry[_SENDERS], sender_id)
+                )
             return out
 
     # add_sender return codes (truthiness preserved for old callers:
@@ -207,10 +252,27 @@ class TxVotePool(IngestLogPool):
             entry = self._votes.get(key)
             if entry is None:
                 return self.SENDER_GONE
-            if sender_id in entry.senders:
+            if not self._add_sender_locked(key, entry, sender_id):
                 return self.SENDER_REPEAT
-            entry.senders.add(sender_id)
             return self.SENDER_ADDED
+
+    def _add_sender_locked(self, key: bytes, entry: _Record, sender_id: int) -> bool:
+        """Record one more sender of a resident vote (under _mtx); False
+        if it is among them already. The record is replaced in place of
+        the dict (its position in the walk order stays), the origin stays
+        first."""
+        senders = entry[_SENDERS]
+        if type(senders) is not tuple:
+            if senders == sender_id:
+                return False
+            senders = (senders,)
+        elif sender_id in senders:
+            return False
+        self._votes[key] = (
+            entry[_VOTE], entry[_HEIGHT], entry[_SEG],
+            senders + (sender_id,), entry[_LANE],
+        )
+        return True
 
     def origins_of(self, keys: list[bytes]) -> list[int]:
         """Ingest origin (pool sender id) for each key, one lock hold;
@@ -223,7 +285,11 @@ class TxVotePool(IngestLogPool):
             out = []
             for k in keys:
                 entry = votes.get(k)
-                out.append(UNKNOWN_PEER_ID if entry is None else entry.origin)
+                if entry is None:
+                    out.append(UNKNOWN_PEER_ID)
+                else:
+                    senders = entry[_SENDERS]
+                    out.append(senders[0] if type(senders) is tuple else senders)
             return out
 
     def _lane_quiet(self, vote: TxVote) -> int:
@@ -245,10 +311,10 @@ class TxVotePool(IngestLogPool):
         vote leaves the dedup cache too, so peer regossip re-delivers it
         once the pool drains (same retryability as a full-pool bounce)."""
         for k, e in self._votes.items():
-            if self._lane_quiet(e.vote) == LANE_PRIORITY:
+            if self._lane_quiet(e[_VOTE]) == LANE_PRIORITY:
                 continue
             self._votes.pop(k)
-            self._votes_bytes -= e.size
+            self._votes_bytes -= _wire_size(e[_SEG])
             self._index_discard(k, e)
             self.cache.remove(k)
             return True
@@ -259,12 +325,12 @@ class TxVotePool(IngestLogPool):
     def check_tx(
         self, vote: TxVote, tx_info: TxInfo | None = None, write_wal: bool = True
     ) -> None:
-        """Raises on rejection; returns None when the vote entered the pool."""
-        tx_info = tx_info or TxInfo(UNKNOWN_PEER_ID)
-        encoded = encode_tx_vote(vote)
-        with self._mtx:
-            self._ingest_locked(vote, encoded, vote_key(vote), tx_info, write_wal)
-            self._notify_txs_available()
+        """Raises on rejection; returns None when the vote entered the
+        pool. A one-vote frame through check_tx_many: one ingest, so the
+        two cannot drift."""
+        err = self.check_tx_many([vote], tx_info, write_wal)[0]
+        if err is not None:
+            raise err
 
     def check_tx_many(
         self,
@@ -272,181 +338,126 @@ class TxVotePool(IngestLogPool):
         tx_info: TxInfo | None = None,
         write_wal: bool = True,
     ) -> list[Exception | None]:
-        """Frame-batched ingest: per-vote acceptance decisions identical
-        to check_tx (same order, same errors — returned, not raised),
-        with bounded lock holds (64-vote groups) and one waiter wakeup
-        per group. Encode/hash for cache-miss votes runs inside the lock
-        group — in the gossip path those caches are always primed at
-        decode, so the in-lock work is dict stores and accounting; only
-        locally constructed votes pay an in-lock encode (~1 us each,
-        r5 microbench: the out-of-lock prepped-tuple design cost more in
-        packaging than it saved in lock width)."""
+        """The pool's ingest: a frame's per-vote acceptance decisions in
+        frame order (errors returned, not raised; built only on actual
+        rejection), with bounded lock holds (64-vote groups) and one
+        waiter wakeup per group.
+
+        A frame is ingested as a frame. Its bytes — wire form, gossip
+        segment, dedup key — come from one pass over it BEFORE the lock
+        (types/tx_vote.py ingest_bytes_many; a vote that arrives primed
+        costs three reads there), so a lock hold is dict stores and
+        accounting: one record tuple a vote, the group's keys appended to
+        the ingest log in one extend, the byte count carried in a local.
+        Sized with tools/ingest_bench.py (sandbox CPU, shape only;
+        val64-flood's frames, 65,536 resident, thread CPU a vote over
+        several readings): 4.7-6.6 us cold (no cached bytes), 2.5-3.1
+        decoded (wire form only), 1.7-2.4 primed, where PR 32's pair,
+        which encoded with twenty small calls under the lock and kept a
+        dataclass and a set a vote, cost 7.4-11.0, 3.5-4.7 and 2.0-3.1.
+        On the chip's host, a val64-flood window (txvote_ingest_cpu_s
+        over txvote_ingest_votes): 12.1 -> 6.2 us a vote (PERF.md
+        section 5, PR 34)."""
+        n = len(votes)
+        if n == 0:
+            return []
+        t0 = thread_time()
         tx_info = tx_info or TxInfo(UNKNOWN_PEER_ID)
-        out: list[Exception | None] = [None] * len(votes)
-        # Inlined non-raising twin of _ingest_locked (keep the two in
-        # sync): the wrapper-per-vote form — prepped tuples, try/except,
-        # enumerate — measured 5.7 us/vote against the core's 4.4
-        # (r5 microbench), i.e. more than half the ingest cost was
-        # packaging. Error objects are built only on actual rejection.
+        out: list[Exception | None] = [None] * n
+        wires, segs, keys, fast, general = ingest_bytes_many(votes)
         sid = tx_info.sender_id
         cfg = self.config
+        size_cap = cfg.size
+        bytes_cap = cfg.max_txs_bytes
         max_size = cfg.max_msg_bytes - _MSG_OVERHEAD
         cache_push = self.cache.push
         votes_d = self._votes
-        log_append = self._log_append_quiet  # one _log_notify per group
+        by_tx_d = self._by_tx
         lane_of = self.lane_of_vote
         prio_append = self._prio_log.append
         wal = self.wal if write_wal and not self.wal_degraded else None
-        oset = object.__setattr__
-        new = _PoolVote.__new__
+        tr = self.tracer
         # bounded lock holds: a whole gossip frame under one lock starved
         # the drain/purge/inject paths for milliseconds (r5 instrumented
-        # profile) — 64 votes ≈ a few hundred µs, keeping the pool fair
-        for base in range(0, len(votes), 64):
-            accepted = False
+        # profile) — 64 votes ≈ a hundred µs, keeping the pool fair
+        for base in range(0, n, 64):
+            end = min(base + 64, n)
+            accepted: list[bytes] = []  # this group's keys, in ingest order
             with self._mtx:
-                for i in range(base, min(base + 64, len(votes))):
+                height = self.height
+                nbytes = self._votes_bytes
+                tr_active = tr.active
+                for i in range(base, end):
                     vote = votes[i]
-                    encoded = vote._wire_cache
-                    if encoded is None:
-                        encoded = encode_tx_vote(vote)
-                    vote_size = len(encoded)
+                    vote_size = len(wires[i])
                     lane = -1
                     if lane_of is not None:
                         try:
                             lane = lane_of(vote)
                         except Exception:
                             lane = -1
-                    while (
-                        len(votes_d) >= cfg.size
-                        or vote_size + self._votes_bytes > cfg.max_txs_bytes
-                    ):
-                        if lane != LANE_PRIORITY or not self._evict_bulk_locked():
-                            break
-                    if (
-                        len(votes_d) >= cfg.size
-                        or vote_size + self._votes_bytes > cfg.max_txs_bytes
-                    ):
-                        out[i] = ErrMempoolIsFull(
-                            len(votes_d), cfg.size,
-                            self._votes_bytes, cfg.max_txs_bytes,
-                        )
-                        continue
+                    if len(votes_d) >= size_cap or vote_size + nbytes > bytes_cap:
+                        if lane == LANE_PRIORITY:
+                            # bulk occupancy must not block a priority vote
+                            self._votes_bytes = nbytes
+                            while (
+                                len(votes_d) >= size_cap
+                                or vote_size + self._votes_bytes > bytes_cap
+                            ) and self._evict_bulk_locked():
+                                pass
+                            nbytes = self._votes_bytes
+                        if len(votes_d) >= size_cap or vote_size + nbytes > bytes_cap:
+                            out[i] = ErrMempoolIsFull(
+                                len(votes_d), size_cap, nbytes, bytes_cap
+                            )
+                            continue
                     if vote_size > max_size:
                         out[i] = ErrTxTooLarge(max_size, vote_size)
                         continue
-                    key = vote._vk_cache
-                    if key is None:
-                        key = vote.vote_key()
+                    key = keys[i]
                     if not cache_push(key):
                         entry = votes_d.get(key)
                         if entry is not None:
-                            entry.senders.add(sid)
+                            self._add_sender_locked(key, entry, sid)
                         out[i] = ErrTxInCache()
                         continue
                     if wal is not None:
                         try:
-                            wal.write(encoded)  # txlint: allow(lock-blocking) -- WAL append order must match ingest-log order; buffered write, fsync only if sync_on_write
+                            wal.write(wires[i])  # txlint: allow(lock-blocking) -- WAL append order must match ingest-log order; buffered write, fsync only if sync_on_write
                         except (OSError, FailpointError):
                             self.wal_degraded = True
                             self.wal_errors += 1
                             wal = None
-                    seg = vote._seg_cache
-                    if seg is None:
-                        seg = amino.length_prefixed(encoded)
-                        oset(vote, "_seg_cache", seg)
-                    entry = new(_PoolVote)
-                    entry.height = self.height
-                    entry.vote = vote
-                    entry.senders = {sid}
-                    entry.size = vote_size
-                    entry.seg = seg
-                    entry.lane = lane
-                    entry.origin = sid
-                    votes_d[key] = entry
-                    by_tx = self._by_tx.get(vote.tx_hash)
+                    if not accepted and not self._first_new_t:
+                        self._first_new_t = monotonic()
+                    votes_d[key] = (vote, height, segs[i], sid, lane)
+                    tx_hash = vote.tx_hash
+                    by_tx = by_tx_d.get(tx_hash)
                     if by_tx is None:
-                        by_tx = self._by_tx[vote.tx_hash] = {}
+                        by_tx = by_tx_d[tx_hash] = {}
                     by_tx[key] = None
-                    log_append(key)
+                    accepted.append(key)
                     if lane == LANE_PRIORITY:
                         prio_append(key)
-                    self._votes_bytes += vote_size
-                    accepted = True
-                    tr = self.tracer
-                    if tr.active and tr.sampled(vote.tx_hash):
+                    nbytes += vote_size
+                    if tr_active and tr.sampled(tx_hash):
                         t = monotonic()
-                        tr.span(vote.tx_hash, SPAN_VOTE_INGEST, t, t)
-                        tr.first_vote(vote.tx_hash, t)
+                        tr.span(tx_hash, SPAN_VOTE_INGEST, t, t)
+                        tr.first_vote(tx_hash, t)
+                self._votes_bytes = nbytes
+                if end == n:
+                    # in the frame's last hold: a hold of their own, taken
+                    # right after the waiters were woken, cost val4-served
+                    # 7% of its p95 (PERF.md section 6, PR 33)
+                    self.ingest_votes += n
+                    self.ingest_fast += fast
+                    self.ingest_general += general
+                    self.ingest_cpu_s += thread_time() - t0
                 if accepted:  # an all-dup group must not wake consumers
+                    self._log_extend_quiet(accepted)
                     self._log_notify()
                     self._notify_txs_available()
         return out
-
-    def _ingest_locked(
-        self,
-        vote: TxVote,
-        encoded: bytes,
-        key: bytes,
-        tx_info: TxInfo,
-        write_wal: bool,
-    ) -> None:
-        """One vote's acceptance decision + insertion (under self._mtx);
-        availability notification is the caller's (so frames notify once)."""
-        vote_size = len(encoded)
-        lane = self._lane_quiet(vote)
-        while (
-            len(self._votes) >= self.config.size
-            or vote_size + self._votes_bytes > self.config.max_txs_bytes
-        ):
-            if lane != LANE_PRIORITY or not self._evict_bulk_locked():
-                break
-        if (
-            len(self._votes) >= self.config.size
-            or vote_size + self._votes_bytes > self.config.max_txs_bytes
-        ):
-            raise ErrMempoolIsFull(
-                len(self._votes),
-                self.config.size,
-                self._votes_bytes,
-                self.config.max_txs_bytes,
-            )
-        max_size = self.config.max_msg_bytes - _MSG_OVERHEAD
-        if vote_size > max_size:
-            raise ErrTxTooLarge(max_size, vote_size)
-        if not self.cache.push(key):
-            entry = self._votes.get(key)
-            if entry is not None:
-                entry.senders.add(tx_info.sender_id)
-            raise ErrTxInCache()
-        if self.wal is not None and write_wal and not self.wal_degraded:
-            try:
-                self.wal.write(encoded)  # txlint: allow(lock-blocking) -- WAL append order must match ingest-log order; buffered write, fsync only if sync_on_write
-            except (OSError, FailpointError):
-                self.wal_degraded = True
-                self.wal_errors += 1
-        seg = vote._seg_cache
-        if seg is None:
-            seg = amino.length_prefixed(encoded)
-            object.__setattr__(vote, "_seg_cache", seg)
-        entry = _PoolVote(
-            self.height, vote, {tx_info.sender_id}, vote_size, seg=seg,
-            lane=lane, origin=tx_info.sender_id,
-        )
-        self._votes[key] = entry
-        by_tx = self._by_tx.get(vote.tx_hash)
-        if by_tx is None:
-            by_tx = self._by_tx[vote.tx_hash] = {}
-        by_tx[key] = None
-        self._log_append(key)
-        if lane == LANE_PRIORITY:
-            self._prio_log.append(key)
-        self._votes_bytes += vote_size
-        tr = self.tracer
-        if tr.active and tr.sampled(vote.tx_hash):
-            t = monotonic()
-            tr.span(vote.tx_hash, SPAN_VOTE_INGEST, t, t)
-            tr.first_vote(vote.tx_hash, t)
 
     def _notify_txs_available(self) -> None:
         if self._notify_available and not self._notified_txs_available:
@@ -459,7 +470,7 @@ class TxVotePool(IngestLogPool):
         with self._mtx:
             if max_ < 0:
                 max_ = len(self._votes)
-            return [e.vote for e in list(self._votes.values())[:max_]]
+            return [e[_VOTE] for e in list(self._votes.values())[:max_]]
 
     def drain_batch(self, max_: int, skip: set[bytes] | None = None) -> list[tuple[bytes, TxVote]]:
         """Snapshot up to max_ (key, vote) pairs in order, skipping keys."""
@@ -468,7 +479,7 @@ class TxVotePool(IngestLogPool):
             for k, e in self._votes.items():
                 if skip is not None and k in skip:
                     continue
-                out.append((k, e.vote))
+                out.append((k, e[_VOTE]))
                 if len(out) >= max_:
                     break
         return out
@@ -476,7 +487,7 @@ class TxVotePool(IngestLogPool):
     def entries(self, after: int = 0, limit: int = -1) -> list[tuple[bytes, TxVote]]:
         """Snapshot of (key, vote) pairs in insertion order (gossip walk)."""
         with self._mtx:
-            items = [(k, e.vote) for k, e in self._votes.items()]
+            items = [(k, e[_VOTE]) for k, e in self._votes.items()]
         if limit >= 0:
             return items[after : after + limit]
         return items[after:]
@@ -487,7 +498,7 @@ class TxVotePool(IngestLogPool):
         """Stable-cursor walk of live votes: (key, vote, height, wire seg)
         tuples; see IngestLogPool._entries_from for the cursor contract."""
         raw, pos = self._entries_from(cursor, limit)
-        return [(k, e.vote, e.height, e.seg) for k, e in raw], pos
+        return [(k, e[_VOTE], e[_HEIGHT], e[_SEG]) for k, e in raw], pos
 
     def prio_seq(self) -> int:
         """Monotonic priority-ingest counter (seq()'s twin for the
@@ -513,8 +524,8 @@ class TxVotePool(IngestLogPool):
             while pos - self._log_base < len(self._log) and len(out) < limit:
                 key = self._log[pos - self._log_base]
                 e = self._votes.get(key)
-                if e is not None and e.lane != LANE_PRIORITY:
-                    out.append((key, e.vote, e.height, e.seg))
+                if e is not None and e[_LANE] != LANE_PRIORITY:
+                    out.append((key, e[_VOTE], e[_HEIGHT], e[_SEG]))
                 pos += 1
         return out, pos
 
@@ -533,7 +544,7 @@ class TxVotePool(IngestLogPool):
                 key = self._prio_log[pos - self._prio_log_base]
                 e = self._votes.get(key)
                 if e is not None:
-                    out.append((key, e.vote, e.height, e.seg))
+                    out.append((key, e[_VOTE], e[_HEIGHT], e[_SEG]))
                 pos += 1
         return out, pos
 
@@ -560,18 +571,19 @@ class TxVotePool(IngestLogPool):
             for k in by_tx:
                 entry = self._votes.get(k)
                 if entry is not None:
-                    out.append(entry.seg)
+                    out.append(entry[_SEG])
                     if len(out) >= limit:
                         break
         return out
 
-    def _index_discard(self, k: bytes, entry: _PoolVote) -> None:
+    def _index_discard(self, k: bytes, entry: _Record) -> None:
         """Drop one key from the per-tx index (under self._mtx)."""
-        by_tx = self._by_tx.get(entry.vote.tx_hash)
+        tx_hash = entry[_VOTE].tx_hash
+        by_tx = self._by_tx.get(tx_hash)
         if by_tx is not None:
             by_tx.pop(k, None)
             if not by_tx:
-                del self._by_tx[entry.vote.tx_hash]
+                del self._by_tx[tx_hash]
 
     def remove(self, keys: list[bytes], cache_too: bool = False) -> None:
         """Remove votes by key (quorum purge path)."""
@@ -579,7 +591,7 @@ class TxVotePool(IngestLogPool):
             for k in keys:
                 entry = self._votes.pop(k, None)
                 if entry is not None:
-                    self._votes_bytes -= entry.size
+                    self._votes_bytes -= _wire_size(entry[_SEG])
                     self._index_discard(k, entry)
                 if cache_too:
                     self.cache.remove(k)
@@ -598,7 +610,7 @@ class TxVotePool(IngestLogPool):
                 self.cache.push(k)  # committed votes stay cached
                 entry = self._votes.pop(k, None)
                 if entry is not None:
-                    self._votes_bytes -= entry.size
+                    self._votes_bytes -= _wire_size(entry[_SEG])
                     self._index_discard(k, entry)
             self._log_compact()
             self._prio_compact()

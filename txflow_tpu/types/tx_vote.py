@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import time as _time
 from dataclasses import dataclass, field
+from hashlib import sha256 as _sha256
 
 from ..codec import amino
 from ..crypto import ed25519
@@ -238,6 +239,84 @@ def encode_tx_vote(vote: TxVote) -> bytes:
     if vote.signature is not None:  # immutable once signed
         vote._wire_cache = out
     return out
+
+
+# length prefix of every wire form the one-pass layout can produce (190
+# bytes of fixed fields, at most 11 of height and 24 of timestamp)
+_LEN_PREFIX = [amino.uvarint(n) for n in range(256)]
+# key and length of the timestamp field by its body's length; an empty
+# body elides the field
+_TS_HEAD = [b""] + [b"\x22" + amino.uvarint(n) for n in range(1, 32)]
+
+
+def ingest_bytes_many(
+    votes: list[TxVote],
+) -> tuple[list[bytes], list[bytes], list[bytes], int, int]:
+    """What the vote pool's ingest needs of a frame, one pass a vote:
+    ``(wires, segs, keys, fast, general)`` — each vote's wire form
+    (``encode_tx_vote``, which stays the definition), its gossip segment
+    (``amino.length_prefixed`` of that) and its dedup key
+    (``vote_key()``), byte for byte, and which way the wire forms came.
+
+    A vote that arrives with its wire form set (gossip decode, WAL
+    replay) is never re-encoded: it gets the segment and the key it
+    lacks and counts under neither number. A vote of the canonical shape
+    (a 64-byte hash, a 32-byte key, a 20-byte address, a 64-byte
+    signature: every length prefix is then a constant) is laid out in
+    one join around its timestamp body, counted in ``fast``. Any other
+    shape — a field length off those, no signature — goes through
+    ``encode_tx_vote``, counted in ``general``. The three caches are left
+    primed as ``encode_tx_vote``, ``vote_key`` and the pool have always
+    left them (an unsigned vote's wire form is returned, not cached).
+    Parity over field shapes and seeded random votes:
+    tests/test_tx_vote.py."""
+    wires: list[bytes] = []
+    segs: list[bytes] = []
+    keys: list[bytes] = []
+    fast = general = 0
+    oset = object.__setattr__
+    join = b"".join
+    time_body = amino.encode_time_body
+    for v in votes:
+        wire = v._wire_cache
+        sig = v.signature
+        if wire is None:
+            try:
+                h = v.tx_hash.encode()
+                k = v.tx_key
+                a = v.validator_address
+                if len(h) == 64 and len(k) == 32 and len(a) == 20 and len(sig) == 64:
+                    ht = v.height
+                    tb = time_body(v.timestamp_ns)
+                    wire = join((
+                        b"\x08" + amino.varint(ht) if ht != 0 else b"",
+                        b"\x12\x40", h,
+                        b"\x1a\x20", k,
+                        _TS_HEAD[len(tb)], tb,
+                        b"\x2a\x14", a,
+                        b"\x32\x40", sig,
+                    ))
+            except (AttributeError, TypeError):
+                wire = None  # a field that is no bytes at all: the definition judges it
+            if wire is None:
+                general += 1
+                wire = encode_tx_vote(v)
+            else:
+                fast += 1
+                oset(v, "_wire_cache", wire)  # signed, so immutable
+        wires.append(wire)
+        seg = v._seg_cache
+        if seg is None:
+            n = len(wire)
+            seg = (_LEN_PREFIX[n] if n < 256 else amino.uvarint(n)) + wire
+            oset(v, "_seg_cache", seg)
+        segs.append(seg)
+        key = v._vk_cache
+        if key is None:  # vote_key()'s body
+            key = _sha256(sig or b"").digest()
+            oset(v, "_vk_cache", key)
+        keys.append(key)
+    return wires, segs, keys, fast, general
 
 
 def _uv(data: bytes, pos: int, end: int) -> tuple[int, int, bool]:

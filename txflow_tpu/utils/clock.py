@@ -53,3 +53,10 @@ def perf_counter() -> float:
 def perf_counter_ns() -> int:
     """High-resolution monotonic nanoseconds."""
     return time.perf_counter_ns()
+
+
+def thread_time() -> float:
+    """CPU seconds of the calling thread: what a stretch of code cost the
+    interpreter, whoever else held its lock meanwhile. A cost counter's
+    clock, never a span's timestamp."""
+    return time.thread_time()

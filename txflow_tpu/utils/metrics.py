@@ -263,6 +263,12 @@ class HealthMetrics:
         self.pipeline_overlap = r.gauge("health", "pipeline_overlap_ratio", "engine verify-pipeline overlap (device-busy / active)")
         self.warmup_cold_votes = r.gauge("health", "warmup_cold_fallback_votes", "votes served by the CPU fallback awaiting shape promotion")
         self.pipeline_depth_now = r.gauge("health", "pipeline_depth", "engine's current (possibly adaptive) pipeline depth")
+        # the vote pool's ingest (pool/txvotepool.py check_tx_many), republished from the pool's own totals each tick
+        self.txvote_ingest_votes = r.gauge("health", "txvote_ingest_votes", "votes offered to the vote pool's ingest")
+        self.txvote_ingest_cpu_s = r.gauge("health", "txvote_ingest_cpu_s", "thread CPU seconds of the vote pool's ingest frames")
+        self.txvote_ingest_fast = r.gauge("health", "txvote_ingest_fast", "ingested votes whose wire form was laid out in one pass")
+        self.txvote_ingest_general = r.gauge("health", "txvote_ingest_general", "ingested votes encoded by encode_tx_vote (a shape off the canonical one)")
+        self.txvote_ingest_primed = r.gauge("health", "txvote_ingest_primed", "ingested votes that arrived with their wire form (gossip decode, WAL replay)")
 
 
 class NetMetrics:
