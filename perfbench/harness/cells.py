@@ -4,8 +4,11 @@ A cell is an entry of ``workloads``. Its configuration is the file the
 ``configs`` entry names; its traffic is ``perfbench/traffic/<traffic>.json``;
 numbers that belong to the one cell (a served cell's rate, found by its own
 sweep) are in ``perfbench/cells/<cell>.json`` and override the traffic
-file's, key by key. A later PR adds a cell by adding an entry and files:
-nothing here names a cell, a configuration or a traffic mix.
+file's, key by key. Code that belongs to one traffic kind, one arrival
+schedule or one per-layer metric is a file too, found by the name the data
+gives: ``kinds/<kind>.py``, ``arrivals/<name>.py``, ``metrics/<stem>.py``. A
+later PR adds a cell by adding an entry and files: nothing here names a
+cell, a configuration, a traffic mix, a kind, a schedule or a metric.
 """
 
 from __future__ import annotations
@@ -70,12 +73,37 @@ def stem(name: str) -> str:
     return name.split(".", 1)[0]
 
 
+def by_file(folder: str, name: str):
+    """The module ``perfbench/<folder>/<name>.py``, loaded from its file."""
+    path = os.path.join(BENCH, folder, name + ".py")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(
+            f"perfbench/{folder}/{name}.py is not there: {name!r} is named by the "
+            "benchmark's data and has to come with its file"
+        )
+    spec = importlib.util.spec_from_file_location(f"perfbench_{folder}_{name}", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
 def metric_reader(name: str):
     """The reader of one per-layer metric: ``perfbench/metrics/<stem>.py``,
     a module with ``read(ctx)`` that returns the number, or None where it
     finds nothing to read."""
-    path = os.path.join(BENCH, "metrics", stem(name) + ".py")
-    spec = importlib.util.spec_from_file_location("perfbench_metric_" + stem(name), path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read
+    return by_file("metrics", stem(name)).read
+
+
+def kind(name: str):
+    """A traffic kind, the traffic file's ``kind``: ``perfbench/kinds/<kind>.py``,
+    a module with ``run(cell, opt)`` that returns the result line. It states
+    what it runs (``RUNS``) and refuses a configuration that states otherwise."""
+    return by_file("kinds", name)
+
+
+def arrivals(name: str):
+    """An arrival schedule, a served traffic file's ``arrivals``:
+    ``perfbench/arrivals/<name>.py`` with ``offsets_ns(n_txs, rate_tps, seed,
+    params)``, the due time of each tx in ns after the schedule's start,
+    never falling."""
+    return by_file("arrivals", name).offsets_ns

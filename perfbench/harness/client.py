@@ -2,13 +2,13 @@
 
 It never imports JAX or the program, so it cannot starve under the node's
 interpreter lock, nor touch the chip. It sends ``POST /broadcast_tx`` on
-a fixed schedule, whatever the replies do (an open loop, evenly spaced, as
-``tm-load-test -r`` paces), and listens on the node's ``/websocket`` for
-the commit events. Times are ``time.monotonic_ns()``, which on Linux is
-one clock for every process of the machine, so the parent can set the
-schedule and read the result.
+the schedule it is given, whatever the replies do (an open loop: tx i is
+due ``offsets_ns[i]`` after ``t0_ns``), and listens on the node's
+``/websocket`` for the commit events. Times are ``time.monotonic_ns()``,
+which on Linux is one clock for every process of the machine, so the parent
+can set the schedule and read the result.
 
-argv[1]: one JSON object (see ``run``). stdout: one JSON object.
+stdin: one JSON object (see ``run``). stdout: one JSON object.
 """
 
 from __future__ import annotations
@@ -116,10 +116,10 @@ class EventListener(threading.Thread):
 def _sender(k: int, job: dict, out: dict) -> None:
     conn = http.client.HTTPConnection(job["host"], job["port"], timeout=30)
     tag = job["tag"].encode()
-    period_ns = 1e9 / job["rate_tps"]
+    offsets_ns = job["offsets_ns"]
     try:
-        for i in range(k, job["n_txs"], job["senders"]):
-            due = job["t0_ns"] + int(i * period_ns)
+        for i in range(k, len(offsets_ns), job["senders"]):
+            due = job["t0_ns"] + offsets_ns[i]
             sleep_until(due)
             tx = corpus_mod.make_tx(tag, job["first_tx"] + i, job["tx_bytes"])
             sent = time.monotonic_ns()
@@ -141,12 +141,12 @@ def _sender(k: int, job: dict, out: dict) -> None:
 
 
 def run(job: dict) -> dict:
-    """job: host, port, t0_ns, rate_tps, first_tx, n_txs, tx_bytes, tag, senders,
-    wait_s. Returns per tx: when it was sent and acknowledged, how the
+    """job: host, port, t0_ns, offsets_ns (one a tx), first_tx, tx_bytes, tag,
+    senders, wait_s. Returns per tx: when it was sent and acknowledged, how the
     node answered, and when its commit event arrived (0 = never)."""
     import hashlib
 
-    n = job["n_txs"]
+    n = len(job["offsets_ns"])
     listener = EventListener(job["host"], job["port"])
     listener.start()
     out = {"status": [-3] * n, "sent_ns": [0] * n, "acked_ns": [0] * n}
@@ -179,7 +179,7 @@ def run(job: dict) -> dict:
 
 
 def main() -> int:
-    job = json.loads(sys.argv[1])
+    job = json.load(sys.stdin)
     json.dump(run(job), sys.stdout)
     sys.stdout.flush()
     return 0

@@ -96,6 +96,23 @@ def is_corrupt(seed: int, i: int, share_den: int) -> bool:
     return int.from_bytes(h[:4], "little") % share_den == 0
 
 
+def powers_of(config: dict) -> list[int]:
+    """Every validator's stake, by index: the configuration's ``stake``, a
+    list of ``validators`` positive whole numbers written out in its file,
+    or ``stake_each`` for all of them where it has no such list. The one
+    place the benchmark reads a configuration's stake: the node's validator
+    set, the signed corpus and the reference's sums all take it from here."""
+    n_vals = int(config["validators"])
+    stake = config.get("stake")
+    if stake is None:
+        return [int(config["stake_each"])] * n_vals
+    if len(stake) != n_vals:
+        raise ValueError(f"stake lists {len(stake)} validators, the configuration has {n_vals}")
+    if any(type(p) is not int or p <= 0 for p in stake):
+        raise ValueError(f"stake has to be positive whole numbers: {stake!r}")
+    return list(stake)
+
+
 def byzantine_of(config: dict, fault: str | None) -> dict | None:
     """The peer that corrupts signatures in this run: the configuration's
     own, or, where the net is honest, the one its file plants for the
@@ -177,7 +194,7 @@ class CorpusBuilder:
             chain_id=config["chain_id"], tag=b"pb%x" % seed, seed=seed,
             tx_bytes=tx_bytes, n_txs=n_txs, n_vals=n_vals, signer_idx=list(signers),
             pub_keys=[public_key(s) for s in seeds],
-            powers=[int(config["stake_each"])] * n_vals,
+            powers=powers_of(config),
             byz_idx=int(byz.get("validator", -1)),
             byz_den=int(byz.get("corrupt_one_in", 0)),
         )

@@ -246,9 +246,10 @@ class SystemUnderTest:
         self.device = None
         self.registry = None
         self.cache_dir = None
+        powers = corpus_mod.powers_of(config)
         self.val_set = ValidatorSet([
-            Validator.from_pub_key(pv.get_pub_key(), int(config["stake_each"]))
-            for pv in self.priv_vals
+            Validator.from_pub_key(pv.get_pub_key(), power)
+            for pv, power in zip(self.priv_vals, powers)
         ])
         if scalar:
             verifier = ScalarVoteVerifier(self.val_set)
@@ -268,7 +269,7 @@ class SystemUnderTest:
             verifier = self.recorder = StepRecorder(verifier)
         self.net = LocalNet(
             n_vals, chain_id=config["chain_id"], priv_vals=self.priv_vals,
-            voting_power=int(config["stake_each"]), config=cfg,
+            voting_powers=powers, config=cfg,
             use_device_verifier=not scalar, verifier=verifier, sign=sign,
             mempool_broadcast=False, enable_consensus=False, rpc=rpc,
             index_txs=False, n_nodes=1,
@@ -336,15 +337,16 @@ class SystemUnderTest:
                                sigs[64 * i : 64 * i + 64]))
         self._ingest(votes, sender)
 
-    def deliver_tx_votes(self, corpus, i: int, sender: int) -> None:
-        """Every signer's vote on tx i, in one frame from one relaying peer."""
+    def deliver_tx_votes(self, corpus, i: int, signers, sender: int) -> None:
+        """The votes on tx i of ``signers``, (place in the corpus, validator)
+        pairs made in set-up, in one frame from one relaying peer."""
         key = corpus.tx_key(i)
         hx = key.hex().upper()
         n_vals = corpus.n_vals
         self._ingest([
             _vote(hx, key, corpus_mod.vote_timestamp(i, n_vals, v), self._addr[v],
                   corpus.sig(k, i))
-            for k, v in enumerate(corpus.signer_idx)
+            for k, v in signers
         ], sender)
 
     def _ingest(self, votes, sender: int) -> None:
@@ -359,6 +361,15 @@ class SystemUnderTest:
 
     def pipeline(self) -> dict:
         return self.node.txflow.pipeline_stats()
+
+    def counters(self) -> dict:
+        """Every counter the program keeps, whole, by the layer that keeps
+        it: a kind reads it as the window opens and as it closes, and a
+        reader takes what it needs from ``ctx["counters"]``."""
+        return {
+            "pipeline": self.node.txflow.pipeline_stats(),
+            "ingest": self.node.tx_vote_pool.ingest_stats(),
+        }
 
     def dispatches(self) -> dict:
         if self.device is None:

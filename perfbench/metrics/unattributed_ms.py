@@ -1,6 +1,8 @@
 """Whole path: the client's median commit latency less the path of the
-votes that complete the quorum, which the harness delivers
-``peer_delay_ms`` after the tx is due: that delay, then the medians of
+votes that complete the quorum, which the harness delivers a delay after
+the tx is due (``ctx["quorum_delay_ms"]``: the delay at which the delivered
+stake first passes 2/3, which is the traffic's ``peer_delay_ms`` where that
+is one number): that delay, then the medians of
 ``vote_wait`` (the tx's first vote in the pool -> ``host_prep`` of the step
 that drains it: the engine's pickup and the lane's hold, per tx),
 ``host_prep``, ``dispatch``, ``collect_wait``, ``route_tally`` (route start
@@ -30,6 +32,6 @@ def read(ctx):
     spans = [ctx["spans"](family, ctx["t_open"], ctx["t_close"]) for family in FAMILIES]
     if not all(spans):
         return None
-    named_ms = float(ctx["traffic"].get("peer_delay_ms", 0))
+    named_ms = float(ctx["quorum_delay_ms"])
     named_ms += sum(1e3 * statistics.median(s) for s in spans)
     return stats.percentile(client["lat_ms"], 50) - named_ms

@@ -1,6 +1,6 @@
 """Read a per-step timeline that ``run.py --timeline`` wrote.
 
-    python3 perfbench/study/read_timeline.py perfbench/study/flood_timeline_*.json
+    python3 perfbench/study/read_timeline.py chiprun_out/flood_timeline_*.json
 
 Prints, for each file: the steps inside the window, the cycle (submit to
 submit) as median, shortest and longest, host prep and route per step from

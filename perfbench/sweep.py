@@ -4,7 +4,7 @@
 
 One process, one node, the cell's own configuration and programs; the
 rates in rising order, each offered for ``--seconds`` on the cell's
-schedule (evenly spaced, open loop) after two seconds that are not counted
+arrival schedule (open loop) after two seconds that are not counted
 (the front door's bulk bucket follows the commit rate with a lag). The
 knee is the highest rate at which the backlog does not grow and the
 generator is not late: every tx committed, the 95th percentile under
@@ -37,12 +37,14 @@ def main(argv=None) -> int:
     ap.add_argument("--scalar", action="store_true", help="CPU rehearsal: the scalar verifier")
     args = ap.parse_args(argv)
 
-    from perfbench.harness import cells, drive, stats
+    from perfbench.harness import cells, drive, peers, stats
 
     cell = cells.Cell(args.workload)
     traffic, config = cell.traffic, cell.config
     if traffic["kind"] != "served":
         raise SystemExit("a sweep is of a served cell")
+    served = cells.kind("served")
+    drive.check_runs("served", config, served.RUNS)
     drive.device_info(args.scalar, cell.chips)
     rates = [float(r) for r in args.rates.split(",")]
     skip_s = 2.0
@@ -50,8 +52,10 @@ def main(argv=None) -> int:
     n_vals = int(config["validators"])
     traffic = dict(traffic, warm=traffic["warm"] + [["fused", max(traffic["rungs"]), max(traffic["rungs"])]])
     opt = drive.Options(seed=args.seed, seconds=args.seconds, scalar=args.scalar)
+    signers = list(range(1, n_vals))
+    groups = peers.frames(peers.delays_of(traffic, n_vals), signers)
     sut, corp, _, _ = drive.set_up(
-        config, traffic, opt, sum(counts), signers=list(range(1, n_vals)), sign=True
+        config, traffic, opt, sum(counts), signers=signers, sign=True
     )
     sut.start()
     print(f"sweep: set-up {time.monotonic() - T_START:.1f}s", file=sys.stderr, flush=True)
@@ -60,17 +64,19 @@ def main(argv=None) -> int:
         for rate, n in zip(rates, counts):
             shed0 = sut.admission_shed()
             disp0 = sut.dispatches()
-            t0_ns, proc, injector = drive.served_phase(
-                sut, corp, traffic, first_tx=first, n_txs=n, rate_tps=rate, wait_s=10.0,
+            offsets = served.offsets_of(traffic, n, rate, args.seed)
+            t0_ns, proc, injector = served.served_phase(
+                sut, corp, traffic, first_tx=first, offsets_ns=offsets, groups=groups,
+                wait_s=10.0,
             )
             time.sleep(max(0.0, t0_ns / 1e9 + skip_s - time.monotonic()))
             shed0 = sut.admission_shed()  # sheds of the ramp are not the rate's
-            client = drive.collect_client(proc, timeout=args.seconds + 60)
+            client = served.collect_client(proc, timeout=args.seconds + 60)
             injector.join(timeout=10)
             skip = round(rate * skip_s)
             lat, late, missed = [], [], 0
             for i in range(skip, n):
-                due = t0_ns + int(i * 1e9 / rate)
+                due = t0_ns + offsets[i]
                 late.append((client["sent_ns"][i] - due) / 1e6)
                 if client["status"][i] != 0 or not client["event_ns"][i]:
                     missed += 1
@@ -87,7 +93,7 @@ def main(argv=None) -> int:
                 "late_p95_ms": stats.percentile(late, 95),
                 "injector_late_p95_ms": stats.percentile(injector.late_ns, 95) / 1e6,
                 "shed": sut.admission_shed() - shed0,
-                "dispatches": drive._dispatch_delta(sut.dispatches(), disp0),
+                "dispatches": drive.dispatch_delta(sut.dispatches(), disp0),
             }
             row["sustained"] = bool(
                 missed == 0 and row["shed"] == 0 and row["p95_ms"] is not None

@@ -15,7 +15,7 @@ import sys
 import pytest
 
 from perfbench.harness import cells, drive
-from perfbench.tests.test_cells import TINY_FLOOD
+from perfbench.tests.test_cells import tiny_flood
 
 TINY_SERVED = {"rate_tps": 20, "lead_s": 1, "sign_workers": 2, "compare_txs": 32}
 CELLS = [w["name"] for w in cells.benchmark()["workloads"]]
@@ -23,7 +23,8 @@ CELLS = [w["name"] for w in cells.benchmark()["workloads"]]
 
 def rehearse(name, *, fault=None, trace=False, wait=8.0):
     cell = cells.Cell(name)
-    over = TINY_FLOOD if cell.traffic["kind"] == "flood" else TINY_SERVED
+    flood = cell.traffic["kind"] == "flood"
+    over = tiny_flood(int(cell.config["validators"])) if flood else TINY_SERVED
     opt = drive.Options(
         seed=2**31 + 11, seconds=2, scalar=True, overrides=over, fault=fault,
         commit_wait_s=wait,

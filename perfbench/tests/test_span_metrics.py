@@ -9,7 +9,7 @@ from perfbench.harness import cells
 T_OPEN, T_CLOSE = 100.0, 135.0
 
 
-def ctx_of(families: dict, *, votes=0, lat_ms=None, peer_delay_ms=0):
+def ctx_of(families: dict, *, votes=0, lat_ms=None, quorum_delay_ms=0.0):
     """``families``: family -> durations (s) of the spans that began in
     the window, as ``SystemUnderTest.spans`` hands them to a reader."""
 
@@ -20,7 +20,7 @@ def ctx_of(families: dict, *, votes=0, lat_ms=None, peer_delay_ms=0):
     return {
         "spans": spans, "t_open": T_OPEN, "t_close": T_CLOSE, "window_s": T_CLOSE - T_OPEN,
         "votes": votes, "client": None if lat_ms is None else {"lat_ms": lat_ms, "late_ms": []},
-        "trace": None, "traffic": {"peer_delay_ms": peer_delay_ms},
+        "trace": None, "quorum_delay_ms": quorum_delay_ms,
     }
 
 
@@ -79,8 +79,9 @@ def test_unattributed_reads_nought_on_a_waterfall_that_adds_up():
     for off_path in ("rpc_ingest", "sign_wait", "sign_walk", "route", "pickup_wait", "linger_bulk"):
         families[off_path] = [0.0009] * 5
     assert read(ctx_of(families, lat_ms=[total] * 5)) == pytest.approx(0.0, abs=1e-9)
-    # the peers' votes start the path: a cell that delays them says by how much
-    assert read(ctx_of(families, lat_ms=[total + 50.0] * 5, peer_delay_ms=50)) == pytest.approx(
+    # the votes that complete the quorum start the path: a cell that delays the
+    # peers says at which delay the delivered stake passes 2/3 (harness/peers.py)
+    assert read(ctx_of(families, lat_ms=[total + 50.0] * 5, quorum_delay_ms=50.0)) == pytest.approx(
         0.0, abs=1e-9)
 
 
@@ -94,21 +95,34 @@ def test_unattributed_is_none_where_a_family_is_empty(missing):
 
 
 def test_every_new_metric_names_its_reader_its_cell_and_what_it_moves():
+    """Read from ``BENCHMARK.json``: a suffix names one list of cells and one
+    end-to-end metric, which each of those cells reports; every span metric
+    has a reader that finds nothing among no spans; its layer is one that
+    some other source's metric, or PERF.md's table, already names."""
     bench = cells.benchmark()
-    moves = {"served": ("val4-served", "commit_p50_ms"), "val64": ("val64-served", "commit_p50_ms.val64"),
-             "flood": ("val4-flood", "commit_tx_per_s")}
-    new = [m for m in bench["per_layer"] if m["source"] == "program_span"
-           and cells.stem(m["name"]) not in ("sign_ms", "linger_ms")]
-    assert len(new) == 28 and len({cells.stem(m["name"]) for m in new}) == 14
-    for m in new:
-        cell, moved = moves[m["name"].split(".", 1)[1]]
-        assert m["workloads"] == [cell] and m["moves"] == moved
-        assert callable(cells.metric_reader(m["name"]))
-    assert {m["layer"] for m in new} <= {
-        "load generator and RPC front door", "sign walk", "host prep",
-        "H2D, fused step and readback", "route, tally and commit",
-        "event bus and websocket", "whole path", "engine loop",
-    }
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    all_cells = [w["name"] for w in bench["workloads"]]
+    by_suffix = {}
+    for m in bench["per_layer"]:
+        assert "." in m["name"], m["name"]  # quantity.cells
+        by_suffix.setdefault(m["name"].split(".", 1)[1], []).append(m)
+    assert len(by_suffix) == len({tuple(ms[0]["workloads"]) for ms in by_suffix.values()})
+    for suffix, ms in by_suffix.items():
+        assert len({(tuple(m["workloads"]), m["moves"]) for m in ms}) == 1, suffix
+        moved = e2e[ms[0]["moves"]]
+        assert set(ms[0]["workloads"]) <= set(moved.get("workloads", all_cells)), suffix
+        stems = [cells.stem(m["name"]) for m in ms]
+        assert len(set(stems)) == len(stems), suffix  # one reading of a quantity in a cell
+    spans = [m for m in bench["per_layer"] if m["source"] == "program_span"]
+    assert spans and len(spans) == sum(
+        1 for ms in by_suffix.values() for m in ms if m["source"] == "program_span")
+    for m in spans:
+        assert cells.metric_reader(m["name"])(ctx_of({}, lat_ms=[10.0])) is None, m["name"]
+    by_stem = {}
+    for m in bench["per_layer"]:
+        by_stem.setdefault(cells.stem(m["name"]), set()).add(
+            (m["unit"], m["better"], m["source"], m["layer"]))
+    assert all(len(v) == 1 for v in by_stem.values()), by_stem  # one quantity, one arithmetic
     # the device's idle share is the profiler's alone: no reading of it from host timestamps
     assert not [m for m in bench["per_layer"] if m["layer"] == "device"
                 and m["source"] != "device_trace"]

@@ -41,10 +41,11 @@ def test_spread_is_the_interquartile_distance_over_the_median():
 
 
 def test_served_outcomes_a_refusal_fails_the_tx_but_not_the_guarantee():
-    from perfbench.harness import drive
+    from perfbench.harness import cells, drive
 
+    served = cells.kind("served")
     ms = 1_000_000
-    period = 10 * ms
+    offsets = [i * 10 * ms for i in range(6)]
     reply = {
         #          lead    ok          refused  never       wrong code
         "status": [0, 0, 0, 429, 0, 0],
@@ -52,7 +53,7 @@ def test_served_outcomes_a_refusal_fails_the_tx_but_not_the_guarantee():
         "event_ns": [5 * ms, 19 * ms, 32 * ms, 0, 0, 61 * ms],
         "event_code": [0, 0, 0, -1, -1, 1],
     }
-    out = drive.served_outcomes(reply, 0, period, 1, 6)
+    out = served.served_outcomes(reply, 0, offsets, range(1, 6))
     assert out["lat_ms"] == [9.0, 12.0, drive.NEVER_MS, drive.NEVER_MS, 11.0]
     assert out["late_ms"] == [0.0, 0.05, 1.0, 0.0, 0.0]
     assert out["refused"] == [3] and out["never"] == [4]
