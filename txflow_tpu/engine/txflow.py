@@ -52,6 +52,7 @@ from ..trace.tracer import (
     SPAN_POOL_WAIT,
     SPAN_PREP,
     SPAN_QUORUM,
+    SPAN_QUORUM_WAIT,
     SPAN_ROUTE,
     SPAN_ROUTE_COMMIT,
     SPAN_ROUTE_PURGE,
@@ -140,7 +141,7 @@ class _StepPrep:
         "keys", "votes", "slots", "n_slots", "prior", "msgs", "sigs",
         "val_idx", "dropped", "verifier", "t0", "step",
         "trace_txs", "dispatch_end", "device_sid", "lane",
-        "drop_t", "carry_t",
+        "drop_t", "carry_t", "pickup_t0",
     )
 
     def __init__(self, t0: float, lane: str | None = None):
@@ -174,6 +175,10 @@ class _StepPrep:
         # (None = the drain dropped nothing / formed no batch)
         self.drop_t: tuple[float, float] | None = None
         self.carry_t: tuple[float, float] | None = None
+        # where the step's pickup_wait began (the pool's first vote since
+        # the previous drain), else its host_prep: each quorum_wait span
+        # this step decides starts here
+        self.pickup_t0 = t0
 
 
 class _BatchCoalescer:
@@ -505,6 +510,12 @@ class TxFlow:
         self._late_verified = 0
         self._carried_slots = 0
         self._open_vote_sets = 0
+        # what the quorums took, counted where routing decides them:
+        # commits, their certificate rows, and the steps that added a
+        # vote to each one's set up to the deciding step, summed
+        self._quorums = 0
+        self._quorum_rows = 0
+        self._quorum_steps = 0
         # host-prep split (trace/report.py prep_serial vs prep_pool_wait):
         # sign_s is the assembly stage's wall time, pool_wait_s the slice
         # of it this thread spent parked behind pool shards it didn't run
@@ -1242,6 +1253,8 @@ class TxFlow:
         t_up = (co.flush_t0 if co is not None else 0.0) or t_prep
         if 0.0 < t_in < t_up:
             self._stage_done(SPAN_PICKUP, t_in, t_up, step)
+        if 0.0 < t_in < t_prep:
+            prep.pickup_t0 = t_in
         tr = self.tracer
         for tx_hash in prep.trace_txs:
             t_vote = tr.take_first_vote(tx_hash)
@@ -1627,7 +1640,13 @@ class TxFlow:
                     self.vote_sets[vote.tx_hash] = vs
                 added, err = vs.add_verified_vote(vote)
                 if added:
+                    if vs.last_step != step:
+                        vs.last_step = step
+                        vs.steps += 1
                     if vs.has_two_thirds_majority():
+                        self._quorums += 1
+                        self._quorum_rows += len(vs.votes)
+                        self._quorum_steps += vs.steps
                         in_spec = pos < spec_n
                         traced = tr.active and tr.sampled(vote.tx_hash)
                         if in_spec or traced:
@@ -1637,6 +1656,8 @@ class TxFlow:
                                 # result available (route start) ->
                                 # quorum latched
                                 tr.span(vote.tx_hash, SPAN_QUORUM, t0, now, step)
+                                tr.span(vote.tx_hash, SPAN_QUORUM_WAIT,
+                                        prep.pickup_t0, now, step)
                             if in_spec:
                                 spec_t.append(now)
                                 if traced:
@@ -1754,6 +1775,11 @@ class TxFlow:
             "late_verified": self._late_verified,
             "carried_slots": self._carried_slots,
             "open_vote_sets": self._open_vote_sets,
+            # quorums decided, their certificate rows and the steps that
+            # fed each one, summed (rows / quorums = a certificate's rows)
+            "quorums": self._quorums,
+            "quorum_rows": self._quorum_rows,
+            "quorum_steps": self._quorum_steps,
             # host-prep split: sign/assembly stage wall time, and the
             # slice of it spent parked on host-pool shards (report.py
             # prep_serial vs prep_pool_wait)

@@ -171,7 +171,10 @@ class DegradedModeRegistry:
             # count the votes that arrived for nothing (dropped in prep,
             # or verified before routing found the tx committed: a rising
             # late_verified is device work thrown away); carried_slots and
-            # open_vote_sets what stays open from step to step;
+            # open_vote_sets what stays open from step to step; quorums /
+            # quorum_rows / quorum_steps what the decided quorums took
+            # (rows and steps a certificate, summed: perfbench cert_rows
+            # and quorum_steps read them);
             # full_collections / full_collect_s / survivors /
             # frozen_objects are the process's collector schedule
             stats = pipe()

@@ -111,6 +111,12 @@ SPAN_ROUTE_TALLY = "route_tally"
 SPAN_ROUTE_COMMIT = "route_commit"
 SPAN_ROUTE_PURGE = "route_purge"
 SPAN_QUORUM = "quorum_latch"
+# per tx: the start of the pickup_wait of the step that completes the
+# tx's quorum (the pool's first vote since the previous drain; that
+# step's host_prep where none came) -> the commit decision. The node's
+# own share of a quorum whose last frame arrives last: the frames'
+# delays before that step are not in it
+SPAN_QUORUM_WAIT = "quorum_wait"
 SPAN_COMMIT = "commit_apply"
 # commit event queued (engine/execution.py) -> frame handed to a
 # websocket subscriber's socket (rpc/server.py), or event_bus.publish's
@@ -130,8 +136,8 @@ SPAN_ORDER = (
     SPAN_SIGN_WAIT, SPAN_SIGN, SPAN_VOTE_INGEST, SPAN_PRE_DROP, SPAN_VOTE_WAIT,
     SPAN_POOL_WAIT, SPAN_PICKUP, SPAN_LINGER_PRIO, SPAN_LINGER_BULK, SPAN_PREP,
     SPAN_LOCK_WAIT, SPAN_LATE_DROP, SPAN_CARRY, SPAN_DISPATCH, SPAN_DEVICE,
-    SPAN_COLLECT, SPAN_ROUTE, SPAN_ROUTE_TALLY, SPAN_QUORUM, SPAN_SPEC,
-    SPAN_ROUTE_COMMIT, SPAN_COMMIT, SPAN_ROUTE_PURGE, SPAN_PUBLISH, SPAN_GC,
+    SPAN_COLLECT, SPAN_ROUTE, SPAN_ROUTE_TALLY, SPAN_QUORUM, SPAN_QUORUM_WAIT,
+    SPAN_SPEC, SPAN_ROUTE_COMMIT, SPAN_COMMIT, SPAN_ROUTE_PURGE, SPAN_PUBLISH, SPAN_GC,
     SPAN_SYNC_FETCH, SPAN_SYNC_VERIFY, SPAN_SYNC_APPLY, SPAN_E2E,
 )
 

@@ -106,6 +106,10 @@ class TxVoteSet:
         self.votes: dict[bytes, TxVote] = {}  # validator address -> vote
         self.sum = 0
         self.maj23 = False
+        # engine steps that added a vote to this set, and the last of
+        # them (engine/txflow.py routing: pipeline_stats quorum_steps)
+        self.steps = 0
+        self.last_step = 0
 
     # ---- accessors (reference :53-78, :178-227) ----
 
