@@ -210,21 +210,24 @@ class FakeWarmGate:
         return [("fused", 8, 8)]
 
 
+@pytest.mark.parametrize("tail_votes", [4, 5], ids=["tail_short", "tail_quorum"])
 @pytest.mark.parametrize("seed", [41, 97])
-def test_coalescing_parity_with_cold_fallback(seed):
+def test_coalescing_parity_with_cold_fallback(seed, tail_votes):
     """Randomized stream through the coalescing engine — including
-    linger-deadline flushes and the cold-shape scalar fallback flipping
-    to the primary verifier MID-RUN — produces certificates
+    linger-deadline and quorum flushes and the cold-shape scalar fallback
+    flipping to the primary verifier MID-RUN — produces certificates
     byte-identical to the scalar try_add_vote golden path."""
     pvs, vals = make_pvs(7)  # total 70, quorum 47 -> 5 votes needed
     txs = [b"co%d-%d=%d" % (seed, i, i) for i in range(16)]
     stream = _mixed_stream(pvs, txs, seed)
 
-    # sub-bucket tail: fed only after the main stream drains, so these 3
-    # votes can never join a full bucket — they MUST leave via the linger
-    # deadline (stake 30 < quorum 47: pending in a vote set, no commit)
+    # sub-bucket tail: fed in one frame after the main stream drains, so
+    # these votes can never join a full bucket. One vote short of the
+    # quorum (stake 40 < 47: pending in a vote set, no commit) they decide
+    # nothing and MUST leave via the linger deadline; with the fifth
+    # (50 >= 47) they complete the quorum and leave at once
     tail_tx = b"co%d-tail=1" % seed
-    tail = [sign_vote(pv, tail_tx) for pv in pvs[:3]]
+    tail = [sign_vote(pv, tail_tx) for pv in pvs[:tail_votes]]
 
     # scalar golden path
     flow_s, mem_s, _, store_s, app_s = make_engine(vals, use_device=False)
@@ -281,18 +284,22 @@ def test_coalescing_parity_with_cold_fallback(seed):
             except Exception:
                 pass
         assert _wait_quiescent(flow_p, pool_p), "coalescing engine never drained"
-        for v in tail:
-            pool_p.check_tx(v)
+        co = flow_p._coalescer
+        before = (co.linger_flushes, co.quorum_flushes)
+        pool_p.check_tx_many(tail)
         assert _wait_quiescent(flow_p, pool_p), "tail dribble never flushed"
     finally:
         flow_p.stop()
 
-    # the dispatch-shaping actually happened: canonical full buckets AND
-    # linger flushes for the sub-bucket tail, then post-promotion batches
-    # on the primary verifier
-    co = flow_p._coalescer
+    # the dispatch-shaping actually happened: canonical full buckets, the
+    # sub-bucket tail leaving by the deadline or by its quorum, then
+    # post-promotion batches on the primary verifier
     assert co.full_batches > 0
-    assert co.linger_flushes > 0
+    if tail_votes == 4:
+        assert co.linger_flushes > before[0]
+        assert co.quorum_flushes == before[1]
+    else:
+        assert co.quorum_flushes > before[1]
     assert primary_calls["n"] > 0, "no batch promoted to the primary verifier"
     stats = flow_p.pipeline_stats()
     assert stats["coalesce"]["enabled"]

@@ -201,6 +201,10 @@ def test_a_three_frame_quorum_with_late_frames_reads_correct(device, tmp_path, m
     assert metrics["quorum_wait_ms.hub180"]["value"] > 0
     assert metrics["late_drop_share.hub180"]["value"] > 0
     assert metrics["verified_per_commit.hub180"]["value"] < N_SMALL
+    # the deciding frame ends its hold as it lands: the coalescer's
+    # quorum flushes, one a committed tx at most
+    assert stats["coalesce"]["quorum_flushes"] > 0
+    assert 0 < metrics["quorum_flush_share.hub180"]["value"] <= 100
 
 
 def test_hub180_json_is_what_its_generator_makes():

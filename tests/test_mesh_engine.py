@@ -175,17 +175,18 @@ def test_mesh_engine_certificates_byte_identical():
 
 
 @pytest.mark.slow
-def test_mesh_engine_linger_flush_parity():
+@pytest.mark.parametrize("tail_votes", [4, 5], ids=["tail_short", "tail_quorum"])
+def test_mesh_engine_linger_flush_parity(tail_votes):
     """Threaded coalescing engine on a 3-way mesh (non-pow2): a sub-bucket
-    tail leaves via the linger deadline, and every decision still matches
+    tail one vote short of its quorum leaves via the linger deadline, one
+    that completes it leaves at once, and every decision still matches
     the scalar golden path."""
-    import time
-
     pvs, vals = make_pvs(7)  # quorum 47 -> 5 votes needed
     txs = [b"ml%d=%d" % (i, i) for i in range(8)]
     stream = _mixed_stream(pvs, txs, seed=13)
     tail_tx = b"ml-tail=1"
-    tail = [sign_vote(pv, tail_tx) for pv in pvs[:3]]  # stake 30 < 47
+    # stake 40 < 47 decides nothing; 50 >= 47 completes the quorum
+    tail = [sign_vote(pv, tail_tx) for pv in pvs[:tail_votes]]
 
     flow_s, mem_s, _, store_s, app_s = make_threaded_engine(
         vals, use_device=False
@@ -221,12 +222,16 @@ def test_mesh_engine_linger_flush_parity():
         assert _wait_quiescent(flow_m, pool_m, timeout=90.0), (
             "mesh engine never drained"
         )
-        for v in tail:
-            pool_m.check_tx(v)
+        before = (co.linger_flushes, co.quorum_flushes)
+        pool_m.check_tx_many(tail)
         assert _wait_quiescent(flow_m, pool_m, timeout=90.0), (
             "tail never flushed"
         )
-        assert co.linger_flushes > 0, "tail left without a linger flush"
+        if tail_votes == 4:
+            assert co.linger_flushes > before[0], "tail left without a linger flush"
+            assert co.quorum_flushes == before[1]
+        else:
+            assert co.quorum_flushes > before[1], "tail left without a quorum flush"
     finally:
         flow_m.stop()
 

@@ -192,16 +192,20 @@ class _BatchCoalescer:
     sizes from the verifier's own bucket ladder (>= min_batch, <= the
     drain cap): when the pending backlog covers a bucket, exactly that
     bucket is drained (zero padding, guaranteed-warm shape, remainder
-    carries to the next decision); otherwise the partial backlog lingers
-    until either ``linger`` elapses from its first vote or the pool goes
-    idle (note_idle, the idle_flush analog), then flushes at whatever
-    size coalesced — still padded to a canonical bucket by the verifier.
+    carries to the next decision); otherwise the partial backlog is held
+    until the held votes complete some tx's quorum by stake (``probe``,
+    the engine's _QuorumProbe: nothing that could change that tx's
+    outcome is still to come), ``linger`` elapses from its first vote, or
+    the pool goes idle (note_idle, the idle_flush analog), then flushes
+    at whatever size coalesced — still padded to a canonical bucket by
+    the verifier. A hold that decides no tx ends on the clock alone.
 
     decide() is called from the engine thread only; the counters feed
     txflow_coalesce_* metrics and pipeline_stats()["coalesce"]."""
 
     __slots__ = (
         "targets", "linger", "full_batches", "linger_flushes",
+        "quorum_flushes", "_probe",
         "_deadline", "_idle", "_clock", "_metrics", "_tracer", "_hold_t0",
         "span_name", "flush_t0", "wide_from", "wide_ok", "wide_full_batches",
     )
@@ -209,7 +213,7 @@ class _BatchCoalescer:
     def __init__(self, buckets, cap: int, min_batch: int, linger: float,
                  metrics=None, clock=monotonic, tracer=None,
                  multiple: int = 1, span_name: str = SPAN_LINGER_BULK,
-                 wide_from: int | None = None):
+                 wide_from: int | None = None, probe=None):
         # mesh divisibility: a sharded verifier pads every dispatch up to
         # a multiple of its shard count anyway (verifier.bucket_size), so
         # round the full-bucket targets here and drain exactly what the
@@ -225,6 +229,12 @@ class _BatchCoalescer:
         self.linger = linger
         self.full_batches = 0
         self.linger_flushes = 0
+        # holds ended by the probe, before their deadline; the probe is
+        # asked only while a partial batch is held (never where a full
+        # bucket is handed out) and returns whether the held backlog
+        # completes a quorum
+        self.quorum_flushes = 0
+        self._probe = probe
         self._deadline: float | None = None
         self._idle = False
         self._clock = clock
@@ -251,8 +261,9 @@ class _BatchCoalescer:
 
     def decide(self, pending: int, step: int = 0) -> int:
         """Votes to dispatch NOW: a full canonical bucket, the whole
-        backlog on linger/idle expiry, or 0 (keep coalescing). ``step``
-        is the id the dispatched batch will take (the linger span's)."""
+        backlog on linger/idle expiry or once it completes a quorum, or 0
+        (keep coalescing). ``step`` is the id the dispatched batch will
+        take (the linger span's)."""
         if pending <= 0:
             self._deadline = None
             self._idle = False
@@ -284,19 +295,24 @@ class _BatchCoalescer:
             self._deadline = now + self.linger
             self._hold_t0 = now
         if now >= self._deadline or self._idle:
-            self._deadline = None
-            self._idle = False
-            self.flush_t0 = self._hold_t0
             self.linger_flushes += 1
             if self._metrics is not None:
                 self._metrics.coalesce_linger_flushes.add(1)
-            if self._tracer.active:
-                # batch-level hold: no single tx owns it, so the span is
-                # tagged with the empty tx (report.py attributes linger
-                # from the histogram sum, not per tx)
-                self._tracer.span("", self.span_name, self._hold_t0, now, step)
-            return pending
-        return 0
+        elif self._probe is not None and self._probe():
+            self.quorum_flushes += 1
+            if self._metrics is not None:
+                self._metrics.coalesce_quorum_flushes.add(1)
+        else:
+            return 0
+        self._deadline = None
+        self._idle = False
+        self.flush_t0 = self._hold_t0
+        if self._tracer.active:
+            # batch-level hold: no single tx owns it, so the span is
+            # tagged with the empty tx (report.py attributes linger
+            # from the histogram sum, not per tx)
+            self._tracer.span("", self.span_name, self._hold_t0, now, step)
+        return pending
 
     @property
     def holding(self) -> bool:
@@ -331,6 +347,118 @@ class _BatchCoalescer:
             if idle_flush > 0:
                 budget = min(budget, idle_flush)
         return budget
+
+
+class _QuorumProbe:
+    """Does the backlog one lane's coalescer holds complete some tx's
+    quorum by stake? For each tx it keeps the held validators (each
+    counted once, by the power of the engine's validator set) and their
+    stake; a tx is complete where that stake plus the verified stake of
+    its open vote set reaches ``val_set.quorum_power()``.
+
+    Incremental: a call reads only the lane's pool entries appended since
+    the last (its own cursor, which a drain resets to the lane's drain
+    cursor: what is still undrained), and looks again only at the txs
+    that gained a vote, or at every held tx once a step has been routed
+    since (routing adds stake to the open sets). Votes of a step in
+    flight (drained, not yet routed) are in neither sum, so it can
+    under-count, which keeps the hold on its clock, and never counts a
+    vote twice. A held signature that fails on the device only moves
+    that tx's quorum one step later: the device tally and routing decide
+    every commit. Engine thread only; pool and vote sets are read under
+    the engine's _mtx."""
+
+    __slots__ = ("_eng", "_prio", "_cursor", "_held", "_touched",
+                 "_routed", "_seed", "_metrics", "probed")
+
+    def __init__(self, eng: "TxFlow", prio: bool, metrics=None):
+        self._eng = eng
+        self._prio = prio
+        self._metrics = metrics
+        self.probed = 0  # pool entries read
+        self.reset(0)
+
+    def reset(self, cursor: int) -> None:
+        """A drain of this lane took its votes up to ``cursor``: start
+        over from what is undrained (the lane's requeued votes, read on
+        the next call, then the log from the cursor)."""
+        self._cursor = cursor
+        # tx hash -> [held stake, {validator address: power}]
+        self._held: dict[str, list] = {}
+        self._touched: set[str] = set()
+        self._routed = -1
+        self._seed = True
+
+    def _hold(self, vote: TxVote) -> None:
+        eng = self._eng
+        tx_hash = vote.tx_hash
+        addr = vote.validator_address
+        power = eng._addr_power.get(addr)
+        if not power or eng._committed.__contains__(_hash_key(tx_hash)):
+            return
+        rec = self._held.get(tx_hash)
+        if rec is None:
+            rec = self._held[tx_hash] = [0, {}]
+        elif addr in rec[1]:
+            return
+        rec[1][addr] = power
+        rec[0] += power
+        self._touched.add(tx_hash)
+
+    def _complete(self, tx_hash: str) -> bool:
+        eng = self._eng
+        if eng._committed.__contains__(_hash_key(tx_hash)):
+            del self._held[tx_hash]
+            return False
+        stake, held = self._held[tx_hash]
+        vs = eng.vote_sets.get(tx_hash)
+        need = eng.val_set.quorum_power() - (vs.stake() if vs is not None else 0)
+        if stake < need:
+            return False
+        if vs is None:
+            return True
+        got = 0
+        for addr, power in held.items():
+            if vs.get_by_address(addr) is None:  # not routed already
+                got += power
+                if got >= need:
+                    return True
+        return False
+
+    def __call__(self) -> bool:
+        eng = self._eng
+        pool = eng.tx_vote_pool
+        cap = eng._drain_cap
+        with eng._mtx:
+            skip = ()
+            if self._seed:
+                self._seed = False
+                for _k, vote in eng._retry_prio if self._prio else eng._retry:
+                    self._hold(vote)
+            if self._prio:
+                raw, self._cursor = pool.priority_entries_from(self._cursor, cap)
+            elif eng._prio_lane is not None:
+                raw, self._cursor = pool.bulk_entries_from(self._cursor, cap)
+            else:
+                # merged drain: priority votes it took ahead of the main
+                # log are in flight, not held
+                raw, self._cursor = pool.entries_from(self._cursor, cap)
+                skip = eng._prio_drained
+            for key, vote, _h, _s in raw:
+                if key not in skip:
+                    self._hold(vote)
+            self.probed += len(raw)
+            if self._metrics is not None and raw:
+                self._metrics.coalesce_quorum_probed.add(len(raw))
+            txs = self._touched
+            self._touched = set()
+            if eng._pipe_steps != self._routed:
+                self._routed = eng._pipe_steps
+                txs = list(self._held)
+            for tx_hash in txs:
+                if tx_hash in self._held and self._complete(tx_hash):
+                    return True
+        return False
 
 
 class TxFlow:
@@ -396,6 +524,7 @@ class TxFlow:
         else:
             self.verifier = ScalarVoteVerifier(val_set)
         self._addr_to_idx = {v.address: i for i, v in enumerate(val_set)}
+        self._addr_power = {v.address: v.voting_power for v in val_set}
         # drains larger than the verifier's largest bucket would compile a
         # fresh kernel shape per batch size (verifier.DeviceVoteVerifier)
         self._drain_cap = min(
@@ -442,6 +571,10 @@ class TxFlow:
         # requeue into the priority lane, never behind the bulk backlog
         self._retry_prio: list[tuple[bytes, TxVote]] = []
         self._prio_lane: _BatchCoalescer | None = None
+        # each lane's quorum probe: ends its coalescer's hold once the
+        # held votes complete a tx's quorum (reset by each drain)
+        self._probe_bulk = _QuorumProbe(self, prio=False, metrics=self.metrics)
+        self._probe_prio = _QuorumProbe(self, prio=True)
         self._linger_ctrl = None
         self._lane_prio_batches = 0
         self._lane_prio_votes = 0
@@ -614,6 +747,7 @@ class TxFlow:
                         if self._drain_cap > self._classic_drain_cap
                         else None
                     ),
+                    probe=self._probe_bulk,
                 )
         if self.config.lane_split and self._prio_lane is None:
             # priority verify lane (ISSUE 12): small shard-divisible
@@ -635,6 +769,7 @@ class TxFlow:
                 tracer=self.tracer,
                 multiple=self._verifier_shards(),
                 span_name=SPAN_LINGER_PRIO,
+                probe=self._probe_prio,
             )
         if self.config.adaptive_linger and self._linger_ctrl is None:
             from .adaptive import AdaptiveLingerController
@@ -1231,6 +1366,10 @@ class TxFlow:
             if prep is None:
                 st.skip = True
             else:
+                if lane == "prio":
+                    self._probe_prio.reset(self._prio_drain_cursor)
+                else:
+                    self._probe_bulk.reset(self._drain_cursor)
                 st.step = prep.step
                 self._end_pool_wait(st.t0)
                 self._stage_done(SPAN_LOCK_WAIT, st.t0, lk_acq, st.step)
@@ -1807,6 +1946,10 @@ class TxFlow:
             "enabled": co is not None,
             "full_batches": co.full_batches if co is not None else 0,
             "linger_flushes": co.linger_flushes if co is not None else 0,
+            # holds ended because the held votes completed a quorum, and
+            # the pool entries the probe read to find out
+            "quorum_flushes": co.quorum_flushes if co is not None else 0,
+            "quorum_probed": self._probe_bulk.probed,
             "cold_fallback_votes": self._cold_fallback_votes,
             "prewarm_failures": self._prewarm_failures,
             # wide-rung ladder (wide_buckets): gate line, live verdict,
@@ -1824,6 +1967,7 @@ class TxFlow:
             "prio_votes": self._lane_prio_votes,
             "prio_full_batches": pl.full_batches if pl is not None else 0,
             "prio_linger_flushes": pl.linger_flushes if pl is not None else 0,
+            "prio_quorum_flushes": pl.quorum_flushes if pl is not None else 0,
             # live lingers (adaptive_linger steers these at runtime)
             "prio_linger_ms": (
                 round(pl.linger * 1e3, 4) if pl is not None else None
@@ -2442,6 +2586,7 @@ class TxFlow:
                     verifier = ScalarVoteVerifier(val_set)
             self.val_set = val_set
             self._addr_to_idx = {v.address: i for i, v in enumerate(val_set)}
+            self._addr_power = {v.address: v.voting_power for v in val_set}
             self.verifier = verifier
             if not restaged and self._warm_gate is not None:
                 # the shape-stability layer tracks the OLD verifier's

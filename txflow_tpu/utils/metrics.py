@@ -487,9 +487,13 @@ class TxFlowMetrics:
         self.pipeline_route_seconds = r.counter("txflow", "pipeline_route_seconds", "commit-routing seconds")
         # shape-stable batch coalescing (engine.txflow._BatchCoalescer):
         # full_batches dispatched at exactly a canonical bucket (zero
-        # padding waste), linger_flushes dispatched partial by deadline
+        # padding waste), linger_flushes dispatched partial by deadline,
+        # quorum_flushes dispatched partial once the held votes completed
+        # a tx's quorum (quorum_probed: pool entries the probe read)
         self.coalesce_full_batches = r.counter("coalesce", "full_batches", "batches dispatched at a full canonical bucket")
         self.coalesce_linger_flushes = r.counter("coalesce", "linger_flushes", "partial buckets flushed by the linger deadline")
+        self.coalesce_quorum_flushes = r.counter("coalesce", "quorum_flushes", "partial buckets flushed because the held votes completed a tx's quorum")
+        self.coalesce_quorum_probed = r.counter("coalesce", "quorum_probed", "held votes the quorum probe read")
         # background shape warmup (engine.shapes.BackgroundWarmer): votes
         # the engine served via the scalar fallback while their device
         # shape was still compiling, and shapes promoted so far
