@@ -545,7 +545,7 @@ def phase_mesh(
     """The mesh path and what it is compared with: the phase-A batch
     through the sharded verifier, the single-device verifier and the
     scalar model — identical — with each device holding 1/n_chips of the
-    vote axis of every per-vote argument and of the packed result."""
+    vote axis of the vote rows and of the packed result."""
     t0 = time.perf_counter()
     priv_vals, val_set = baseline_validator_set()
     batch = make_vote_batch(seed, n_votes, priv_vals, val_set)
@@ -558,13 +558,15 @@ def phase_mesh(
 
     def spy(*args):
         out = step(*args)
+        # (rows, tables, powers, slot_state): the vote rows split over
+        # the mesh, the rest replicated
         held["votes"] = [
             [(s.device.id, s.data.shape[0]) for s in a.addressable_shards]
-            for a in args[:7]
+            for a in args[:1]
         ]
         held["replicated"] = [
             [(s.device.id, s.data.shape) for s in a.addressable_shards]
-            for a in args[7:10]
+            for a in args[1:]
         ]
         held["packed"] = [
             (s.device.id, s.data.shape[0]) for s in out.addressable_shards
@@ -594,7 +596,7 @@ def phase_mesh(
         check(
             sorted(d for d, _ in shards) == ids
             and len({shape for _, shape in shards}) == 1,
-            f"epoch constants not replicated over {ids}: {shards}",
+            f"epoch constants / slot state not replicated over {ids}: {shards}",
         )
     per_shard = b // n_chips + 2 * b_slots
     check(

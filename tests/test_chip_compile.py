@@ -167,10 +167,10 @@ def test_sharded_packed_step_compiles_for_four_v5e(chip_compiler):
     rep = NamedSharding(mesh, P())
     b = b_slots = 4096
     shapes = _step_arg_shapes(b, b_slots)
-    # the seven per-vote arrays shard over the vote axis; tables, powers,
-    # prior stake and quorum replicate (DeviceVoteVerifier.submit)
+    # the vote rows shard over the vote axis; tables, powers and the slot
+    # state (prior stake + quorum) replicate (DeviceVoteVerifier.submit)
     args = [
-        jax.ShapeDtypeStruct(shape, dtype, sharding=split if i < 7 else rep)
+        jax.ShapeDtypeStruct(shape, dtype, sharding=split if i == 0 else rep)
         for i, (shape, dtype) in enumerate(shapes)
     ]
     # lru-cached per mesh: no CPU test shares this mesh of described chips

@@ -546,12 +546,12 @@ def test_recompile_hazard_raw_size_flagged():
         def dispatch(self, msgs):
             n = len(msgs)
             b = bucket_size(n, self.buckets)
-            ok = _pad(msgs, b - n)
-            bad = _pad(msgs, n)
+            ok = tally.pack_step_inputs(batch, slot, b, None, b, q)
+            bad = tally.pack_step_inputs(batch, slot, b, None, n, q)
             self.shapes_used.add(("fused", b, n))
     """), "txflow_tpu/verifier.py", [_passes().RecompileHazardPass()])
     assert _rules(active) == ["recompile-hazard"] * 2
-    assert "_pad width" in active[0].message
+    assert "pack_step_inputs size" in active[0].message
     assert "shapes_used" in active[1].message
 
 
@@ -563,9 +563,9 @@ def test_recompile_hazard_ladder_provenance_propagates():
             b = bucket_size(n, self.buckets, multiple=self._n_shards)
             b_slots = self.buckets[0]
             limit = self.max_batch if full else bucket_size(n, self.buckets)
-            pad = b - n
-            _pad(msgs, pad)
-            _pad(msgs, min(limit, b))
+            rows = b + 0
+            tally.pack_step_inputs(batch, slot, rows, None, b_slots, q)
+            pack_step_inputs(batch, slot, min(limit, b), None, b, q)
             self.shapes_used.add(("verify", b, b_slots))
             for shape in self.enumerate_shapes(n):
                 self.shapes_used.add(shape)
@@ -575,7 +575,7 @@ def test_recompile_hazard_ladder_provenance_propagates():
 
 def test_recompile_hazard_out_of_scope_exempt():
     active, _ = lint_source(
-        "def f(n):\n    _pad([], n)\n",
+        "def f(n):\n    pack_step_inputs(None, None, n, None, n, 0)\n",
         "txflow_tpu/node/node.py", [_passes().RecompileHazardPass()],
     )
     assert active == []

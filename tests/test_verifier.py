@@ -155,11 +155,11 @@ def test_bucket_size():
 def test_ring_tally_matches_psum_step():
     """The explicit ppermute ring all-reduce must produce bit-identical
     tallies to the psum formulation over the virtual mesh."""
-    import numpy as _np
-
-    from txflow_tpu.ops import ed25519_batch
-    from txflow_tpu.parallel import make_mesh
-    from txflow_tpu.parallel.mesh import sharded_compact_step, sharded_ring_step
+    from txflow_tpu.ops import ed25519_batch, tally
+    from txflow_tpu.parallel.mesh import (
+        sharded_compact_step_packed_cached,
+        sharded_ring_step,
+    )
 
     vals, seeds = make_valset(4)
     epoch = ed25519_batch.EpochTables([v.pub_key for v in vals])
@@ -167,31 +167,24 @@ def test_ring_tally_matches_psum_step():
         vals, seeds, n_txs=4, corrupt=("ok", "ok", "flip")
     )
     batch = ed25519_batch.prepare_compact(msgs, sigs, vidx, epoch)
-    n = batch.size
-    pad = (-n) % 8
-    import numpy as np
-
-    def p(a):
-        return np.concatenate([a, np.zeros((pad,) + a.shape[1:], a.dtype)])
-
-    args = (
-        p(batch.s_nibbles), p(batch.h_nibbles), p(batch.val_idx),
-        p(batch.r_y), p(batch.r_sign), p(batch.pre_ok),
-        np.concatenate([np.asarray(slot, np.int32), np.full(pad, -1, np.int32)]),
-        epoch.tables, vals.powers_array().astype(np.int32),
-        np.zeros(4, np.int32), np.int32(vals.quorum_power()),
+    b = batch.size + (-batch.size) % 8
+    n_slots = 4
+    rows, slot_state = tally.pack_step_inputs(
+        batch, np.asarray(slot, np.int32), b, None, n_slots, vals.quorum_power()
     )
+    args = (rows, epoch.tables, vals.powers_array().astype(np.int32), slot_state)
     mesh = make_mesh(8)
-    a = sharded_compact_step(mesh)(*args)
-    b = sharded_ring_step(mesh)(*args)
-    _np.testing.assert_array_equal(_np.asarray(a[0]), _np.asarray(b[0]))
+    packed = np.asarray(sharded_compact_step_packed_cached(mesh)(*args)).reshape(8, -1)
+    bs = b // 8
+    valid, ring_stake, ring_maj = (np.asarray(x) for x in sharded_ring_step(mesh)(*args))
+    np.testing.assert_array_equal(packed[:, :bs].reshape(-1).astype(bool), valid)
     # ring outputs are per-shard copies of the global: every shard's slice
     # must equal the psum-replicated global
-    stake = _np.asarray(b[1]).reshape(8, -1)
-    maj = _np.asarray(b[2]).reshape(8, -1)
+    stake = ring_stake.reshape(8, -1)
+    maj = ring_maj.reshape(8, -1)
     for sh in range(8):
-        _np.testing.assert_array_equal(stake[sh], _np.asarray(a[1]))
-        _np.testing.assert_array_equal(maj[sh], _np.asarray(a[2]))
+        np.testing.assert_array_equal(stake[sh], packed[0, bs : bs + n_slots])
+        np.testing.assert_array_equal(maj[sh], packed[0, bs + n_slots :].astype(bool))
 
 
 @pytest.mark.parametrize("nv", [16, 64])
@@ -283,3 +276,159 @@ def test_warmup_full_compiles_every_reachable_shape(valset4):
     dev2 = DeviceVoteVerifier(vals, buckets=LADDER)
     dev2.warmup(R + 1)
     assert dev2.shapes_used.snapshot() == {("fused", LADDER[1], R)}
+
+
+# ---- the step's inputs: vote rows + slot state, two puts a step ----
+
+STEP_LADDER = (8, 64)  # CPU-sized rungs: 23 votes ride the 64 rung, 5 slots the 8
+
+
+@pytest.fixture(scope="module")
+def step_verifiers(valset4):
+    """One verifier per path, built once: every case below shares its
+    compiled (64, 8) program."""
+    vals = valset4[0]
+    return {
+        "single": DeviceVoteVerifier(vals, buckets=STEP_LADDER),
+        "mesh4": DeviceVoteVerifier(vals, buckets=STEP_LADDER, mesh=make_mesh(4)),
+    }
+
+
+def _mixed_step_batch(vals, seeds, seed):
+    """A seeded batch with every kind of row the step must get right:
+    invalid signatures, a vote signed for another chain, repeated (tx,
+    validator) pairs, padding rows (23 votes on the 64 rung), and prior
+    stake that crosses a tx's quorum together with one valid vote."""
+    rng = np.random.default_rng(seed)
+    n_txs = 5
+    msgs, sigs, vidx, slot = make_batch(vals, seeds, n_txs)
+    msgs, sigs, vidx, slot = list(msgs), list(sigs), list(vidx), list(slot)
+    crossing = int(rng.integers(n_txs))  # its one valid vote + prior 20 >= 27
+    at_crossing = [i for i in range(len(msgs)) if slot[i] == crossing]
+    others = [i for i in range(len(msgs)) if slot[i] != crossing]
+    picked = rng.choice(others, 5, replace=False)
+    flipped = list(picked[:3]) + list(rng.permutation(at_crossing)[1:])
+
+    for i in flipped:  # invalid signatures: one bit flipped
+        k = int(rng.integers(64))
+        sigs[i] = sigs[i][:k] + bytes([sigs[i][k] ^ (1 << int(rng.integers(8)))]) + sigs[i][k + 1 :]
+    for i in picked[3:]:  # signed for another chain: never verifies here
+        t = int(slot[i])
+        tx_hash = hashlib.sha256(b"tx%d" % t).hexdigest().upper()
+        other = canonical_sign_bytes("other-chain", 1, tx_hash, 1700000000_000000000 + t)
+        sigs[i] = host_ed.sign(seeds[int(vidx[i])], other)
+    for i in rng.choice(len(msgs), 3, replace=False):  # repeats of a pair
+        msgs.append(msgs[i]), sigs.append(sigs[i]), vidx.append(vidx[i]), slot.append(slot[i])
+    order = rng.permutation(len(msgs))
+    msgs = [msgs[i] for i in order]
+    sigs = [sigs[i] for i in order]
+    vidx = np.array(vidx)[order]
+    slot = np.array(slot)[order]
+    # 20 of the 27 needed: the one valid vote of the batch crosses it
+    prior = np.zeros(n_txs, np.int64)
+    prior[crossing] = 20
+    return msgs, sigs, vidx, slot, n_txs, prior
+
+
+@pytest.mark.parametrize("path", ["single", "mesh4"])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_packed_rows_step_matches_scalar(valset4, step_verifiers, path, seed):
+    """The step on its two buffers (vote rows, slot state) agrees with the
+    golden model field by field, on one device and over a 4-way mesh."""
+    vals, seeds = valset4
+    msgs, sigs, vidx, slot, n_slots, prior = _mixed_step_batch(vals, seeds, seed)
+    dev = step_verifiers[path]
+    r = assert_parity(vals, msgs, sigs, vidx, slot, n_slots, prior, device=dev)
+    assert dev.predicted_shapes(len(msgs), n_slots) == [("fused", 64, 8)]
+    assert r.dropped.sum() >= 1 and (~r.valid & ~r.dropped).sum() >= 5
+    # the quorum the prior stake crossed: the batch alone is short of it
+    q = vals.quorum_power()
+    crossed = np.flatnonzero((prior > 0) & (prior < q) & r.maj23)
+    assert len(crossed) == 1 and r.stake[crossed[0]] - prior[crossed[0]] == 10
+
+
+@pytest.mark.parametrize("path", ["single", "mesh4"])
+def test_submit_puts_two_buffers_and_nothing_implicit(
+    valset4, step_verifiers, path, monkeypatch
+):
+    """A step's inputs reach the device in two explicit puts (vote rows,
+    slot state); the jitted call itself transfers nothing from the host,
+    which the transfer guard would refuse."""
+    import jax
+
+    from txflow_tpu.ops import tally
+
+    vals, seeds = valset4
+    msgs, sigs, vidx, slot, n_slots, prior = _mixed_step_batch(vals, seeds, 7)
+    dev = step_verifiers[path]
+    want = ScalarVoteVerifier(vals).verify_and_tally(msgs, sigs, vidx, slot, n_slots, prior)
+
+    put = []
+    real_put = jax.device_put
+
+    def counting_put(x, *args, **kwargs):
+        put.extend(jax.tree_util.tree_leaves(x))
+        return real_put(x, *args, **kwargs)
+
+    monkeypatch.setattr(jax, "device_put", counting_put)
+    before = dev.h2d_stats()
+    with jax.transfer_guard_host_to_device("disallow"):
+        got = dev.submit(msgs, sigs, vidx, slot, n_slots, prior_stake=prior).result()
+        # the guard is live on this backend: a host array handed to the
+        # same program is refused
+        rows = np.zeros((64, tally.ROW_BYTES), np.uint8)
+        with pytest.raises(Exception, match="host-to-device"):
+            dev._fn(rows, dev._tables_dev, dev._powers_dev, np.zeros(9, np.int32))
+    after = dev.h2d_stats()
+    assert [(p.dtype, p.shape) for p in put] == [
+        (np.uint8, (64, tally.ROW_BYTES)), (np.int32, (8 + 1,)),
+    ]
+    assert after["h2d_puts"] - before["h2d_puts"] == 2
+    assert after["h2d_bytes"] - before["h2d_bytes"] == 64 * tally.ROW_BYTES + 4 * (8 + 1)
+    np.testing.assert_array_equal(got.valid, want.valid)
+    np.testing.assert_array_equal(got.stake, want.stake)
+    np.testing.assert_array_equal(got.maj23, want.maj23)
+
+
+def test_localnet_counts_two_puts_a_step():
+    """Over a LocalNet run on one shared verifier, pipeline_stats()["h2d"]
+    adds two puts and b * ROW_BYTES + 4 * (b_slots + 1) bytes for every
+    engine step, and every engine step is one device dispatch."""
+    import chip_smoke
+    from txflow_tpu.engine.shapes import ShapeWarmRegistry
+    from txflow_tpu.node import LocalNet
+    from txflow_tpu.ops import tally
+    from txflow_tpu.verifier import ResilientVoteVerifier
+
+    priv_vals, val_set = chip_smoke.baseline_validator_set(4)
+    device = DeviceVoteVerifier(val_set, buckets=(64,))
+    verifier = ResilientVoteVerifier(device)
+    ShapeWarmRegistry(verifier).prewarm(full=True)
+    net = LocalNet(
+        4, use_device_verifier=True, priv_vals=priv_vals, verifier=verifier,
+    )
+    net.start()
+    try:
+        h2d0 = net.nodes[0].txflow.pipeline_stats()["h2d"]
+        shapes0 = device.shapes_used.counts()
+        steps0 = [n.txflow.pipeline_stats()["steps"] for n in net.nodes]
+        txs = [b"h2d%d=%d" % (i, i) for i in range(4)]
+        for tx in txs:
+            net.broadcast_tx(tx)
+        assert net.wait_all_committed(txs, timeout=300)
+    finally:
+        net.stop()
+    stats = [n.txflow.pipeline_stats() for n in net.nodes]
+    assert all(s["h2d"] == stats[0]["h2d"] for s in stats)  # one verifier
+    steps = sum(s["steps"] for s in stats) - sum(steps0)
+    dispatched = {
+        shape: n - shapes0.get(shape, 0)
+        for shape, n in device.shapes_used.counts().items()
+    }
+    assert steps > 0 and sum(dispatched.values()) == steps
+    h2d = stats[0]["h2d"]
+    assert h2d["h2d_puts"] - h2d0["h2d_puts"] == 2 * steps
+    assert h2d["h2d_bytes"] - h2d0["h2d_bytes"] == sum(
+        n * (b * tally.ROW_BYTES + 4 * (b_slots + 1))
+        for (_, b, b_slots), n in dispatched.items()
+    )

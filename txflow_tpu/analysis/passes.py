@@ -839,8 +839,8 @@ _SHAPE_ATTRS = {"buckets", "max_batch", "capacity", "_n_shards"}
 
 class RecompileHazardPass(LintPass):
     """Shape args at dispatch sinks must provably flow from the bucket
-    ladder. Sinks: ``_pad(x, P)``'s pad width and ``shapes_used.add(t)``'s
-    tuple elements. Provenance propagates through assignments, BinOps
+    ladder. Sinks: ``pack_step_inputs(batch, slot, B, prior, S, q)``'s
+    padded sizes B and S and ``shapes_used.add(t)``'s tuple elements. Provenance propagates through assignments, BinOps
     with a ladder-derived operand (``pad = b - n``), subscripts of
     blessed attrs (``self.buckets[0]``), min/max, and conditionals."""
 
@@ -864,13 +864,13 @@ class RecompileHazardPass(LintPass):
             f = sub.func
             fname = _expr_str(f) if isinstance(f, (ast.Attribute, ast.Name)) else ""
             last = fname.rsplit(".", 1)[-1]
-            if last == "_pad" and len(sub.args) >= 2:
-                if not self._is_safe(sub.args[1], safe):
+            if last == "pack_step_inputs" and len(sub.args) >= 5:
+                if not all(self._is_safe(sub.args[i], safe) for i in (2, 4)):
                     out.append(Violation(
                         self.name, module.path, sub.lineno,
-                        "_pad width does not flow from the bucket ladder "
-                        "(bucket_size/ShapeWarmRegistry) — every new raw "
-                        "size is a fresh XLA compile",
+                        "pack_step_inputs size does not flow from the bucket "
+                        "ladder (bucket_size/ShapeWarmRegistry) — every new "
+                        "raw size is a fresh XLA compile",
                     ))
             elif (
                 last == "add"

@@ -2002,6 +2002,9 @@ class TxFlow:
             ring_stats = ring() if ring is not None else None
             if ring_stats is not None:
                 stats["staging"] = ring_stats
+            # the steps' host->device puts: two a step, vote rows and
+            # slot state (DeviceVoteVerifier.submit)
+            stats["h2d"] = dev.h2d_stats()
         return stats
 
     # ---- scalar parity API (reference TryAddVote :169-188) ----

@@ -176,7 +176,8 @@ class DegradedModeRegistry:
             # (rows and steps a certificate, summed: perfbench cert_rows
             # and quorum_steps read them);
             # full_collections / full_collect_s / survivors /
-            # frozen_objects are the process's collector schedule
+            # frozen_objects are the process's collector schedule; h2d
+            # the steps' host->device puts and bytes (two puts a step)
             stats = pipe()
             progress["pipeline"] = stats
             if stats["overlap_ratio"] is not None:

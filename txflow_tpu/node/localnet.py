@@ -9,7 +9,7 @@ TxVotes replayed through txvotepool").
 Every node runs the full fast path: mempool gossip -> signTxRoutine ->
 vote gossip -> batched device verify+tally -> per-tx commit against its
 own app instance. All nodes share one process and (on TPU) one chip; the
-device kernel is shared-compiled across nodes (ops.tally.compact_step_jit).
+device kernel is shared-compiled across nodes (ops.tally.compact_step_packed_jit).
 """
 
 from __future__ import annotations

@@ -170,8 +170,9 @@ verify_kernel_jit = jax.jit(verify_kernel)
 # The naive path above ships a gathered [B, 16, 4, 32] int32 table block per
 # batch (~8 KiB/vote — measured to cap sustained throughput at ~80k votes/s
 # on PCIe-class links). Here the per-epoch tables live on device once and
-# votes ship as ~162 bytes each (u8 nibbles + R bytes + indices); the
-# validator gather happens device-side inside the jit.
+# the fused step ships each vote as one ``ops.tally.VOTE_ROW`` (u8 nibbles
+# + R bytes + indices, 170 bytes padded to ``ROW_BYTES``); the validator
+# gather happens device-side inside the jit.
 
 
 @dataclass

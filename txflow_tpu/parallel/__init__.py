@@ -11,14 +11,12 @@ ICI — the workload's analog of sequence parallelism.
 from .mesh import (
     VOTE_AXIS,
     make_mesh,
-    sharded_compact_step,
     sharded_compact_step_packed_cached,
     sharded_verify_and_tally,
 )
 
 __all__ = [
     "make_mesh",
-    "sharded_compact_step",
     "sharded_compact_step_packed_cached",
     "sharded_verify_and_tally",
     "VOTE_AXIS",

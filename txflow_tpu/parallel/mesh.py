@@ -3,9 +3,10 @@
 Sharding layout (tpu-first, not a translation of the reference's per-peer
 goroutines):
 
-- vote-batch axis ("votes"): fully sharded — every per-vote array
-  (scalar nibbles, pubkey window tables, R encodings, masks, slots, powers)
-  is split across devices; the curve kernel runs embarrassingly parallel.
+- vote-batch axis ("votes"): fully sharded — the fused step's vote rows
+  (``ops.tally.VOTE_ROW``: scalar nibbles, R encoding, validator index,
+  slot, masks) are split across devices, and so are the naive path's
+  gathered pubkey tables; the curve kernel runs embarrassingly parallel.
 - tx-slot stake vector: computed as per-shard partial segment-sums, then
   ``psum`` over the mesh axis — one ICI collective per step — so every
   shard holds the identical global tally and quorum mask (replicated out).
@@ -72,46 +73,26 @@ import functools
 
 
 @functools.lru_cache(maxsize=8)
-def sharded_compact_step_cached(mesh: Mesh, axis_name: str = VOTE_AXIS):
-    """Process-wide shared jit of the sharded step (Mesh is hashable).
-
-    Shared for the same reason as ``tally.compact_step_jit``: N in-proc
-    nodes over one mesh must reuse one compiled program per shape."""
-    return sharded_compact_step(mesh, axis_name)
-
-
-def sharded_compact_step(mesh: Mesh, axis_name: str = VOTE_AXIS):
-    """jit(shard_map) of the compact fused step (ops.tally.compact_step).
-
-    Per-vote arrays shard over the vote axis; the per-epoch table/power
-    constants and the prior-stake/quorum scalars are replicated; per-shard
-    partial stake tallies psum over ICI. Same call signature as the
-    single-device compact step.
-    """
-    inner = tally.compact_step(axis_name=axis_name)
-    v = P(axis_name)
-    f = shard_map(
-        inner,
-        mesh=mesh,
-        in_specs=(v, v, v, v, v, v, v, P(), P(), P(), P()),
-        out_specs=(v, P(), P()),
-    )
-    return jax.jit(f)
-
-
-@functools.lru_cache(maxsize=8)
 def sharded_compact_step_packed_cached(mesh: Mesh, axis_name: str = VOTE_AXIS):
-    """Packed-output sharded step (single D2H readback; tally.compact_step_
-    packed docstring). Per-shard output [B/n + 2*S] int32, sharded over the
-    vote axis -> host sees [B + 2*S*n]; the stake/maj segments repeat the
-    psum-replicated global per shard."""
+    """jit(shard_map) of the packed fused step (ops.tally.compact_step_packed),
+    shared process-wide (Mesh is hashable) for the same reason as
+    ``tally.compact_step_packed_jit``: N in-proc nodes over one mesh must
+    reuse one compiled program per shape.
+
+    f(rows, tables, powers, slot_state), the single-device signature. The
+    vote rows (uint8[B, ROW_BYTES], one ``tally.VOTE_ROW`` a vote) split
+    over the vote axis, each shard verifying B/n whole rows; the per-epoch
+    tables and powers and the slot state (prior stake + quorum) are
+    replicated; per-shard partial stake tallies psum over ICI. Per-shard
+    output [B/n + 2*S] int32, sharded over the vote axis -> host sees
+    [B + 2*S*n]; the stake/maj segments repeat the psum-replicated global
+    per shard (one D2H readback)."""
     inner = tally.compact_step_packed(axis_name=axis_name)
-    v = P(axis_name)
     f = shard_map(
         inner,
         mesh=mesh,
-        in_specs=(v, v, v, v, v, v, v, P(), P(), P(), P()),
-        out_specs=v,
+        in_specs=(P(axis_name), P(), P(), P()),
+        out_specs=P(axis_name),
     )
     return jax.jit(f)
 
@@ -144,9 +125,10 @@ def ring_tally(stake_partial, axis_name: str = VOTE_AXIS):
 def sharded_ring_step(mesh: Mesh, axis_name: str = VOTE_AXIS):
     """Compact fused step with the ring all-reduce instead of psum.
 
-    Bit-identical tallies to ``sharded_compact_step`` (integer addition is
-    associative/commutative and every shard contributes exactly once) —
-    pinned by tests/test_verifier.py's mesh parity test.
+    Bit-identical tallies to ``sharded_compact_step_packed_cached`` (integer
+    addition is associative/commutative and every shard contributes
+    exactly once) — pinned by tests/test_verifier.py's mesh parity test.
+    Same inputs: f(rows, tables, powers, slot_state).
 
     Output layout difference, for honesty with the static VMA checker: a
     ppermute chain does not PROVE replication the way psum does, so the
@@ -155,10 +137,11 @@ def sharded_ring_step(mesh: Mesh, axis_name: str = VOTE_AXIS):
     slice. (The checker stays ON; suppressing it was round-2 review
     finding #7 and is not coming back.)
     """
-    from ..ops import ed25519_batch
-
-    def inner(s_nib, h_nib, val_idx, r_y, r_sign, pre_ok, tx_slot,
-              tables, powers, prior_stake, quorum):
+    def inner(rows, tables, powers, slot_state):
+        s_nib, h_nib, val_idx, r_y, r_sign, pre_ok, tx_slot = (
+            tally.unpack_vote_rows(rows)
+        )
+        prior_stake, quorum = slot_state[:-1], slot_state[-1]
         valid = ed25519_batch.verify_kernel_gather(
             s_nib, h_nib, val_idx, tables, r_y, r_sign, pre_ok,
             axis_name=axis_name,
@@ -174,7 +157,7 @@ def sharded_ring_step(mesh: Mesh, axis_name: str = VOTE_AXIS):
     f = shard_map(
         inner,
         mesh=mesh,
-        in_specs=(v, v, v, v, v, v, v, P(), P(), P(), P()),
+        in_specs=(v, P(), P(), P()),
         out_specs=(v, v, v),
     )
     return jax.jit(f)

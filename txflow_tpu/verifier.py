@@ -424,20 +424,19 @@ class DeviceVoteVerifier:
 
             self._n_shards = mesh.size
             self._fn = sharded_compact_step_packed_cached(mesh)
-            # per-batch staging shardings: padded vote-axis arrays are
-            # device_put split across the mesh, the prior-stake vector
-            # replicated — explicit placement so dispatch never falls
-            # back to an implicit host->device-0 transfer + reshard, and
-            # the compiled programs see one canonical input layout per
-            # bucket (zero-recompile across epoch restages, same as the
+            # per-step placement of the two input buffers: the vote rows
+            # split over the vote axis, the slot state replicated — the
+            # compiled programs see one canonical input layout per bucket
+            # (zero-recompile across epoch restages, same as the
             # single-device ladder)
-            self._vote_sharding = NamedSharding(self.mesh, PartitionSpec(VOTE_AXIS))
-            self._rep_sharding = NamedSharding(self.mesh, PartitionSpec())
+            self._put_shardings = (
+                NamedSharding(self.mesh, PartitionSpec(VOTE_AXIS)),
+                NamedSharding(self.mesh, PartitionSpec()),
+            )
         else:
             self._n_shards = 1
             self._fn = tally.compact_step_packed_jit()
-            self._vote_sharding = None
-            self._rep_sharding = None
+            self._put_shardings = None
         # sharded host-prep pool (engine.hostprep): sized by the FIRST
         # sizer — co-located engines sharing this verifier share one pool
         # (ensure_host_pool), so worker count doesn't multiply per node
@@ -445,6 +444,10 @@ class DeviceVoteVerifier:
         self.host_prep_workers = 0
         self.host_prep_backend = "thread"
         self._stats_mtx = make_lock("verifier.DeviceVoteVerifier._stats_mtx")
+        # the steps' explicit host->device puts and their bytes (two a
+        # step: vote rows and slot state; pipeline_stats()["h2d"])
+        self.h2d_puts = 0
+        self.h2d_bytes = 0
         # double-buffered readback (parallel.staging.StagingRing): packed
         # device results enter the ring at dispatch and a side thread
         # pulls them to host eagerly, so batch N's device_put + dispatch
@@ -537,6 +540,11 @@ class DeviceVoteVerifier:
         """Staging-ring counters (None until the first staged dispatch)."""
         ring = self._staging
         return None if ring is None else ring.stats()
+
+    def h2d_stats(self) -> dict:
+        """The steps' host->device puts and bytes since construction."""
+        with self._stats_mtx:
+            return {"h2d_puts": self.h2d_puts, "h2d_bytes": self.h2d_bytes}
 
     def _build_stage(self, val_set: ValidatorSet) -> _DeviceStage:
         # int32 device tally: with dedup, per-slot batch stake and prior
@@ -679,39 +687,24 @@ class DeviceVoteVerifier:
             msgs, sigs, val_idx, st.epoch, pool=self._host_pool
         )
         batch.pre_ok &= keep
-        # pad to bucket: pre_ok False + slot -1 => contributes nothing
-        pad = b - n
-        s_nib = _pad(batch.s_nibbles, pad)
-        h_nib = _pad(batch.h_nibbles, pad)
-        vidx = _pad(batch.val_idx, pad)
-        r_y = _pad(batch.r_y, pad)
-        r_sign = _pad(batch.r_sign, pad)
-        pre_ok = _pad(batch.pre_ok, pad)
-        slot = np.full(b, -1, np.int32)
-        slot[:n] = tx_slot
-
-        prior = np.zeros(b_slots, np.int32)
-        if prior_stake is not None:
-            prior[:n_slots] = np.asarray(prior_stake, dtype=np.int32)
-        q = np.int32(st.val_set.quorum_power() if quorum is None else quorum)
+        q = st.val_set.quorum_power() if quorum is None else quorum
+        rows, slot_state = tally.pack_step_inputs(
+            batch, tx_slot, b, prior_stake, b_slots, q
+        )
 
         self.shapes_used.add(("fused", b, b_slots))
-        if self.mesh is not None:
-            # explicit placement: vote-axis arrays split across the mesh
-            # (b is a multiple of _n_shards by construction), prior
-            # replicated — the numpy buffers hand off without an extra
-            # host copy and the program never implicitly reshards
-            import jax
+        import jax
 
-            s_nib, h_nib, vidx, r_y, r_sign, pre_ok, slot = jax.device_put(
-                (s_nib, h_nib, vidx, r_y, r_sign, pre_ok, slot),
-                self._vote_sharding,
-            )
-            prior = jax.device_put(prior, self._rep_sharding)
-        packed = self._fn(
-            s_nib, h_nib, vidx, r_y, r_sign, pre_ok, slot,
-            st.tables_dev, st.powers_dev, prior, q,
-        )
+        # the step's two explicit host->device puts (one call): vote rows
+        # split across the mesh (b is a multiple of _n_shards by
+        # construction), slot state replicated; on one device both go to
+        # the default device. Nothing else the program reads is on the
+        # host: tables and powers are the stage's device arrays.
+        rows, slot_state = jax.device_put((rows, slot_state), self._put_shardings)
+        with self._stats_mtx:
+            self.h2d_puts += 2
+            self.h2d_bytes += rows.nbytes + slot_state.nbytes
+        packed = self._fn(rows, st.tables_dev, st.powers_dev, slot_state)
         # ONE readback — deferred to ticket.result() and (with a staging
         # ring) already in flight on the ring thread; per-shard layout
         # [valid b/n | stake S | maj S] (tally.compact_step_packed);
@@ -967,8 +960,3 @@ class _ResilientTicket(VerifyTicket):
         self._done = res
         return res
 
-
-def _pad(a: np.ndarray, pad: int) -> np.ndarray:
-    if pad == 0:
-        return a
-    return np.concatenate([a, np.zeros((pad,) + a.shape[1:], a.dtype)])
